@@ -54,6 +54,9 @@ cleanup() {
 }
 trap cleanup EXIT
 
+# Local fan-out (no YTK_SLAVE_HOSTS) is for CPU hosts: on a TPU host every
+# forked rank would claim every chip. One process drives all chips there:
+# bin/tpu_optimizer.sh ... --devices N (docs/running_guide.md).
 for ((rank = num_procs - 1; rank >= 0; rank--)); do
   cmd=(python -m ytklearn_tpu.cli train "${model_name}" "${properties_path}"
        --coordinator "${coordinator}" --num-processes "${num_procs}"

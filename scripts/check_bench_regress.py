@@ -153,7 +153,7 @@ def check(old, new, tol: float) -> List[str]:
     if n["downgrades"] > o["downgrades"]:
         fails.append(
             f"downgrades increased: {o['downgrades']} -> {n['downgrades']} "
-            "(a kernel rung was silently lost — see gbdt.downgrade.* in obs)"
+            "(a kernel rung was lost — see the artifact's obs counters)"
         )
     print(
         f"  health events: r{n_rnd} {n['health_events']} vs "

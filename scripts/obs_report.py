@@ -8,7 +8,7 @@ telemetry, and the collective census.
     python scripts/obs_report.py flight_20260803-101512_4711_1.json
     python scripts/obs_report.py /tmp/trace.json        # YTK_TRACE output
     python scripts/obs_report.py /tmp/events.jsonl      # YTK_TRACE_JSONL
-    python scripts/obs_report.py BENCH_r05.json         # bench artifact
+    python scripts/obs_report.py bench.json             # bench.py's JSON line
     python scripts/obs_report.py lint.json              # ytklint --format json
     python scripts/obs_report.py traces.json            # /admin/traces snapshot
     python scripts/obs_report.py traces.json --perfetto merged.json
@@ -957,7 +957,7 @@ def report(path: str, perfetto: Optional[str] = None) -> None:
     downs = {
         k: v
         for k, v in counters.items()
-        if k.startswith(("gbdt.downgrade.", "gbdt.efb.downgrade"))
+        if k.startswith("gbdt.efb.downgrade")
     }
     if downs:
         _section("downgrades")

@@ -7,7 +7,6 @@ Usage: python scripts/tune_gbdt.py [n_trees] [rows]
 
 from __future__ import annotations
 
-import os
 import sys
 import time
 
@@ -17,15 +16,14 @@ import numpy as np
 def main() -> None:
     import jax
 
-    os.makedirs(".jax_cache", exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
     import jax.numpy as jnp
 
+    from ytklearn_tpu.compile_cache import configure_compile_cache
     from ytklearn_tpu.config.params import ApproximateSpec, GBDTParams, ModelParams
     from ytklearn_tpu.gbdt.data import GBDTData
     from ytklearn_tpu.gbdt.trainer import GBDTTrainer
 
+    configure_compile_cache()
     n_trees = int(sys.argv[1]) if len(sys.argv) > 1 else 12
     n = int(sys.argv[2]) if len(sys.argv) > 2 else 10_500_000
     F = 28

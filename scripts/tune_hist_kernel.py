@@ -8,7 +8,6 @@ i32 one-hot compares.
 
 from __future__ import annotations
 
-import os
 import sys
 import time
 from functools import partial
@@ -20,13 +19,13 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
-    os.makedirs(".jax_cache", exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(".jax_cache"))
-
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from ytklearn_tpu.compile_cache import configure_compile_cache
     from ytklearn_tpu.gbdt.hist import _hist_pallas, _hist_pallas_q
+
+    configure_compile_cache()
 
     n = 1280 * 8192  # 10.48M
     F, B, N = 28, 256, 32

@@ -14,8 +14,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 rank, nprocs, port, mode, workdir = (
     int(sys.argv[1]), int(sys.argv[2]), sys.argv[3], sys.argv[4], sys.argv[5],
 )

@@ -6,7 +6,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ytklearn_tpu.parallel import DATA_AXIS, collectives as coll, make_mesh
-from ytklearn_tpu.parallel.mesh import shard_map_compat as shard_map
 
 
 def test_psum_and_scatter_and_gather(mesh8):
@@ -24,7 +23,7 @@ def test_psum_and_scatter_and_gather(mesh8):
             ag = coll.all_gather(xs)
             return s * jnp.ones_like(xs), sc, ag
 
-        return shard_map(
+        return jax.shard_map(
             f,
             mesh=mesh8,
             in_specs=P(DATA_AXIS),
@@ -47,7 +46,7 @@ def _run_pargmax(mesh8, scores, payload):
             best, pay = coll.pargmax_tuple(s[0], {"v": p[0]})
             return jnp.array([best]), jnp.array([pay["v"]])
 
-        return shard_map(
+        return jax.shard_map(
             f,
             mesh=mesh8,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS)),

@@ -412,7 +412,7 @@ def test_obs_report_on_flight_dump(obs_on, health_env):
     with obs.span("gbdt.round", round=1):
         pass
     obs.inc("health.nan")
-    obs.inc("gbdt.downgrade.total")
+    obs.inc("gbdt.efb.downgrade")
     obs.gauge("mem.unit.host_rss_peak_bytes", 1 << 30)
     path = recorder.dump(reason="report-test")
     r = _run_script("obs_report.py", path)
@@ -420,7 +420,7 @@ def test_obs_report_on_flight_dump(obs_on, health_env):
     out = r.stdout
     assert "run-health report" in out and "(flight)" in out
     assert "health.nan" in out
-    assert "gbdt.downgrade.total" in out
+    assert "gbdt.efb.downgrade" in out
     assert "1.0 GiB" in out
     assert "gbdt.round" in out
 
@@ -434,7 +434,8 @@ def test_obs_report_on_jsonl_and_bench(obs_on, tmp_path):
     r = _run_script("obs_report.py", p)
     assert r.returncode == 0, r.stderr
     assert "(jsonl)" in r.stdout and "train.round" in r.stdout
-    r = _run_script("obs_report.py", os.path.join(REPO, "BENCH_r05.json"))
+    _bench_artifact(tmp_path, 5, 1.0)
+    r = _run_script("obs_report.py", str(tmp_path / "BENCH_r05.json"))
     assert r.returncode == 0, r.stderr
     assert "(bench)" in r.stdout and "trees_per_sec" in r.stdout
 
@@ -494,7 +495,8 @@ def test_check_bench_regress_pass_and_fail(tmp_path):
 
 
 def test_check_bench_regress_on_real_repo_artifacts():
-    """The gate must pass on the checked-in trajectory (r05 vs r03)."""
+    """The gate must pass on whatever artifacts are checked in (today too
+    few BENCH records to compare: it skips cleanly)."""
     r = _run_script("check_bench_regress.py")
     assert r.returncode == 0, r.stdout + r.stderr
 

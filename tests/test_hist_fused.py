@@ -18,7 +18,12 @@ import jax
 import jax.numpy as jnp
 
 from ytklearn_tpu.gbdt.engine import GrowSpec, make_grow_tree
-from ytklearn_tpu.gbdt.hist import hist_wave, hist_wave_gather, hist_wave_q
+from ytklearn_tpu.gbdt.hist import (
+    gather_table,
+    hist_wave,
+    hist_wave_gather,
+    hist_wave_q,
+)
 
 
 def _case(n=4096, F=6, B=16, seed=0):
@@ -60,8 +65,9 @@ def test_fused_kernel_matches_dense_f32():
     )
     got = np.asarray(
         hist_wave_gather(
-            jnp.asarray(rows), jnp.asarray(idx), jnp.asarray(pg),
-            jnp.asarray(gg), jnp.asarray(hg), jnp.asarray(ids), B,
+            gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
+            jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
+            jnp.asarray(ids), rows.shape[1], B,
             mode="mxu", use_bf16=False, bm_g=bm_g, interpret=True,
         )
     )
@@ -83,8 +89,9 @@ def test_fused_kernel_matches_dense_int8_exact():
     )
     got = np.asarray(
         hist_wave_gather(
-            jnp.asarray(rows), jnp.asarray(idx), jnp.asarray(pg),
-            jnp.asarray(gg), jnp.asarray(hg), jnp.asarray(ids), B,
+            gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
+            jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
+            jnp.asarray(ids), rows.shape[1], B,
             mode="int8", bm_g=bm_g, interpret=True,
         )
     )
@@ -93,17 +100,17 @@ def test_fused_kernel_matches_dense_int8_exact():
     # lands on the identical i32 sums
     got_dense = np.asarray(
         hist_wave_gather(
-            jnp.asarray(rows), jnp.asarray(idx), jnp.asarray(pg),
-            jnp.asarray(gg), jnp.asarray(hg), jnp.asarray(ids), B,
+            gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
+            jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
+            jnp.asarray(ids), rows.shape[1], B,
             mode="int8", bm_g=bm_g, force_dense=True,
         )
     )
     np.testing.assert_array_equal(got_dense, ref)
 
 
-def test_fused_kernel_int32_bins_dtype():
-    """B > 256 keeps the row matrix int32 — the kernel must gather and
-    one-hot that dtype too."""
+def test_fused_kernel_wide_bins():
+    """B > 256: bin ids past the uint8 range survive the int32 table."""
     rng = np.random.RandomState(7)
     n, F, B = 2048, 3, 512
     rows = rng.randint(0, B, size=(n, F)).astype(np.int32)
@@ -120,8 +127,9 @@ def test_fused_kernel_int32_bins_dtype():
     )
     got = np.asarray(
         hist_wave_gather(
-            jnp.asarray(rows), jnp.asarray(idx), jnp.asarray(pg),
-            jnp.asarray(gg), jnp.asarray(hg), jnp.asarray(ids), B,
+            gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
+            jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
+            jnp.asarray(ids), rows.shape[1], B,
             mode="int8", bm_g=256, interpret=True,
         )
     )

@@ -99,7 +99,7 @@ def test_cluster_launcher_two_ranks(tmp_path):
     _write_data(tmp_path)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["YTK_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["YTK_COORDINATOR_PORT"] = str(_free_port())
     env["YTK_MASTER_LOG"] = str(tmp_path / "master.log")
     env.pop("XLA_FLAGS", None)

@@ -300,8 +300,9 @@ def test_roofline_obs_identity(integrated):
     }
     from_obs = bench.gbdt_stats_from_obs(trainer, snapshot=integrated["snap"])
     assert from_obs  # came from gbdt.stat.* gauges, not the fallback
-    assert bench.roofline_fields(from_obs, 3) == bench.roofline_fields(
-        legacy_stats, 3
+    kind = "TPU v5 lite"  # peaks are looked up by device_kind
+    assert bench.roofline_fields(from_obs, 3, kind) == bench.roofline_fields(
+        legacy_stats, 3, kind
     )
 
 

@@ -3,7 +3,7 @@
 Two JAX-semantic rules (host-sync-in-jit, retrace-hazard) share a traced-
 scope analysis: a function is *traced* when it is jit-decorated
 (`@jax.jit`, `@partial(jax.jit, ...)`) or passed by name to
-`jax.jit` / `shard_map` / `shard_map_compat` / `pallas_call`, and
+`jax.jit` / `shard_map` / `pallas_call`, and
 everything lexically inside it (nested defs included) runs under the
 tracer. Parameters declared static (static_argnames/static_argnums) are
 concrete Python values and are excluded from the traced-value heuristics.
@@ -23,7 +23,7 @@ from .core import rule
 # ---------------------------------------------------------------------------
 
 _JIT_NAMES = {"jit", "pjit"}
-_WRAPPER_CALLS = {"jit", "pjit", "shard_map", "shard_map_compat",
+_WRAPPER_CALLS = {"jit", "pjit", "shard_map",
                   "pallas_call"}
 
 

@@ -31,7 +31,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.collectives import pargmax_tuple
-from ..parallel.mesh import DATA_AXIS, shard_map_compat
+from ..parallel.mesh import DATA_AXIS
 from .engine import split_kernel
 from .hist import hist_wave
 from .tree import Tree
@@ -92,7 +92,7 @@ def _make_level_step(mesh, F_pad: int, B: int, cfg, n_nodes: int):
         P(DATA_AXIS),  # feat_mask
     )
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=specs_in,
@@ -126,7 +126,7 @@ def _make_router(mesh, F_pad: int, n_nodes: int):
         return jnp.where(pos >= 0, new, -1)
 
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             route,
             mesh=mesh,
             in_specs=(P(DATA_AXIS, None), P(), P(), P(), P()),

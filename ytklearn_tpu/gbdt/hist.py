@@ -47,6 +47,21 @@ def _pad_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def tile_bins(bins_t, bm: int):
+    """(F, n) bin matrix -> (F, n/bm, 1, bm), the layout the full-scan and
+    routing kernels block over. 32-bit bins reshape for free. A uint8 matrix
+    is widened for the reshape and narrowed after it, behind an optimization
+    barrier so XLA cannot fold the pair away: its direct uint8 reshape into
+    a shape with a size-1 sublane dim compiles in time proportional to n
+    (139 s at 4.2M rows, most of the round program's ~9 min at 10.5M; with
+    the barrier 12 s at 4.2M — TPU v5 lite, libtpu 0.0.34)."""
+    F, n = bins_t.shape
+    if bins_t.dtype.itemsize >= 4:
+        return bins_t.reshape(F, n // bm, 1, bm)
+    wide = bins_t.astype(jnp.int32).reshape(F, n // bm, 1, bm)
+    return jax.lax.optimization_barrier(wide).astype(bins_t.dtype)
+
+
 @partial(jax.jit, static_argnames=("B", "bm", "fg", "use_bf16"))
 def _hist_pallas(
     bins4, pos, g, h, node_ids, B: int, bm: int, fg: int, use_bf16: bool
@@ -198,11 +213,7 @@ def hist_wave_q(
     N = node_ids.shape[0]
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu and not force_dense:
-        bins4 = (
-            bins_t
-            if bins_t.ndim == 4
-            else bins_t.reshape(F, bins_t.shape[1] // bm, 1, bm)
-        )
+        bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
         out = _hist_pallas_q(bins4, pos, gq, hq, node_ids, B, bm, _pick_fg(F))
     else:
         bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
@@ -258,11 +269,7 @@ def hist_wave(
     N = node_ids.shape[0]
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu and not force_dense:
-        bins4 = (
-            bins_t
-            if bins_t.ndim == 4
-            else bins_t.reshape(F, bins_t.shape[1] // bm, 1, bm)
-        )
+        bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
         out = _hist_pallas(
             bins4, pos, g, h, node_ids, B, bm, _pick_fg(F), use_bf16
         )
@@ -296,24 +303,31 @@ def hist_wave(
 
 BMG_DEFAULT = 1024  # gathered-tile rows (sublane dim of the NN dot)
 
+# The gather source is a (n, W) int32 table, W = F padded to whole 128-lane
+# tiles, because Mosaic (libtpu 0.0.34, v5e) refuses anything narrower for a
+# one-row DMA: a uint8 (n, 28) matrix tiles (8,128)(4,1) — four rows packed
+# per sublane word — and "Slice shape along dimension 0 must be aligned to
+# tiling (8), but is 1"; an int32 (n, 28) matrix tiles (1,128) and "Slice
+# shape along dimension 1 must be aligned to tiling (128), but is 28". The
+# price is 512 B of HBM per row per 128 features (5.4 GB at 10.5M x 28,
+# where the uint8 matrix is 0.29 GB); each DMA moves one 512 B tile row.
+GATHER_LANES = 128
 
-def _tpu_compiler_params(**kw):
-    """jax renamed TPUCompilerParams -> CompilerParams; the fused kernel
-    traces on CPU too (interpret-mode tests), so resolve at call time."""
-    from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
+def gather_table(bins_t):
+    """(F, n) bin matrix -> the fused kernel's (n, W) int32 row table."""
+    F = bins_t.shape[0]
+    rows = jnp.transpose(bins_t).astype(jnp.int32)
+    return jnp.pad(rows, ((0, 0), (0, _pad_to(F, GATHER_LANES) - F)))
 
 
 def _gather_grid_call(
-    rows, idx, pos_g, g_t, h_t, ids2, out_dtype, kernel, B, bm_g, interpret
+    rows, idx, pos_g, g_t, h_t, ids2, out_dtype, kernel, F, B, bm_g, interpret
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     R = idx.shape[0]
-    F = rows.shape[1]
     N = ids2.shape[0]
     assert R % bm_g == 0, (R, bm_g)
     return pl.pallas_call(
@@ -321,7 +335,7 @@ def _gather_grid_call(
         grid=(R // bm_g,),
         in_specs=[
             pl.BlockSpec((bm_g,), lambda t: (t,), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # rows stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # rows stay in HBM
             pl.BlockSpec((1, 1, bm_g), lambda t: (t, 0, 0)),
             pl.BlockSpec((1, 1, bm_g), lambda t: (t, 0, 0)),
             pl.BlockSpec((1, 1, bm_g), lambda t: (t, 0, 0)),
@@ -330,10 +344,10 @@ def _gather_grid_call(
         out_specs=pl.BlockSpec((F, 3 * N, B), lambda t: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((F, 3 * N, B), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((bm_g, F), rows.dtype),
+            pltpu.VMEM((bm_g, rows.shape[1]), rows.dtype),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -366,15 +380,16 @@ def _gather_rows_dma(idx_ref, rows_ref, scratch, sem, bm_g: int):
 
 
 @partial(
-    jax.jit, static_argnames=("B", "bm_g", "use_bf16", "interpret")
+    jax.jit, static_argnames=("F", "B", "bm_g", "use_bf16", "interpret")
 )
 def _hist_gather_pallas(
-    rows, idx, pos_g, g, h, node_ids, B: int, bm_g: int, use_bf16: bool,
-    interpret: bool,
+    rows, idx, pos_g, g, h, node_ids, F: int, B: int, bm_g: int,
+    use_bf16: bool, interpret: bool,
 ):
     """Fused gather+histogram, f32/bf16 MXU variant.
 
-    rows     (n, F) u8|i32 — ROW-major bin matrix (HBM resident)
+    rows     (n, W) i32    — gather_table(): ROW-major bins, HBM resident,
+                             features in lanes [0, F)
     idx      (R,) i32      — compacted row indices (R % bm_g == 0; slots
                              past the wave's row count point at row 0 and
                              are masked by pos_g = -1)
@@ -386,7 +401,6 @@ def _hist_gather_pallas(
     from jax import lax
 
     R = idx.shape[0]
-    F = rows.shape[1]
     N = node_ids.shape[0]
     cdt = jnp.bfloat16 if use_bf16 else jnp.float32
     prec = None if use_bf16 else jax.lax.Precision.HIGHEST
@@ -410,7 +424,7 @@ def _hist_gather_pallas(
         PV = jnp.concatenate([P * gv, P * hv, P], axis=0)  # (3N, bm_g)
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
         for f in range(F):
-            col = scratch[:, f : f + 1].astype(jnp.int32)  # (bm_g, 1)
+            col = scratch[:, f : f + 1]  # (bm_g, 1)
             OH = (col == iota_b).astype(cdt)  # (bm_g, B) row-major
             acc = lax.dot_general(
                 PV, OH, nn, precision=prec,
@@ -426,13 +440,15 @@ def _hist_gather_pallas(
                 out_ref[f, :, :] = out_ref[f, :, :] + acc
 
     return _gather_grid_call(
-        rows, idx, pos3, g3, h3, ids2, jnp.float32, kernel, B, bm_g, interpret
+        rows, idx, pos3, g3, h3, ids2, jnp.float32, kernel, F, B, bm_g,
+        interpret,
     )
 
 
-@partial(jax.jit, static_argnames=("B", "bm_g", "interpret"))
+@partial(jax.jit, static_argnames=("F", "B", "bm_g", "interpret"))
 def _hist_gather_pallas_q(
-    rows, idx, pos_g, gq, hq, node_ids, B: int, bm_g: int, interpret: bool
+    rows, idx, pos_g, gq, hq, node_ids, F: int, B: int, bm_g: int,
+    interpret: bool,
 ):
     """Fused gather+histogram, int8 variant (gq/hq are f32 integers in
     [-127, 127], caller owns the scales; i32 accumulation is exact and
@@ -441,7 +457,6 @@ def _hist_gather_pallas_q(
     from jax import lax
 
     R = idx.shape[0]
-    F = rows.shape[1]
     N = node_ids.shape[0]
     nn = (((1,), (0,)), ((), ()))
 
@@ -466,8 +481,7 @@ def _hist_gather_pallas_q(
         PV = jnp.concatenate([gv, hv, P], axis=0).astype(jnp.int8)  # (3N, bm_g)
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
         for f in range(F):
-            col = scratch[:, f : f + 1].astype(jnp.int32)
-            OH = (col == iota_b).astype(jnp.int8)  # (bm_g, B)
+            OH = (scratch[:, f : f + 1] == iota_b).astype(jnp.int8)  # (bm_g, B)
             acc = lax.dot_general(
                 PV, OH, nn, preferred_element_type=jnp.int32
             )  # (3N, B) i32
@@ -481,7 +495,8 @@ def _hist_gather_pallas_q(
                 out_ref[f, :, :] = out_ref[f, :, :] + acc
 
     return _gather_grid_call(
-        rows, idx, pos3, g3, h3, ids2, jnp.int32, kernel, B, bm_g, interpret
+        rows, idx, pos3, g3, h3, ids2, jnp.int32, kernel, F, B, bm_g,
+        interpret,
     )
 
 
@@ -492,6 +507,7 @@ def hist_wave_gather(
     g,
     h,
     node_ids,
+    F: int,
     B: int,
     mode: str = "mxu",
     use_bf16: bool = True,
@@ -499,27 +515,28 @@ def hist_wave_gather(
     force_dense: bool = False,
     interpret: bool = False,
 ):
-    """(N, F, B, 3) partial histograms over a compacted row subset.
+    """(N, F, B, 3) partial histograms over a compacted row subset of
+    `rows`, the gather_table() of the wave's (F, n) bin matrix.
 
     The TPU path runs the fused gather+hist kernel; off-TPU (unless
     `interpret` forces the Pallas interpreter, for tests) the same math
     runs as an explicit (R, F) gather + dense einsum — bit-identical in
     int8 mode. Output dtype matches hist_wave (f32) / hist_wave_q (i32).
     """
-    F = rows.shape[1]
     N = node_ids.shape[0]
     on_tpu = jax.default_backend() == "tpu"
     if (on_tpu and not force_dense) or interpret:
         if mode == "int8":
             out = _hist_gather_pallas_q(
-                rows, idx, pos_g, g, h, node_ids, B, bm_g, interpret
+                rows, idx, pos_g, g, h, node_ids, F, B, bm_g, interpret
             )
         else:
             out = _hist_gather_pallas(
-                rows, idx, pos_g, g, h, node_ids, B, bm_g, use_bf16, interpret
+                rows, idx, pos_g, g, h, node_ids, F, B, bm_g, use_bf16,
+                interpret,
             )
     else:
-        bt = jnp.transpose(jnp.take(rows, idx, axis=0)).astype(jnp.int32)
+        bt = jnp.transpose(jnp.take(rows, idx, axis=0)[:, :F])
         if mode == "int8":
             out = _hist_dense_q(bt, pos_g, g, h, node_ids, B)
         else:

@@ -92,11 +92,9 @@ def route_wave(
     if hi is None:
         hi = jnp.full((NW,), 2**30, jnp.int32)
     if jax.default_backend() == "tpu":
-        bins4 = (
-            bins_t
-            if bins_t.ndim == 4
-            else bins_t.reshape(F, bins_t.shape[1] // bm, 1, bm)
-        )
+        from .hist import tile_bins
+
+        bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
         return _route_pallas(
             bins4, pos, valid, nid,
             jnp.maximum(feat, 0), slot, lo, hi, lch, rch, bm,

@@ -1,9 +1,12 @@
 """ctypes bindings for the native C++ ingest parser (native/ytk_parse.cpp).
 
-The .so is compiled on demand with g++ (cached by source mtime under
-native/build/). Callers use `native_available()` and fall back to the pure
-Python parser when the toolchain is missing — the native path is an exact
-drop-in (same rows, same errors, same first-seen feature-name order; parity
+The .so is compiled on demand with g++ into native/build/, named by a hash
+of its source and compile command, so a changed source or flag is a new
+file and a binary from another tree is never picked up. A build or load
+failure raises: the python parser is ~10x slower, and a training run that
+silently fell back to it would not be the program the code claims to ship.
+`YTK_NO_NATIVE=1` is the one explicit way onto the python parser (exact
+drop-in: same rows, same errors, same first-seen feature-name order; parity
 enforced by tests/test_native_ingest.py).
 
 TPU-native framing: this is the runtime's data-loader component — the
@@ -17,6 +20,7 @@ from the mesh.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -32,61 +36,56 @@ log = logging.getLogger(__name__)
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "ytk_parse.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libytkparse.so")
+# no -march=native: the cached .so must run on whatever CPU the tree is
+# copied to next
+CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lock = threading.Lock()
 _lib = None
-_lib_failed = False
 
 
-def _build() -> bool:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
+def ensure_so(src: str, cmd: Sequence[str], stem: str) -> str:
+    """Path of native/build/<stem>-<hash of source bytes + compile
+    command>.so, compiled first if it is not there; raises
+    CalledProcessError/OSError when the compile fails."""
+    with open(src, "rb") as f:
+        tag = hashlib.sha256(f.read() + " ".join(cmd).encode()).hexdigest()[:12]
+    so = os.path.join(_REPO, "native", "build", f"{stem}-{tag}.so")
+    if os.path.exists(so):
+        return so
+    os.makedirs(os.path.dirname(so), exist_ok=True)
     # per-process temp name: concurrent builders (multi-host JAX on one
     # machine, parallel pytest) each compile privately, then atomically
     # promote — last os.replace wins, never a torn .so
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        "-march=native", _SRC, "-o", tmp,
-    ]
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (subprocess.SubprocessError, OSError) as e:  # toolchain missing / compile error -> fallback
-        err = getattr(e, "stderr", b"")
-        log.warning("native parser build failed (%s); using python parser: %s",
-                    e, err.decode()[:500] if err else "")
-        try:
+        subprocess.run(
+            [*cmd, src, "-o", tmp], check=True, capture_output=True, timeout=120
+        )
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return False
-    os.replace(tmp, _SO)
-    return True
+    return so
 
 
 def _load():
-    global _lib, _lib_failed
+    global _lib
     with _lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
         if knobs.get_bool("YTK_NO_NATIVE"):
-            _lib_failed = True
             return None
         try:
-            stale = (not os.path.exists(_SO)
-                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        except OSError:
-            stale = True
-        # ytklint: allow(blocking-call-under-lock) reason=first-touch build serialization is the point — every ingest thread must wait for the ONE compiler run instead of racing N compiles of the same .so
-        if stale and not _build():
-            _lib_failed = True
-            return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError as e:
-            log.warning("native parser load failed: %s", e)
-            _lib_failed = True
-            return None
+            # ytklint: allow(blocking-call-under-lock) reason=first-touch build serialization is the point — every ingest thread must wait for the ONE compiler run instead of racing N compiles of the same .so
+            so = ensure_so(_SRC, CXX, "libytkparse")
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                "native parser build failed (set YTK_NO_NATIVE=1 to opt "
+                "into the ~10x slower python parser): "
+                + e.stderr.decode(errors="replace")[:2000]
+            ) from e
+        lib = ctypes.CDLL(so)
         lib.ytk_parse.restype = ctypes.c_void_p
         lib.ytk_parse.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_char_p,
@@ -107,6 +106,7 @@ def _load():
 
 
 def native_available() -> bool:
+    """False only under YTK_NO_NATIVE=1; a broken toolchain raises."""
     return _load() is not None
 
 

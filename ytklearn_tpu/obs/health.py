@@ -583,7 +583,7 @@ def install_trace_counters() -> None:
         monitoring.register_event_duration_secs_listener(_on_duration)
         monitoring.register_event_listener(_on_event)
         _trace_counters_installed = True
-    except Exception as e:  # noqa: BLE001 — older jax without monitoring
+    except Exception as e:  # noqa: BLE001 — counters are evidence, never the run
         log.debug("trace counters unavailable: %s", e)
         _trace_counters_installed = True  # don't retry every call
 

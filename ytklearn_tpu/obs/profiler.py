@@ -451,7 +451,7 @@ def _install_ledger_listener() -> None:
 
         monitoring.register_event_duration_secs_listener(_on_duration)
         _ledger_listener_installed = True
-    except Exception as e:  # noqa: BLE001 — older jax without monitoring
+    except Exception as e:  # noqa: BLE001 — the ledger is evidence, never the run
         log.debug("compile ledger unavailable: %s", e)
         _ledger_listener_installed = True  # don't retry every call
 
@@ -647,7 +647,7 @@ def parse_trace_json(path: str) -> Optional[dict]:
     """Bucket one captured Chrome trace into per-annotation device time
     and a kernel aggregate.
 
-    Layout facts (verified against jax 0.4.x CPU + TPU captures):
+    Layout facts (from the captures this parser was written against):
       * thread_name/process_name metadata arrive as `ph:"M"` events;
       * python-side frames are `$`-prefixed; `TraceAnnotation` spans are
         the un-prefixed X events on python threads;

@@ -29,19 +29,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-def _to_varying(x, axes):
-    """Mark x varying over the given mesh axes (shard_map vma typing).
-    jax 0.9 deprecates lax.pvary in favor of lax.pcast(..., to="varying");
-    pre-vma jax (0.4.x) has neither and needs no marking — identity."""
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is not None:
-        return pc(x, axes, to="varying")
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None:
-        return pv(x, axes)
-    return x
-
-
 def _split(batch, row_mask):
     rows = tuple(a for a, r in zip(batch, row_mask) if r)
     consts = tuple(a for a, r in zip(batch, row_mask) if not r)
@@ -100,7 +87,7 @@ def chunked_value_and_grad(
         rows, consts = _split(batch, mask)
         xs, _ = _stack_chunks(rows, chunk)
         if vary_axes:
-            w = _to_varying(w, vary_axes)
+            w = lax.pcast(w, vary_axes, to="varying")
 
         def body(carry, ch):
             l, g = jax.value_and_grad(fn)(w, *_rebuild(mask, ch, consts))
@@ -108,7 +95,7 @@ def chunked_value_and_grad(
 
         init = (jnp.zeros((), w.dtype), jnp.zeros_like(w))
         if vary_axes:
-            init = (_to_varying(init[0], vary_axes), init[1])
+            init = (lax.pcast(init[0], vary_axes, to="varying"), init[1])
         (loss, grad), _ = lax.scan(body, init, xs)
         return loss, grad
 
@@ -134,7 +121,7 @@ def chunked_sum(
 
         init = jnp.zeros(())
         if vary_axes:
-            init = _to_varying(init, vary_axes)
+            init = lax.pcast(init, vary_axes, to="varying")
         loss, _ = lax.scan(body, init, xs)
         return loss
 
@@ -172,8 +159,6 @@ def mesh_chunked_value_and_grad(
     psum over the data axis — the reference's grad allreduce
     (optimizer/HoagOptimizer.java:1038) with the block loop inside each
     rank, matching its per-thread CoreData block walk."""
-    from ..parallel.mesh import shard_map_compat as shard_map
-
     mask = tuple(row_mask) if row_mask is not None else (True,) * n_batch
     cvg = chunked_value_and_grad(fn, chunk, mask, vary_axes=(axis,))
     in_specs = (P(), tuple(P(axis) if r else P() for r in mask))
@@ -185,7 +170,7 @@ def mesh_chunked_value_and_grad(
         loss, grad = cvg(w, *batch)
         return psum(loss, axis), psum(grad, axis)
 
-    sm = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    sm = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     return lambda w, *batch: sm(w, batch)
 
 
@@ -200,8 +185,6 @@ def mesh_chunked_sum(
     """`chunked_sum` per shard under shard_map + psum. Reshaping a
     row-sharded global array for the plain scan would make XLA all-gather
     the batch onto every device — this keeps each shard's chunks local."""
-    from ..parallel.mesh import shard_map_compat as shard_map
-
     mask = tuple(row_mask) if row_mask is not None else (True,) * n_batch
     cs = chunked_sum(fn, chunk, mask, vary_axes=(axis,))
     in_specs = (P(), tuple(P(axis) if r else P() for r in mask))
@@ -211,7 +194,7 @@ def mesh_chunked_sum(
     def local(w, batch):
         return psum(cs(w, *batch), axis)
 
-    sm = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P())
+    sm = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P())
     return lambda w, *batch: sm(w, batch)
 
 
@@ -225,8 +208,6 @@ def mesh_blocked_rows(
 ) -> Callable:
     """`blocked_rows` per shard under shard_map — per-row outputs stay
     row-sharded (out_specs P(axis)), no collective needed."""
-    from ..parallel.mesh import shard_map_compat as shard_map
-
     mask = tuple(row_mask) if row_mask is not None else (True,) * n_batch
     br = blocked_rows(fn, chunk, mask)
     in_specs = (P(), tuple(P(axis) if r else P() for r in mask))
@@ -234,7 +215,7 @@ def mesh_blocked_rows(
     def local(w, batch):
         return br(w, *batch)
 
-    sm = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(axis))
+    sm = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(axis))
     return lambda w, *batch: sm(w, batch)
 
 

@@ -88,7 +88,7 @@ def pargmax_tuple(score, payload, axis_name: str = DATA_AXIS):
     """
     record_collective("pargmax", (score, payload), axis_name)
     idx = lax.axis_index(axis_name)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     # NaN scores (split gains can be NaN from 0/0 hessian sums) are treated
     # as -inf so they can never win and never poison the pmax — HLO maximum
     # is NaN-propagating on some backends (VERDICT r1 Weak #4). All ranks
@@ -112,13 +112,6 @@ def pargmax_tuple(score, payload, axis_name: str = DATA_AXIS):
 
 def axis_index(axis_name: str = DATA_AXIS):
     return lax.axis_index(axis_name)
-
-
-def axis_size(axis_name: str = DATA_AXIS):
-    fn = getattr(lax, "axis_size", None)  # absent pre-0.5 jax
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)  # constant-folds to the axis size
 
 
 # ---------------------------------------------------------------------------

@@ -26,20 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "data"
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map across jax versions: the top-level export (with its
-    `check_vma` flag) landed after 0.4.x, where the API lives at
-    jax.experimental.shard_map with the flag spelled `check_rep`."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(n_devices: Optional[int] = None, axis_name: str = DATA_AXIS) -> Mesh:
     """1-D mesh over (a prefix of) the available devices."""
     devs = jax.devices()

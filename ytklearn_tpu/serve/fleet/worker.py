@@ -275,16 +275,12 @@ def stop_replica(h: ReplicaHandle, timeout_s: float = 30.0,
 
 
 def default_replica_count() -> int:
-    """`--replicas -1` / auto: one replica per accelerator device, or per
-    CPU core divided by two on the host backend (each CPU replica runs a
-    featurize thread + an XLA thread pool; 1:1 per core oversubscribes)."""
-    try:
-        import jax
-
-        if jax.default_backend() != "cpu":
-            return max(1, jax.local_device_count())
-    except Exception as e:  # noqa: BLE001 — sizing must work without a backend
-        log.warning("fleet: backend probe failed (%s); sizing by cpu count", e)
+    """`--replicas -1` / auto: one replica per two CPU cores (each replica
+    runs a featurize thread + an XLA thread pool; 1:1 per core
+    oversubscribes). The front never touches JAX: a process that
+    initialises the backend holds the chip, and its workers would then
+    fail or hang claiming it. Multi-replica fleets are CPU-host only until
+    each worker is given its own chip (docs/serving.md)."""
     return max(1, (os.cpu_count() or 2) // 2)
 
 

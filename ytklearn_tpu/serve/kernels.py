@@ -392,8 +392,7 @@ def _fused_call(xt, feat, sv, dleft, leaf, depth, binned, sentinel,
                 interpret):
     import jax
     from jax.experimental import pallas as pl
-
-    from ..gbdt.hist import _tpu_compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
     T, H = feat.shape
     LL = leaf.shape[1]
@@ -414,7 +413,7 @@ def _fused_call(xt, feat, sv, dleft, leaf, depth, binned, sentinel,
         ],
         out_specs=pl.BlockSpec((1, B), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, B), leaf.dtype),
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -489,49 +488,36 @@ def make_binned_xla(packed: np.ndarray, leaf: np.ndarray, depth: int,
 
 # ---------------------------------------------------------------------------
 # Native C++ binned kernel (native/ytk_serve.cpp) — the io/native.py idiom:
-# compiled on demand with g++, cached by source mtime, loudly optional.
+# compiled on demand with g++, cached by source+flags hash, loudly optional.
 # ---------------------------------------------------------------------------
 
-_REPO = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_SRC = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "native", "ytk_serve.cpp",
 )
-_SRC = os.path.join(_REPO, "native", "ytk_serve.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libytkserve.so")
 
 _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 
 
-def _build() -> bool:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    base = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        "-march=native", _SRC, "-o", tmp,
-    ]
+def _build() -> Optional[str]:
+    """Build (or find) the hash-named .so (io/native.py idiom); None when
+    no compile succeeds — serving then stays on the XLA walk."""
+    from ..io.native import CXX, ensure_so
+
     # OpenMP first (row-parallel scoring), plain second (the pragma is
     # ignored without it — single-threaded but still branchless+blocked)
-    for cmd in (base[:1] + ["-fopenmp"] + base[1:], base):
+    for cmd in (CXX + ["-fopenmp"], CXX):
         try:
-            # ytklint: allow(unseamed-io) reason=native-build allowlist; one-shot best-effort g++ compile with interpreter fallback, retries would just rebuild the same failure
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            return ensure_so(_SRC, cmd, "libytkserve")
         except (subprocess.SubprocessError, OSError) as e:
             err = getattr(e, "stderr", b"")
             log.warning(
                 "native serve kernel build failed (%s): %s", e,
                 err.decode()[:300] if err else "",
             )
-            continue
-        # ytklint: allow(unseamed-io) reason=native-build allowlist; pid-suffixed tmp commit in the build cache dir, not durable model/data state
-        os.replace(tmp, _SO)
-        return True
-    try:
-        # ytklint: allow(unseamed-io) reason=native-build allowlist; best-effort tmp cleanup after a failed compile
-        os.unlink(tmp)
-    except OSError:
-        pass
-    return False
+    return None
 
 
 def _load():
@@ -542,19 +528,13 @@ def _load():
         if knobs.get_bool("YTK_NO_NATIVE"):
             _lib_failed = True
             return None
-        try:
-            stale = (
-                not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            )
-        except OSError:
-            stale = True
-        # ytklint: allow(blocking-call-under-lock) reason=first-touch build serialization is the point — concurrent scorer lowerings must wait for the ONE compiler run instead of racing N compiles of the same .so (io/native.py precedent)
-        if stale and not _build():
+        # ytklint: allow(deep-blocking-under-lock) reason=first-touch build serialization is the point — concurrent scorer lowerings must wait for the ONE compiler run instead of racing N compiles of the same .so (io/native.py precedent)
+        so = _build()
+        if so is None:
             _lib_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             log.warning("native serve kernel load failed: %s", e)
             _lib_failed = True

@@ -740,8 +740,7 @@ class CompiledScorer:
 
     def _downgrade(self, kind: str, reason: str) -> None:
         """Named rung fallback: counter + flight-ring event + log — a
-        Mosaic/toolchain failure must be visible, never silent (the r6
-        gbdt.downgrade.* discipline)."""
+        Mosaic/toolchain failure must be visible, never silent."""
         obs_inc("serve.downgrade.total")
         obs_inc(f"serve.downgrade.{kind}")
         obs_event("serve.downgrade", kind=kind, reason=reason[:200])
