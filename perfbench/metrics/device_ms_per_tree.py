@@ -1,0 +1,2 @@
+"""Device busy milliseconds a tree in the traced window."""
+from pb.readers import device_ms_per_step as read  # noqa: F401

@@ -1,0 +1,60 @@
+"""Window arithmetic on synthetic step timestamps."""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from pb.window import Window, close_on_boundaries  # noqa: E402
+
+
+def boundaries(step_s, n, t0=100.0, stall_after=None, stall_s=0.0):
+    out, t = [(t0, 0)], t0
+    for i in range(1, n + 1):
+        t += step_s + (stall_s if stall_after == i else 0.0)
+        out.append((t, i))
+    return out
+
+
+def test_whole_steps_and_ends_on_a_boundary_at_or_after_seconds():
+    b = boundaries(7.1, 20)
+    w = close_on_boundaries(b, open_index=1, seconds=30.0)
+    assert w.steps == 5  # 4 steps are 28.4 s: too short; the fifth closes it
+    assert w.length_s == pytest.approx(35.5)
+    assert w.length_s >= 30.0 and w.overshoot_s == pytest.approx(5.5)
+    assert (w.t_close, w.steps_close) in [(t, float(s)) for t, s in b]
+    assert w.rate(1000) == pytest.approx(5 * 1000 / 35.5)
+    assert not w.exhausted
+
+
+def test_rate_is_the_same_wherever_the_edge_falls():
+    # a fixed-length window would hold 6 or 7 steps of 7.1 s in 45 s
+    rates = {round(close_on_boundaries(boundaries(7.1, 20, t0=t0), 1, 45.0).rate(), 9)
+             for t0 in (0.0, 1.3, 3.3, 6.9)}
+    assert len(rates) == 1
+
+
+def test_a_stall_inside_the_window_lowers_the_rate():
+    clean = close_on_boundaries(boundaries(1.0, 60), 2, 10.0)
+    stalled = close_on_boundaries(boundaries(1.0, 60, stall_after=5, stall_s=4.0), 2, 10.0)
+    assert clean.rate() == pytest.approx(1.0)
+    assert stalled.rate() < 0.75 and stalled.length_s >= 10.0
+
+
+def test_training_that_ends_first_is_the_whole_phase():
+    w = close_on_boundaries(boundaries(1.0, 5), 1, 30.0)
+    assert w.exhausted and w.steps == 4 and w.length_s == pytest.approx(4.0)
+
+
+def test_a_window_cannot_close_early_or_twice():
+    w = Window(10.0)
+    w.open(0.0, 3)
+    assert not w.due(9.99) and w.due(10.0)
+    with pytest.raises(RuntimeError):
+        w.close(5.0, 4)
+    w.close(12.0, 9)
+    with pytest.raises(RuntimeError):
+        w.close(13.0, 10)
+    with pytest.raises(RuntimeError):
+        Window(1.0).rate()
