@@ -6,7 +6,6 @@ the capture parser buckets device time under named annotations, flight
 dumps carry the prof block, and obs_report renders the checked-in PROF
 artifact."""
 
-import gzip
 import json
 import os
 import subprocess
@@ -33,7 +32,7 @@ def prof_on():
     yield profiler
     profiler.configure_profiler(on=False, capture_dir=None)
     profiler.reset_profiler()
-    obs_core.configure(enabled=False, jax_annotations=False)
+    obs_core.configure(enabled=False)
     obs.reset()
 
 
@@ -218,67 +217,54 @@ def test_mem_ring_bound_eviction_and_phase_attribution(prof_on):
 # ---------------------------------------------------------------------------
 
 
-def _synthetic_trace(tmp_path):
-    doc = {
-        "traceEvents": [
-            {"ph": "M", "name": "process_name", "pid": 1,
-             "args": {"name": "python"}},
-            {"ph": "M", "name": "thread_name", "pid": 1, "tid": 9,
-             "args": {"name": "python MainThread"}},
-            {"ph": "M", "name": "process_name", "pid": 2,
-             "args": {"name": "/device:CPU:0"}},
-            # annotation (lowercase dotted) + nested inner annotation
-            {"ph": "X", "name": "gbdt.train", "pid": 1, "tid": 9,
-             "ts": 0, "dur": 10_000},
-            {"ph": "X", "name": "gbdt.round", "pid": 1, "tid": 9,
-             "ts": 1_000, "dur": 4_000},
-            # interpreter / runtime noise that must NOT become annotations
-            {"ph": "X", "name": "$train_loop", "pid": 1, "tid": 9,
-             "ts": 0, "dur": 10_000},
-            {"ph": "X", "name": "ExecuteReplicated.__call__", "pid": 1,
-             "tid": 9, "ts": 500, "dur": 8_000},
-            # kernels: one inside gbdt.round (innermost wins), one inside
-            # only gbdt.train, one outside every annotation
-            {"ph": "X", "name": "dot.1", "pid": 2, "tid": 1, "ts": 2_000,
-             "dur": 1_000, "args": {"hlo_op": "dot.1"}},
-            {"ph": "X", "name": "add.2", "pid": 2, "tid": 1, "ts": 8_000,
-             "dur": 500, "args": {"hlo_op": "add.2"}},
-            {"ph": "X", "name": "copy.3", "pid": 2, "tid": 1, "ts": 90_000,
-             "dur": 250, "args": {"hlo_op": "copy.3"}},
-        ]
-    }
-    path = os.path.join(str(tmp_path), "t.trace.json.gz")
-    with gzip.open(path, "wt") as f:
-        json.dump(doc, f)
-    return path
+#: a small capture recorded by jax.profiler on the CPU backend (python
+#: tracer off): obs spans gbdt.train > gbdt.round x3 around a jitted
+#: matmul+sum, then one jitted tanh+sum outside every span
+CAPTURE = os.path.join(REPO, "tests", "data", "capture_cpu.xplane.pb")
 
 
-def test_parse_trace_json_buckets_device_time(tmp_path):
-    res = profiler.parse_trace_json(_synthetic_trace(tmp_path))
+def test_parse_xplane_buckets_device_time():
+    res = profiler.parse_xplane(CAPTURE)
+    # spans are the annotations that carry an id; runtime scopes
+    # (PjitFunction(step), PjRtCpuExecutable::Execute) are not
     assert set(res["annotations"]) == {"gbdt.train", "gbdt.round"}
-    # innermost-containing-annotation attribution (chrome ts/dur are µs)
-    assert res["span_device_ms"]["gbdt.round"] == pytest.approx(1.0)
-    assert res["span_device_ms"]["gbdt.train"] == pytest.approx(0.5)
-    assert res["kernels"]["copy.3"] == {"ms": 0.25, "count": 1}
-    assert sum(v["ms"] for v in res["kernels"].values()) == pytest.approx(
-        1.75
-    )
+    assert res["annotations"]["gbdt.train"] >= res["annotations"]["gbdt.round"] > 0
+    # three steps of the matmul, all inside gbdt.round (innermost wins:
+    # nothing is left for gbdt.train); the tanh ran outside every span
+    assert res["kernels"]["dot_general.1"]["count"] == 3
+    assert res["kernels"]["wrapped_tanh"]["count"] == 1
+    assert set(res["span_device_ms"]) == {"gbdt.round"}
+    total = sum(v["ms"] for v in res["kernels"].values())
+    outside = total - res["span_device_ms"]["gbdt.round"]
+    assert res["kernels"]["wrapped_tanh"]["ms"] <= outside < 0.1 * total
+    assert profiler.parse_xplane(os.path.join(REPO, "README.md")) is None
+
+
+def test_self_time_charges_a_loop_what_its_body_leaves():
+    ops = [(0.0, 100.0, "while"), (10.0, 30.0, "fusion.1"),
+           (50.0, 40.0, "fusion.2"), (150.0, 50.0, "fusion.1")]
+    got = {}
+    for _mid, name, self_ns in profiler._self_times(ops):
+        got[name] = got.get(name, 0.0) + self_ns
+    assert got == {"while": 30.0, "fusion.1": 80.0, "fusion.2": 40.0}
 
 
 def test_parse_capture_dir_and_topk(prof_on, tmp_path):
+    import shutil
+
     sub = os.path.join(str(tmp_path), "plugins", "profile", "run1")
     os.makedirs(sub)
-    doc_path = _synthetic_trace(sub)
+    shutil.copy(CAPTURE, os.path.join(sub, "host.xplane.pb"))
     assert profiler.parse_capture_dir(str(tmp_path)) is not None
+    assert profiler.parse_capture_dir(os.path.join(REPO, "docs")) is None
     # register it as a completed capture and merge through parse_captures
     profiler._captures.append(("gbdt.train", str(tmp_path)))
     merged = profiler.parse_captures(topk=2)
     assert merged["parsed"] == 1
     assert len(merged["top_kernels"]) == 2
-    assert merged["top_kernels"][0]["name"] == "dot.1"
-    assert merged["top_kernels"][0]["share"] == pytest.approx(1.0 / 1.75,
-                                                             abs=1e-3)
-    assert profiler.parse_trace_json(doc_path) is not None
+    assert merged["top_kernels"][0]["name"] == "dot_general.1"
+    assert merged["top_kernels"][0]["share"] > 0.5
+    assert merged["span_device_ms"]["gbdt.round"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -621,6 +621,8 @@ _PRODUCER_KINDS = {
     "gauge": "gauge", "obs_gauge": "gauge",
     "event": "event", "obs_event": "event",
     "span": "span", "obs_span": "span", "phase": "span",
+    "step_span": "span", "obs_step_span": "span",
+    "root_span": "span", "obs_root_span": "span",
     "hop": "span", "hop_at": "span", "batch_hop": "span",
 }
 

@@ -228,11 +228,15 @@ def train_main(argv: Optional[List[str]] = None) -> int:
             "last checkpoint"
         )
         restarts = 0
+    from . import obs
     from .resilience import Preempted
 
     for attempt in range(restarts + 1):
         try:
-            rc = _train_once(name, cfg, mesh, hook)
+            # the run's root span, around the data load too (the trainers
+            # ask for the same root and find it open)
+            with obs.root_span("train.run", family=name):
+                rc = _train_once(name, cfg, mesh, hook)
             _flush_trace(args.trace_out)
             return rc
         except Preempted as e:

@@ -122,9 +122,6 @@ _knob("YTK_EFB_CONFLICT", "int", 0,
 _knob("YTK_OBS", "str", None,
       "`1` enables obs collection without export; `0` force-disables "
       "(wins over the trace-path knobs)")
-_knob("YTK_OBS_JAX", "bool", False,
-      "wrap obs spans in jax.profiler.TraceAnnotation so they show up "
-      "inside XLA/xprof traces")
 _knob("YTK_TRACE", "str", None,
       "enable obs + write a Chrome-trace/Perfetto JSON to this path at exit")
 _knob("YTK_TRACE_JSONL", "str", None,

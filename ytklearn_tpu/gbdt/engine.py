@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.scopes import scope
 from .hist import (
     BMG_DEFAULT,
     compact_indices,
@@ -467,15 +468,16 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             # to f32 rounding, and an ulp between shards reorders gain-
             # ordered selection: on four chips at the Higgs width the mesh
             # tree came out different from the single-chip tree.
-            dev = jax.lax.axis_index(axis)
-            g0, h0 = node_totals(hists)
-            tot = psum(jnp.where(dev == 0, jnp.stack([g0, h0]), 0.0), axis)
-            out = split_kernel(
-                hists, fmask_loc, cfg, ranges_loc, totals=(tot[0], tot[1])
-            )
-            gflat = out[1] + dev * (F_loc * B)
-            chg, payload = pargmax_tuple(out[0], (gflat,) + out[2:], axis)
-            return (chg,) + payload
+            with scope("gbdt.split"):
+                dev = jax.lax.axis_index(axis)
+                g0, h0 = node_totals(hists)
+                tot = psum(jnp.where(dev == 0, jnp.stack([g0, h0]), 0.0), axis)
+                out = split_kernel(
+                    hists, fmask_loc, cfg, ranges_loc, totals=(tot[0], tot[1])
+                )
+                gflat = out[1] + dev * (F_loc * B)
+                chg, payload = pargmax_tuple(out[0], (gflat,) + out[2:], axis)
+                return (chg,) + payload
     else:
 
         def combine_hist(local):
@@ -485,7 +487,8 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             return None if rlo_g is None else (rlo_g, rhi_g)
 
         def best_splits(hists, fmask_loc, ranges_loc=None):
-            return split_kernel(hists, fmask_loc, cfg, ranges_loc)
+            with scope("gbdt.split"):
+                return split_kernel(hists, fmask_loc, cfg, ranges_loc)
 
     def can_split(fr: _Frontier, tr: TreeArrays, leaves):
         ok = fr.active & jnp.isfinite(fr.chg) & (fr.chg > spec.min_split_loss)
@@ -861,30 +864,31 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             )
 
             # routing (train + any aux sets)
-            if spec.force_dense:
-                pos = _route_wave(
-                    bins_t, pos, sel_ok, nid, f_best, slot_l, sel_lo, sel_hi,
-                    lch, rch, nw,
-                )
-                aux_pos = tuple(
-                    _route_wave(
-                        bt, ap, sel_ok, nid, f_best, slot_l, sel_lo, sel_hi,
-                        lch, rch, nw,
+            with scope("gbdt.route"):
+                if spec.force_dense:
+                    pos = _route_wave(
+                        bins_t, pos, sel_ok, nid, f_best, slot_l, sel_lo,
+                        sel_hi, lch, rch, nw,
                     )
-                    for bt, ap in zip(aux, aux_pos)
-                )
-            else:
-                pos = route_wave(
-                    bins_k, pos, sel_ok, nid, f_best, slot_l, lch, rch,
-                    bm=spec.bm, lo=sel_lo, hi=sel_hi,
-                )
-                aux_pos = tuple(
-                    route_wave(
-                        bt, ap, sel_ok, nid, f_best, slot_l, lch, rch,
+                    aux_pos = tuple(
+                        _route_wave(
+                            bt, ap, sel_ok, nid, f_best, slot_l, sel_lo,
+                            sel_hi, lch, rch, nw,
+                        )
+                        for bt, ap in zip(aux, aux_pos)
+                    )
+                else:
+                    pos = route_wave(
+                        bins_k, pos, sel_ok, nid, f_best, slot_l, lch, rch,
                         bm=spec.bm, lo=sel_lo, hi=sel_hi,
                     )
-                    for bt, ap in zip(aux_k, aux_pos)
-                )
+                    aux_pos = tuple(
+                        route_wave(
+                            bt, ap, sel_ok, nid, f_best, slot_l, lch, rch,
+                            bm=spec.bm, lo=sel_lo, hi=sel_hi,
+                        )
+                        for bt, ap in zip(aux_k, aux_pos)
+                    )
 
             # smaller-child histogram + sibling subtraction
             small = jnp.where(CLs <= CRs, lch, rch)

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.scopes import scope
+
 
 # sample-block width: the Pallas grid's lane-major tile. 16384 measured
 # ~13% faster than 8192 at the Higgs shape (fewer grid steps amortize the
@@ -107,6 +109,7 @@ def _hist_pallas(
 
     out = pl.pallas_call(
         kernel,
+        name="gbdt_hist_scan",
         grid=(F // fg, nblk),
         in_specs=[
             pl.BlockSpec((fg, 1, 1, bm), lambda fo, k: (fo, k, 0, 0)),
@@ -172,6 +175,7 @@ def _hist_pallas_q(bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int):
 
     return pl.pallas_call(
         kernel,
+        name="gbdt_hist_scan_q",
         grid=(F // fg, nblk),
         in_specs=[
             pl.BlockSpec((fg, 1, 1, bm), lambda fo, k: (fo, k, 0, 0)),
@@ -212,14 +216,15 @@ def hist_wave_q(
     F = bins_t.shape[0]
     N = node_ids.shape[0]
     on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and not force_dense:
-        bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
-        out = _hist_pallas_q(bins4, pos, gq, hq, node_ids, B, bm, _pick_fg(F))
-    else:
-        bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
-        out = _hist_dense_q(bins2, pos, gq, hq, node_ids, B)
-    out = out.reshape(F, 3, N, B)
-    return jnp.transpose(out, (2, 0, 3, 1))
+    with scope("gbdt.hist"):
+        if on_tpu and not force_dense:
+            bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
+            out = _hist_pallas_q(bins4, pos, gq, hq, node_ids, B, bm, _pick_fg(F))
+        else:
+            bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
+            out = _hist_dense_q(bins2, pos, gq, hq, node_ids, B)
+        out = out.reshape(F, 3, N, B)
+        return jnp.transpose(out, (2, 0, 3, 1))
 
 
 @partial(jax.jit, static_argnames=("B", "use_bf16"))
@@ -268,17 +273,18 @@ def hist_wave(
     F = bins_t.shape[0]
     N = node_ids.shape[0]
     on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and not force_dense:
-        bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
-        out = _hist_pallas(
-            bins4, pos, g, h, node_ids, B, bm, _pick_fg(F), use_bf16
-        )
-    else:
-        bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
-        out = _hist_dense(bins2, pos, g, h, node_ids, B, use_bf16)
-    # (F, 3N, B) -> (N, F, B, 3)
-    out = out.reshape(F, 3, N, B)
-    return jnp.transpose(out, (2, 0, 3, 1))
+    with scope("gbdt.hist"):
+        if on_tpu and not force_dense:
+            bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
+            out = _hist_pallas(
+                bins4, pos, g, h, node_ids, B, bm, _pick_fg(F), use_bf16
+            )
+        else:
+            bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
+            out = _hist_dense(bins2, pos, g, h, node_ids, B, use_bf16)
+        # (F, 3N, B) -> (N, F, B, 3)
+        out = out.reshape(F, 3, N, B)
+        return jnp.transpose(out, (2, 0, 3, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -332,6 +338,7 @@ def _gather_grid_call(
     assert R % bm_g == 0, (R, bm_g)
     return pl.pallas_call(
         kernel,
+        name="gbdt_hist_gather",
         grid=(R // bm_g,),
         in_specs=[
             pl.BlockSpec((bm_g,), lambda t: (t,), memory_space=pltpu.SMEM),
@@ -525,24 +532,25 @@ def hist_wave_gather(
     """
     N = node_ids.shape[0]
     on_tpu = jax.default_backend() == "tpu"
-    if (on_tpu and not force_dense) or interpret:
-        if mode == "int8":
-            out = _hist_gather_pallas_q(
-                rows, idx, pos_g, g, h, node_ids, F, B, bm_g, interpret
-            )
+    with scope("gbdt.hist"):
+        if (on_tpu and not force_dense) or interpret:
+            if mode == "int8":
+                out = _hist_gather_pallas_q(
+                    rows, idx, pos_g, g, h, node_ids, F, B, bm_g, interpret
+                )
+            else:
+                out = _hist_gather_pallas(
+                    rows, idx, pos_g, g, h, node_ids, F, B, bm_g, use_bf16,
+                    interpret,
+                )
         else:
-            out = _hist_gather_pallas(
-                rows, idx, pos_g, g, h, node_ids, F, B, bm_g, use_bf16,
-                interpret,
-            )
-    else:
-        bt = jnp.transpose(jnp.take(rows, idx, axis=0)[:, :F])
-        if mode == "int8":
-            out = _hist_dense_q(bt, pos_g, g, h, node_ids, B)
-        else:
-            out = _hist_dense(bt, pos_g, g, h, node_ids, B, use_bf16)
-    out = out.reshape(F, 3, N, B)
-    return jnp.transpose(out, (2, 0, 3, 1))
+            bt = jnp.transpose(jnp.take(rows, idx, axis=0)[:, :F])
+            if mode == "int8":
+                out = _hist_dense_q(bt, pos_g, g, h, node_ids, B)
+            else:
+                out = _hist_dense(bt, pos_g, g, h, node_ids, B, use_bf16)
+        out = out.reshape(F, 3, N, B)
+        return jnp.transpose(out, (2, 0, 3, 1))
 
 
 def compact_indices(mask, R: int):

@@ -62,6 +62,7 @@ def _route_pallas(bins4, pos, valid, nid, feat, slot, lo, hi, lch, rch, bm: int)
 
     return pl.pallas_call(
         kernel,
+        name="gbdt_route",
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -30,6 +30,7 @@ TPU-first design notes:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import time
@@ -54,7 +55,10 @@ from ..obs import (
     inc as obs_inc,
     profiler,
     recorder,
+    root_span as obs_root_span,
+    scopes as obs_scopes,
     span as obs_span,
+    step_span as obs_step_span,
 )
 from ..parallel.mesh import row_sharding
 from ..resilience import chaos_point, trainer_guard
@@ -140,6 +144,23 @@ def node_hist_kernel(bins, in_node, g, h, F: int, B: int):
 # ---------------------------------------------------------------------------
 
 
+@contextlib.contextmanager
+def _sync_span(step: int, **args):
+    """A `gbdt.sync` span: it lasts as long as the host stands waiting for
+    the device, and `gbdt.sync_wait_s` is the sum of their durations."""
+    with obs_span("gbdt.sync", step=step, **args) as sp:
+        yield sp
+    obs_inc("gbdt.sync_wait_s", sp.dur)
+
+
+@jax.jit
+def sync_slice(buf, rnd):
+    """One round's entry of a per-round loss buffer: the small program a
+    sync enqueues, under a name of its own (`jit_sync_slice` on the
+    trace's module line)."""
+    return buf[rnd]
+
+
 @dataclass
 class _DevInputs:
     """Device-resident training inputs prepared once per run (the device
@@ -160,6 +181,11 @@ class _DevInputs:
     y_t: Optional[jnp.ndarray]
     w_t: Optional[jnp.ndarray]
     nt_score: int
+
+    def device_arrays(self) -> tuple:
+        """What the preprocess span settles on."""
+        return (self.bins_t, self.y, self.weight, self.real_mask,
+                self.aux_bins, self.y_t, self.w_t)
 
 
 @dataclass
@@ -336,7 +362,9 @@ class GBDTTrainer:
         # boundary, where the loop dumps an emergency checkpoint through
         # the ordinary atomic dump path and raises Preempted — `--resume
         # auto` re-enters here via continue_train (docs/fault_tolerance.md)
-        with trainer_guard(self):
+        # `train.run`: the root of every span of the run (the benchmark
+        # enters here; the CLI has opened it around the data load already)
+        with obs_root_span("train.run", family="gbdt"), trainer_guard(self):
             if self.engine == "device":
                 return self._train_device(train, test)
             if jax.process_count() > 1:
@@ -731,17 +759,18 @@ class GBDTTrainer:
                         tr, pos_train, y, scores, weight, real_mask,
                         p.learning_rate,
                     )
-                add = tr.leaf[pos_train]
-                if K > 1:
-                    scores = scores.at[:, grp].add(add)
-                else:
-                    scores = scores + add
-                if has_test:
-                    add_t = tr.leaf[aux_pos[0]]
+                with obs_scopes.scope("gbdt.score_update"):
+                    add = tr.leaf[pos_train]
                     if K > 1:
-                        scores_t = scores_t.at[:, grp].add(add_t)
+                        scores = scores.at[:, grp].add(add)
                     else:
-                        scores_t = scores_t + add_t
+                        scores = scores + add
+                    if has_test:
+                        add_t = tr.leaf[aux_pos[0]]
+                        if K > 1:
+                            scores_t = scores_t.at[:, grp].add(add_t)
+                        else:
+                            scores_t = scores_t + add_t
                 t_idx = rnd * K + grp
                 for name in (
                     "feat", "slot", "slot_r", "left", "right",
@@ -780,13 +809,14 @@ class GBDTTrainer:
         """AOT-compile the round program once; the compiled object is
         reused for every round, so this is not a second compile. A
         Mosaic/XLA failure raises: a kernel that does not compile is
-        repaired, never routed around."""
-        return jit_round.lower(
+        repaired, never routed around. The compiled HLO is where the
+        scope map (which instruction is `gbdt.hist`, ...) is read from."""
+        return obs_scopes.compile_lowered(jit_round.lower(
             carry,
             jnp.asarray(start_round),
             jax.random.fold_in(jax.random.PRNGKey(20170425), start_round),
             data,
-        ).compile()
+        ))
 
     def _export_wave_stats(self, ts: dict, dd: "_DevInputs", spec: GrowSpec):
         """Analytic device-cost totals from the engine's wave log — the
@@ -885,8 +915,9 @@ class GBDTTrainer:
         ago, so the float() costs host time only, with zero device idle
         (the queue stays ~2 windows deep; watch mode keeps the synchronous
         path since its metric evals fetch eagerly anyway). What a sync
-        costs on today's chip is not measured; the lag stays until
-        something measures it."""
+        costs is measured: a `gbdt.sync` span lasts as long as the host
+        stood waiting for the device (counter `gbdt.sync_wait_s`), and the
+        ends of two successive ones are a device-settled sync window."""
         p = self.params
         K = self.K
         root_key = jax.random.PRNGKey(20170425)
@@ -914,42 +945,51 @@ class GBDTTrainer:
         profile_dir = knobs.get_str("YTK_PROFILE_DIR")
         if profile_dir:
             jax.profiler.start_trace(profile_dir)
-        t_train0 = time.time()
+        self._t_train0 = time.time()
         pending: Optional[
             Tuple[int, jnp.ndarray, Optional[jnp.ndarray], float]
         ] = None
+        synced = start_round - 1  # last round a sync point was taken at
         for rnd in range(start_round, p.round_num):
             if self._guard is not None and self._guard.triggered:
                 # round boundary = the safe preemption point: fetching the
                 # tree buffers drains every enqueued round, so the dump
                 # holds exactly the completed rounds and the resumed run
                 # re-enters at `rnd` bit-identically (round-indexed RNG)
-                self._preempt_checkpoint(
-                    model, carry[2], dd.bins, feature_names, rnd
-                )
+                with obs_span("gbdt.preempt", step=rnd):
+                    self._preempt_checkpoint(
+                        model, carry[2], dd.bins, feature_names, rnd
+                    )
             # enqueue-side span: the round program is async, so this
             # measures dispatch (device time shows up in the sync spans)
-            with obs_span("gbdt.round", round=rnd), profiler.LEDGER.program(
+            with obs_step_span("gbdt.round", rnd, round=rnd), profiler.LEDGER.program(
                 "gbdt.round",
                 sig_fn=lambda: profiler.abstract_signature(carry, data),
             ):
+                rnd_dev = jnp.asarray(rnd)
                 carry = jit_round(
-                    carry, jnp.asarray(rnd), jax.random.fold_in(root_key, rnd), data
+                    carry, rnd_dev, jax.random.fold_in(root_key, rnd), data
                 )
             obs_inc("gbdt.rounds")
             if (rnd + 1) % sync_every == 0 or rnd == p.round_num - 1:
                 if watch_eval is None:
-                    nxt = (
-                        rnd,
-                        carry[3][rnd],
-                        carry[4][rnd] if has_test else None,
-                        time.time(),  # sync-point host time, not emission
-                    )
-                    if pending is not None:
-                        self._emit_sync(pending, t0)
-                    pending = nxt
+                    # the span holds the slices' dispatch (and, the first
+                    # time, their compile) and the host's wait for the
+                    # loss enqueued one window earlier
+                    with _sync_span(rnd, rounds=rnd - synced, lagged=True) as sp:
+                        nxt = (
+                            rnd,
+                            sync_slice(carry[3], rnd_dev),
+                            sync_slice(carry[4], rnd_dev) if has_test else None,
+                            time.time(),  # sync-point host time, not emission
+                        )
+                        if pending is not None:
+                            sp.add(round=pending[0])
+                            self._emit_sync(pending, t0)
+                        pending = nxt
                 else:
-                    self._sync_report(rnd, carry, dd, watch_eval, t0)
+                    self._sync_report(rnd, carry, dd, watch_eval, t0, rnd - synced)
+                synced = rnd
             if p.model.dump_freq > 0 and (rnd + 1) % p.model.dump_freq == 0:
                 self._append_trees_from_bufs(
                     model, carry[2], dd.bins, feature_names,
@@ -957,13 +997,14 @@ class GBDTTrainer:
                 )
                 self._dump_model(model)
         if pending is not None:
-            self._emit_sync(pending, t0)
+            with _sync_span(pending[0], round=pending[0], rounds=0, lagged=True):
+                self._emit_sync(pending, t0)
 
         if profile_dir:
             jax.block_until_ready(carry[0])
             jax.profiler.stop_trace()
             log.info("jax profiler trace written to %s", profile_dir)
-        ts["train"] = time.time() - t_train0
+        ts["train"] = time.time() - self._t_train0
         if self.sync_log:
             # skip the first sync window: it absorbs the one-time XLA compile
             r0, s0 = self.sync_log[1] if len(self.sync_log) >= 3 else self.sync_log[0]
@@ -988,7 +1029,11 @@ class GBDTTrainer:
         health.record_memory("gbdt.load")
         K = self.K
 
-        with profiler.phase("gbdt.preprocess", F=train.n_features):
+        dd = None
+        with profiler.phase(
+            "gbdt.preprocess", settle=lambda: dd.device_arrays(),
+            F=train.n_features,
+        ):
             dd = self._prep_device_inputs(train, test)
         health.record_memory("gbdt.preprocess")
         bins = dd.bins
@@ -996,32 +1041,42 @@ class GBDTTrainer:
         ts["preprocess"] = time.time() - t0 - ts["load"]
         log.info("load+preprocess %.1fs", time.time() - t0)
 
-        # GOSS sizing discounts sample-axis padding (real-row fraction of
-        # the per-process padded shard; top_k needs a static k, so the
-        # engine can't count real rows itself)
-        n_pad_local = dd.n_score // max(jax.process_count(), 1)
-        goss_scale = min(1.0, train.n_real / max(n_pad_local, 1))
-        spec = self._grow_spec(dd.F_prog, dd.B, goss_scale=goss_scale)
+        # everything between binning and the compile (grow spec, base
+        # score, resume, initial scores, tree buffers, the round step's
+        # construction) under one device-settled span
+        scores = scores_t = bufs = loss_buf = tloss_buf = None
+        with profiler.phase(
+            "gbdt.prepare",
+            settle=lambda: (scores, scores_t, bufs, loss_buf, tloss_buf),
+        ):
+            # GOSS sizing discounts sample-axis padding (real-row fraction
+            # of the per-process padded shard; top_k needs a static k, so
+            # the engine can't count real rows itself)
+            n_pad_local = dd.n_score // max(jax.process_count(), 1)
+            goss_scale = min(1.0, train.n_real / max(n_pad_local, 1))
+            spec = self._grow_spec(dd.F_prog, dd.B, goss_scale=goss_scale)
+            self._wave_ctx = (dd, spec)  # what the stop path publishes with
 
-        base_np = self._base_score(train, K)
-        model = GBDTModel(
-            base_prediction=float(np.mean(base_np)),
-            num_tree_in_group=K,
-            obj_name=self.loss.name,
-        )
-        model, start_round = self._load_resume_model(
-            model, K, feature_names=train.feature_names
-        )
-        scores, scores_t = self._init_device_scores(model, dd, base_np)
-        bufs, loss_buf, tloss_buf = self._make_tree_bufs(spec.max_nodes)
+            base_np = self._base_score(train, K)
+            model = GBDTModel(
+                base_prediction=float(np.mean(base_np)),
+                num_tree_in_group=K,
+                obj_name=self.loss.name,
+            )
+            model, start_round = self._load_resume_model(
+                model, K, feature_names=train.feature_names
+            )
+            scores, scores_t = self._init_device_scores(model, dd, base_np)
+            bufs, loss_buf, tloss_buf = self._make_tree_bufs(spec.max_nodes)
 
-        has_test = test is not None
-        # big arrays ride as explicit args (closure capture would bake them
-        # into the program as constants); test arrays fold into `data`
-        data = (dd.bins_t, y, weight, dd.real_mask) + (
-            (dd.aux_bins[0], y_t, w_t) if has_test else ()
-        )
-        jit_round = self._build_round_step(dd, spec, has_test)
+            has_test = test is not None
+            # big arrays ride as explicit args (closure capture would bake
+            # them into the program as constants); test arrays fold into
+            # `data`
+            data = (dd.bins_t, y, weight, dd.real_mask) + (
+                (dd.aux_bins[0], y_t, w_t) if has_test else ()
+            )
+            jit_round = self._build_round_step(dd, spec, has_test)
 
         if p.just_evaluate:
             return self._finalize_device(
@@ -1071,13 +1126,17 @@ class GBDTTrainer:
                 if "trees_per_sec_steady" in ts else ""
             ),
         )
-        # mirror every scalar time_stat into the registry (gbdt.stat.*) —
-        # the ONE snapshot bench roofline accounting reads, so benchmarks
-        # and production runs report from the same source of truth
+        self._publish_time_stats(ts)
+        return out
+
+    @staticmethod
+    def _publish_time_stats(ts: dict) -> None:
+        """Mirror every scalar time_stat into the registry (gbdt.stat.*) —
+        the ONE snapshot bench roofline accounting reads, so benchmarks
+        and production runs report from the same source of truth."""
         for k, v in ts.items():
             if isinstance(v, (bool, int, float)):
                 obs_gauge(f"gbdt.stat.{k}", float(v))
-        return out
 
     def _health_sync(self, rnd: int, tl: float) -> None:
         """Sentinels at a pipeline sync: NaN/inf train loss (strict mode
@@ -1094,11 +1153,19 @@ class GBDTTrainer:
             self._retrace.check(sig=sig, round=rnd)
 
     def _preempt_checkpoint(self, model, bufs, bins, names, rnd: int) -> None:
-        """Emergency checkpoint at round boundary `rnd`, then Preempted."""
+        """Emergency checkpoint at round boundary `rnd`, then Preempted.
+        The wave-log counters and the `gbdt.stat.*` gauges are published
+        first, through the functions the normal end uses: a stopped run's
+        registry says what it did."""
         self._append_trees_from_bufs(
             model, bufs, bins, names, len(model.trees), rnd * self.K
         )
         self._dump_model(model)
+        ts = self.time_stats
+        ts["train"] = time.time() - self._t_train0
+        self.wave_log = np.asarray(jax.device_get(bufs["wlog"]))
+        self._export_wave_stats(ts, *self._wave_ctx)
+        self._publish_time_stats(ts)
         if knobs.get_str("YTK_PROFILE_DIR"):
             # the Preempted raise skips the post-loop stop_trace: close the
             # profiler here or the very run being profiled loses its trace
@@ -1120,8 +1187,7 @@ class GBDTTrainer:
         chaos_point("gbdt.sync")
         rnd, loss_dev, tloss_dev, t_sync = pending
         obs_inc("gbdt.syncs")
-        with obs_span("gbdt.sync", round=rnd, lagged=True):
-            tl = float(loss_dev)  # completed a window ago: one RTT, no stall
+        tl = float(loss_dev)  # completed a window ago: one RTT, no stall
         self._health_sync(rnd, tl)
         elapsed = t_sync - t0
         self.sync_log.append((rnd, elapsed))
@@ -1130,7 +1196,9 @@ class GBDTTrainer:
             msg += f" test loss={float(tloss_dev):.6f}"
         log.info(msg)
 
-    def _sync_report(self, rnd: int, carry, dd: "_DevInputs", watch_eval, t0):
+    def _sync_report(
+        self, rnd: int, carry, dd: "_DevInputs", watch_eval, t0, rounds: int,
+    ):
         """Pipeline sync + progress line (+ watch-flag metrics at sync
         points — reference: EvalSet per round when watch_train/watch_test;
         here per sync so the enqueue pipeline stays deep between syncs).
@@ -1139,7 +1207,7 @@ class GBDTTrainer:
         p = self.params
         chaos_point("gbdt.sync")
         obs_inc("gbdt.syncs")
-        with obs_span("gbdt.sync", round=rnd, lagged=False):
+        with _sync_span(rnd, round=rnd, rounds=rounds, lagged=False):
             tl = float(carry[3][rnd])  # syncs the pipeline
         self._health_sync(rnd, tl)
         elapsed = time.time() - t0

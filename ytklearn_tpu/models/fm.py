@@ -21,6 +21,7 @@ import numpy as np
 
 from ..config.params import CommonParams
 from ..io.reader import SparseDataset
+from ..obs.scopes import scope
 from .base import ConvexModel, random_init
 
 
@@ -70,7 +71,11 @@ class FMModel(ConvexModel):
     def scores(self, w, *xargs):
         idx, val = xargs
         w = self._apply_mask(w)
-        wx = jnp.sum(val * w[: self.v_start][idx], axis=-1)
+        # the two gathers under scopes of their own: autodiff hands the
+        # name on to their transposes, the two scatter-adds of the gradient
+        with scope("fm.gather_w"):
+            w1x = w[: self.v_start][idx]
+        wx = jnp.sum(val * w1x, axis=-1)
         if not self.need_second_order:
             return wx
         # k-major latent gather: the (k, n, width) intermediate keeps width
@@ -78,7 +83,9 @@ class FMModel(ConvexModel):
         # (8->128, 16x) — the k-minor layout is what OOM'd BENCH_r04
         # (f32[2M*39,8] lane-padded to 39.9 GB)
         Vt = w[self.v_start :].reshape(self.n_features, self.sok).T  # (k, nf)
-        vx = Vt[:, idx] * val[None]  # (k, n, width)
+        with scope("fm.gather_v"):
+            vg = Vt[:, idx]
+        vx = vg * val[None]  # (k, n, width)
         S = jnp.sum(vx, axis=-1)  # Σ v x            (k, n)
         S2 = jnp.sum(vx * vx, axis=-1)  # Σ (v x)^2  (k, n)
         return wx + 0.5 * jnp.sum(S * S - S2, axis=0)

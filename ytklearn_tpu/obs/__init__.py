@@ -2,7 +2,19 @@
 
 Public surface (see docs/observability.md):
 
-  span(name, settle=None, **attrs)   nested wall-clock span (ctx manager)
+  span(name, settle=None, step=None, **attrs)
+                                     nested wall-clock span (ctx manager)
+                                     with id, parent id and step; also a
+                                     jax.profiler TraceAnnotation
+  root_span(name, **attrs)           the run's root (`train.run`), opened
+                                     once however many layers ask for it
+  step_span(name, step, **attrs)     a span that is a step of the loop
+                                     (StepTraceAnnotation in a device trace)
+  spans_between(t0, t1)              finished spans of an interval given on
+                                     time.perf_counter
+  scopes                             names of the program's own on the
+                                     device: named scopes, AOT programs and
+                                     the instruction -> scope map
   inc(name, value=1.0)               counter add
   gauge(name, value)                 gauge set
   event(name, **attrs)               instant trace marker
@@ -69,6 +81,7 @@ from .core import (  # noqa: F401
     Registry,
     Span,
     configure,
+    current_span,
     enabled,
     event,
     flush,
@@ -76,9 +89,12 @@ from .core import (  # noqa: F401
     inc,
     record_collective,
     reset,
+    root_span,
     set_identity,
     snapshot,
     span,
+    spans_between,
+    step_span,
 )
 from .export import (  # noqa: F401
     chrome_trace_events,
@@ -93,6 +109,6 @@ from .heartbeat import (  # noqa: F401
     start_history_sampler,
     stop_history_sampler,
 )
-from . import health, profiler, recorder, trace  # noqa: F401
+from . import health, profiler, recorder, scopes, trace  # noqa: F401
 from .health import HealthError, SLOBurnSentinel  # noqa: F401
 from .trace import TRACE_HEADER, configure_tracing  # noqa: F401

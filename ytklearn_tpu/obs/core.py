@@ -6,7 +6,11 @@ this module replaces all of that with three primitives every layer shares:
 
   spans     nested wall-clock intervals (`with span("tree.grow", tree=t):`)
             with optional device-settled timing (`settle=` blocks on a jax
-            value before the end timestamp is taken)
+            value before the end timestamp is taken). A span records its
+            own id, its parent's id and the `step` (boosting round, L-BFGS
+            iteration) it belongs to, and is a `jax.profiler`
+            TraceAnnotation carrying both, so that it lies in a device
+            trace on the device trace's clock.
   counters  monotonically accumulated floats (`inc("ingest.rows", n)`)
   gauges    last-write-wins floats (`gauge("gbdt.partition", 1)`)
 
@@ -24,13 +28,12 @@ Env knobs (read once at import; `configure()` overrides at runtime):
   YTK_TRACE_JSONL=path  enable + write the JSONL event stream at exit
   YTK_OBS=1             enable collection without any export
   YTK_OBS=0             force-disable (wins over the path knobs)
-  YTK_OBS_JAX=1         also wrap spans in jax.profiler.TraceAnnotation so
-                        they show up inside XLA/xprof traces
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import math
 import os
 import threading
@@ -48,6 +51,26 @@ WALL_T0 = time.time()
 
 def _now() -> float:
     return time.perf_counter() - _T0
+
+
+# span ids: one process-wide sequence (next() on a C iterator is atomic
+# under the interpreter lock)
+_span_ids = itertools.count(1)
+
+# jax.profiler's annotation classes, looked up once when collection is
+# first enabled (`_load_annotations`), never inside a span. With no
+# profiler session live an annotation is a flag test in C++.
+_TraceAnnotation = None
+_StepTraceAnnotation = None
+
+
+def _load_annotations() -> None:
+    global _TraceAnnotation, _StepTraceAnnotation
+    if _TraceAnnotation is not None:
+        return
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+    _TraceAnnotation, _StepTraceAnnotation = TraceAnnotation, StepTraceAnnotation
 
 
 class Registry:
@@ -188,13 +211,12 @@ def set_identity(**kw) -> None:
 
 
 class _State:
-    __slots__ = ("enabled", "trace_path", "jsonl_path", "jax_annotations")
+    __slots__ = ("enabled", "trace_path", "jsonl_path")
 
     def __init__(self):
         self.enabled = False
         self.trace_path: Optional[str] = None
         self.jsonl_path: Optional[str] = None
-        self.jax_annotations = False
 
 
 _state = _State()
@@ -219,6 +241,8 @@ class _NoopSpan:
     def add(self, **kw):
         return self
 
+    dur = 0.0
+
 
 NOOP_SPAN = _NoopSpan()
 
@@ -229,31 +253,44 @@ class Span:
     `settle` (array, pytree, or zero-arg callable returning one) is
     block_until_ready'd before the end timestamp — opt-in device-settled
     timing for spans that enqueue async device work.
-    """
 
-    __slots__ = ("name", "args", "t0", "_settle", "_jax_ann")
+    `step` is the boosting round or L-BFGS iteration the span belongs to;
+    a span given none inherits its parent's. The thread-local stack holds
+    the open spans themselves, so a child reads its parent's id and step
+    from the top of it. `dur` is the recorded duration, once closed."""
 
-    def __init__(self, name: str, args: dict, settle=None):
+    __slots__ = ("name", "args", "t0", "dur", "id", "parent", "step",
+                 "_settle", "_is_step", "_ann")
+
+    def __init__(self, name: str, args: dict, settle=None, step=None,
+                 is_step: bool = False):
         self.name = name
         self.args = args
+        self.id = next(_span_ids)
+        self.parent = None
+        self.step = step
         self._settle = settle
-        self._jax_ann = None
+        self._is_step = is_step
 
     def add(self, **kw) -> "Span":
         self.args.update(kw)
         return self
 
     def __enter__(self) -> "Span":
-        if _state.jax_annotations:
-            try:
-                import jax.profiler
-
-                self._jax_ann = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ann.__enter__()
-            # ytklint: allow(broad-except) reason=profiler annotation is best-effort decoration; a broken profiler must not fail the span
-            except Exception:
-                self._jax_ann = None
-        REGISTRY._stack().append(self.name)
+        stack = REGISTRY._stack()
+        if stack:
+            top = stack[-1]
+            self.parent = top.id
+            if self.step is None:
+                self.step = top.step
+        stack.append(self)
+        if self._is_step:
+            self._ann = _StepTraceAnnotation(self.name, step_num=self.step, id=self.id)
+        elif self.step is not None:
+            self._ann = _TraceAnnotation(self.name, id=self.id, step=self.step)
+        else:
+            self._ann = _TraceAnnotation(self.name, id=self.id)
+        self._ann.__enter__()
         self.t0 = _now()
         return self
 
@@ -267,13 +304,8 @@ class Span:
             # ytklint: allow(broad-except) reason=settle targets may be deleted/donated by exit time; timing must never kill the run
             except Exception:
                 pass
-        t1 = _now()
-        if self._jax_ann is not None:
-            try:
-                self._jax_ann.__exit__(exc_type, exc, tb)
-            # ytklint: allow(broad-except) reason=profiler exit is best-effort; the span event must still be recorded below
-            except Exception:
-                pass
+        self.dur = _now() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
         stack = REGISTRY._stack()
         if stack:
             stack.pop()
@@ -281,10 +313,14 @@ class Span:
             "name": self.name,
             "ph": "X",
             "ts": self.t0,
-            "dur": t1 - self.t0,
+            "dur": self.dur,
             "tid": threading.get_ident(),
             "depth": len(stack),
+            "id": self.id,
+            "parent": self.parent,
         }
+        if self.step is not None:
+            ev["step"] = self.step
         if self.args:
             ev["args"] = self.args
         if exc_type is not None:
@@ -293,14 +329,69 @@ class Span:
         return False
 
 
-def span(name: str, settle=None, **args):
+def span(name: str, settle=None, step=None, **args):
     """`with span("tree.grow", tree=t): ...` — no-op when obs is disabled.
 
-    `settle` is reserved: pass a jax value (or a callable producing one)
-    to block on it before the end timestamp (device-settled duration)."""
+    `settle`: a jax value (or a callable producing one) to block on before
+    the end timestamp (device-settled duration). `step`: the round or
+    iteration this span belongs to; children inherit it."""
     if not _state.enabled:
         return NOOP_SPAN
-    return Span(name, args, settle)
+    return Span(name, args, settle, step)
+
+
+def step_span(name: str, step, settle=None, **args):
+    """A span that IS a step of the training loop (`gbdt.round`,
+    `lbfgs.iteration`): as `span(..., step=step)`, and in a device trace a
+    `StepTraceAnnotation` with `step_num`, which the profiler's own tools
+    read as the step boundary."""
+    if not _state.enabled:
+        return NOOP_SPAN
+    return Span(name, args, settle, step, is_step=True)
+
+
+def root_span(name: str, **args):
+    """`span(name)`, unless the calling thread already stands inside a span
+    of that name: an entry point (the CLI, around the data load) and the
+    layer under it (a trainer, which the benchmark calls directly) both ask
+    for the run's root, and one is recorded."""
+    if not _state.enabled or any(s.name == name for s in REGISTRY._stack()):
+        return NOOP_SPAN
+    return Span(name, args)
+
+
+def current_span() -> Optional[Span]:
+    """The innermost open span of the calling thread, if any."""
+    stack = REGISTRY._stack()
+    return stack[-1] if stack else None
+
+
+def spans_between(t0: float, t1: float) -> List[dict]:
+    """The finished spans that overlap [t0, t1], both given on
+    `time.perf_counter`: each as {name, id, parent, step, start, end, tid,
+    args}, start and end on that same clock and not clipped. What a reader
+    of a measured interval needs, without `REGISTRY.events` or the clock
+    origin."""
+    lo, hi = t0 - _T0, t1 - _T0
+    with REGISTRY._lock:
+        evs = [
+            ev for ev in REGISTRY.events
+            if ev["ph"] == "X" and "id" in ev
+            and ev["ts"] <= hi and ev["ts"] + ev["dur"] >= lo
+        ]
+    return [
+        {
+            "name": ev["name"],
+            "id": ev["id"],
+            "parent": ev["parent"],
+            "step": ev.get("step"),
+            "start": ev["ts"] + _T0,
+            "end": ev["ts"] + ev["dur"] + _T0,
+            "tid": ev["tid"],
+            "args": dict(ev.get("args") or {}),
+        }
+        for ev in evs
+    ]
 
 
 def inc(name: str, value: float = 1.0) -> None:
@@ -373,7 +464,7 @@ def record_collective(verb: str, x, axis_name: str) -> None:
     census of the program's collective surface. That is exactly what you
     want when debugging a hung multi-host collective ("which verbs, what
     sizes, staged from where"); per-step collective wall time lives in the
-    XLA profile (YTK_OBS_JAX=1 / YTK_PROFILE_DIR)."""
+    XLA profile (YTK_PROFILE_DIR)."""
     if not _state.enabled:
         return
     nbytes = _leaf_bytes(x)
@@ -423,7 +514,6 @@ def configure(
     enabled: Optional[bool] = None,
     trace_path=_UNSET,
     jsonl_path=_UNSET,
-    jax_annotations: Optional[bool] = None,
 ) -> None:
     """Runtime configuration (the CLI's --trace-out lands here).
 
@@ -438,9 +528,9 @@ def configure(
         if jsonl_path and enabled is None:
             enabled = True
     if enabled is not None:
+        if enabled:
+            _load_annotations()
         _state.enabled = bool(enabled)
-    if jax_annotations is not None:
-        _state.jax_annotations = bool(jax_annotations)
     if _state.trace_path or _state.jsonl_path:
         _ensure_atexit()
 
@@ -453,8 +543,6 @@ def _configure_from_env() -> None:
     jsonl = knobs.get_str("YTK_TRACE_JSONL")
     if trace or jsonl or flag == "1":
         configure(enabled=True, trace_path=trace, jsonl_path=jsonl)
-    if knobs.get_bool("YTK_OBS_JAX"):
-        _state.jax_annotations = True
 
 
 _configure_from_env()

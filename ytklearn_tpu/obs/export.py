@@ -66,8 +66,12 @@ def chrome_trace_events(registry: Registry = REGISTRY) -> List[dict]:
             if ev["ph"] == "i":
                 rec["s"] = "t"  # thread-scoped instant
             end_ts = max(end_ts, ts_us)
-        if ev.get("args"):
-            rec["args"] = ev["args"]
+        args = dict(ev.get("args") or {})
+        for k in ("id", "parent", "step"):  # the span's own identity
+            if ev.get(k) is not None:
+                args[k] = ev[k]
+        if args:
+            rec["args"] = args
         out.append(rec)
     for name, value in sorted(counters.items()):
         out.append(

@@ -558,6 +558,11 @@ def install_trace_counters() -> None:
       compile.traces.backend_compile_secs   cumulative seconds
       compile.traces.jaxpr_trace            python->jaxpr traces
       compile.traces.cache_hits             persistent-cache hits
+
+    Every backend compile also drops an instant `compile` event naming the
+    innermost open span of the compiling thread (`span=`, `span_id=`), the
+    function compiled (`program=`), its seconds and whether the persistent
+    cache served it: which step of the loop compiled, with obs alone.
     """
     global _trace_counters_installed
     if _trace_counters_installed:
@@ -565,12 +570,26 @@ def install_trace_counters() -> None:
     try:
         import jax.monitoring as monitoring
 
+        # a cache hit is reported inside the compile it serves, on the
+        # compiling thread, before that compile's duration
+        tls = threading.local()
+
         def _on_duration(event: str, duration: float, **kw) -> None:
             if not core.enabled():
                 return
             if event.endswith("backend_compile_duration"):
                 core.inc("compile.traces.backend_compile")
                 core.inc("compile.traces.backend_compile_secs", duration)
+                sp = core.current_span()
+                core.event(
+                    "compile",
+                    span=sp.name if sp is not None else None,
+                    span_id=sp.id if sp is not None else None,
+                    program=kw.get("fun_name"),
+                    secs=duration,
+                    cache_hit=getattr(tls, "hit", False),
+                )
+                tls.hit = False
             elif event.endswith("jaxpr_trace_duration"):
                 core.inc("compile.traces.jaxpr_trace")
 
@@ -579,6 +598,7 @@ def install_trace_counters() -> None:
                 return
             if "cache_hit" in event:
                 core.inc("compile.traces.cache_hits")
+                tls.hit = True
 
         monitoring.register_event_duration_secs_listener(_on_duration)
         monitoring.register_event_listener(_on_event)

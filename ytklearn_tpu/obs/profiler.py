@@ -37,8 +37,6 @@ YTK_PROF_MEM_S, YTK_PROF_LEDGER_N. The CLI's `--profile [DIR]` lands on
 from __future__ import annotations
 
 import collections
-import gzip
-import json
 import logging
 import os
 import re
@@ -619,101 +617,101 @@ MEM = MemWatermarkSampler()
 
 
 # ---------------------------------------------------------------------------
-# Trace-capture parser (Chrome-trace JSON written by jax.profiler.trace)
+# Trace-capture parser (the *.xplane.pb that jax.profiler writes)
 # ---------------------------------------------------------------------------
 
-
-def _load_trace_doc(path: str) -> Optional[dict]:
-    try:
-        if path.endswith(".gz"):
-            with gzip.open(path, "rt") as fh:
-                return json.load(fh)
-        from ..io.fs import LocalFileSystem  # lazy: fs pulls the retry seam, which imports obs
-
-        with LocalFileSystem().open(path) as fh:
-            return json.load(fh)
-    except Exception as e:  # partial/corrupt captures are skipped, not fatal
-        log.debug("trace parse failed for %s: %s", path, e)
-        return None
-
-
 #: obs span names are lowercase dotted identifiers ("gbdt.train",
-#: "serve.score"); anything else on a python thread is interpreter or
+#: "serve.score"); in a capture a span is a TraceAnnotation that carries the
+#: span's `id` — anything else on a host thread is interpreter or
 #: jax-runtime noise
 _ANN_NAME = re.compile(r"^[a-z][a-z0-9_.\-]*$")
 
 
-def parse_trace_json(path: str) -> Optional[dict]:
-    """Bucket one captured Chrome trace into per-annotation device time
-    and a kernel aggregate.
+def _event_stats(ev) -> dict:
+    try:
+        return dict(ev.stats)
+    # ytklint: allow(broad-except) reason=a stat the binding cannot decode; the event still counts by name and time
+    except Exception:
+        return {}
+
+
+def _self_times(ops: List[Tuple[float, float, str]]) -> List[Tuple[float, str, float]]:
+    """(start, dur, name) device ops -> (midpoint, name, self ns) each: a
+    `while` around its body's ops is charged what they leave over."""
+    out: List[Tuple[float, str, float]] = []
+    stack: List[list] = []  # [start, end, name, child_ns]
+
+    def pop():
+        start, end, name, child = stack.pop()
+        out.append(((start + end) / 2.0, name, max(end - start - child, 0.0)))
+        if stack:
+            stack[-1][3] += end - start
+
+    for start, dur, name in sorted(ops, key=lambda e: (e[0], -e[1])):
+        while stack and stack[-1][1] <= start:
+            pop()
+        stack.append([start, start + dur, name, 0.0])
+    while stack:
+        pop()
+    return out
+
+
+def parse_xplane(path: str) -> Optional[dict]:
+    """Bucket one captured `*.xplane.pb` into per-annotation device time
+    and a kernel aggregate, through `jax.profiler.ProfileData`.
 
     Layout facts (from the captures this parser was written against):
-      * thread_name/process_name metadata arrive as `ph:"M"` events;
-      * python-side frames are `$`-prefixed; `TraceAnnotation` spans are
-        the un-prefixed X events on python threads;
-      * device work is X events carrying `args.hlo_op` (CPU runtime
-        thread) or living under a `/device:` process (TPU).
+      * obs spans are the host-plane (`/host:...`) events with a lowercase
+        dotted name and an `id` stat (obs/core.py makes every span a
+        TraceAnnotation carrying its id);
+      * device work is the `XLA Ops` line of a `/device:...` plane (TPU: an
+        event is named by its whole HLO text, `%fusion.3 = f32[...] ...`;
+        the instruction name is kept), or, in a CPU capture, the host-plane
+        events that carry an `hlo_op` stat.
 
     Returns {"annotations": {name: ms}, "span_device_ms": {name: ms},
     "kernels": {name: {"ms", "count"}}} or None if unreadable."""
-    doc = _load_trace_doc(path)
-    if doc is None:
+    try:
+        from jax.profiler import ProfileData
+
+        pd = ProfileData.from_file(path)
+    except Exception as e:  # partial/corrupt captures are skipped, not fatal
+        log.debug("xplane parse failed for %s: %s", path, e)
         return None
-    events = doc.get("traceEvents", doc if isinstance(doc, list) else [])
-    thread_names: Dict[Tuple[int, int], str] = {}
-    proc_names: Dict[int, str] = {}
-    for ev in events:
-        if ev.get("ph") != "M":
+    ann: List[Tuple[float, float, str]] = []  # (lo, hi, name)
+    ops: List[Tuple[float, float, str]] = []  # (start, dur, name)
+    for plane in pd.planes:
+        device = plane.name.startswith("/device:")
+        if not device and not plane.name.startswith("/host:"):
             continue
-        if ev.get("name") == "thread_name":
-            thread_names[(ev.get("pid"), ev.get("tid"))] = (
-                ev.get("args", {}).get("name", "")
-            )
-        elif ev.get("name") == "process_name":
-            proc_names[ev.get("pid")] = ev.get("args", {}).get("name", "")
-    ann_events: List[dict] = []
-    kernel_events: List[dict] = []
-    for ev in events:
-        if ev.get("ph") != "X" or "dur" not in ev:
-            continue
-        name = ev.get("name", "")
-        args = ev.get("args") or {}
-        pname = proc_names.get(ev.get("pid"), "")
-        if "hlo_op" in args or "/device:" in pname or "Device" in pname:
-            kernel_events.append(ev)
-            continue
-        tname = thread_names.get((ev.get("pid"), ev.get("tid")), "")
-        if not _ANN_NAME.match(name):
-            # python interpreter frames ($-prefixed), C++ runtime scopes
-            # (Foo::Bar), jax-internal python TraceMes (jit(f),
-            # ExecuteReplicated.__call__) — neither a user annotation nor
-            # device work; obs span names are lowercase dotted identifiers
-            continue
-        if "python" in tname.lower() or not thread_names:
-            ann_events.append(ev)
+        for line in plane.lines:
+            if device and line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                if device:
+                    name = ev.name.split(" = ", 1)[0].lstrip("%")
+                    ops.append((ev.start_ns, ev.duration_ns, name))
+                    continue
+                st = _event_stats(ev)
+                if "hlo_op" in st:
+                    ops.append((ev.start_ns, ev.duration_ns, str(st["hlo_op"])))
+                elif "id" in st and _ANN_NAME.match(ev.name):
+                    ann.append((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name))
     annotations: Dict[str, float] = {}
-    for ev in ann_events:
-        annotations[ev["name"]] = (
-            annotations.get(ev["name"], 0.0) + ev["dur"] / 1000.0
-        )
+    for lo, hi, name in ann:
+        annotations[name] = annotations.get(name, 0.0) + (hi - lo) / 1e6
     # innermost-containing-annotation attribution: smallest annotation
-    # interval whose [ts, ts+dur) contains the kernel midpoint
-    intervals = sorted(
-        ((ev["ts"], ev["ts"] + ev["dur"], ev["name"]) for ev in ann_events),
-        key=lambda iv: iv[1] - iv[0],
-    )
+    # interval that contains the kernel's midpoint
+    ann.sort(key=lambda iv: iv[1] - iv[0])
     span_device: Dict[str, float] = {}
     kernels: Dict[str, dict] = {}
-    for ev in kernel_events:
-        mid = ev["ts"] + ev["dur"] / 2.0
-        ms = ev["dur"] / 1000.0
-        kname = ev.get("name", "?")
+    for mid, kname, self_ns in _self_times(ops):
         k = kernels.setdefault(kname, {"ms": 0.0, "count": 0})
-        k["ms"] += ms
+        k["ms"] += self_ns / 1e6
         k["count"] += 1
-        for lo, hi, name in intervals:
+        for lo, hi, name in ann:
             if lo <= mid < hi:
-                span_device[name] = span_device.get(name, 0.0) + ms
+                span_device[name] = span_device.get(name, 0.0) + self_ns / 1e6
                 break
     return {
         "annotations": {k: round(v, 3) for k, v in annotations.items()},
@@ -726,17 +724,17 @@ def parse_trace_json(path: str) -> Optional[dict]:
 
 
 def parse_capture_dir(root: str) -> Optional[dict]:
-    """Find + parse the newest `*.trace.json(.gz)` under a capture dir
-    (jax nests them below plugins/profile/<run>/)."""
+    """Find + parse the newest `*.xplane.pb` under a capture dir (jax
+    nests them below plugins/profile/<run>/)."""
     newest, newest_m = None, -1.0
     for dirpath, _dirs, files in os.walk(root):
         for fn in files:
-            if fn.endswith(".trace.json.gz") or fn.endswith(".trace.json"):
+            if fn.endswith(".xplane.pb"):
                 p = os.path.join(dirpath, fn)
                 m = os.path.getmtime(p)
                 if m > newest_m:
                     newest, newest_m = p, m
-    return parse_trace_json(newest) if newest else None
+    return parse_xplane(newest) if newest else None
 
 
 def parse_captures(topk: Optional[int] = None) -> dict:
@@ -873,12 +871,12 @@ def flight_block() -> Optional[dict]:
 
 
 def _activate() -> None:
-    """Arm everything the plane rides on: obs collection (spans), jax
-    TraceAnnotations (so captures carry span names), the health compile
-    counters, the ledger listener, and the watermark sampler."""
+    """Arm everything the plane rides on: obs collection (spans, which
+    are jax TraceAnnotations, so captures carry span names), the health
+    compile counters, the ledger listener, and the watermark sampler."""
     from . import health
 
-    core.configure(enabled=True, jax_annotations=True)
+    core.configure(enabled=True)
     health.install_trace_counters()
     _install_ledger_listener()
     MEM.start()

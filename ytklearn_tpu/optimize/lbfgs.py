@@ -38,7 +38,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..obs import health, inc as obs_inc, profiler, span as obs_span
+from ..obs import (
+    health,
+    inc as obs_inc,
+    profiler,
+    span as obs_span,
+    step_span as obs_step_span,
+)
+from ..obs.scopes import Program
 
 _MODES = {"sufficient_decrease": 0, "wolfe": 1, "strong_wolfe": 2}
 
@@ -303,12 +310,10 @@ def _build_programs(
     def two_loop(g, S, Y, ys_arr, cursor, hist_len):
         return _two_loop_core(g, S, Y, ys_arr, cursor, hist_len, m)
 
-    @jax.jit
     def first_eval(w, reg, batch):
         pure, loss, g = lg(w, reg, batch)
         return pure, loss, g, jnp.linalg.norm(w), jnp.linalg.norm(g)
 
-    @jax.jit
     def iteration(state: LBFGSState, reg: Reg, batch):
         """One full L-BFGS iteration: direction from history -> line search
         -> history update (reference main loop :566-715)."""
@@ -355,10 +360,14 @@ def _build_programs(
         )
         return new_state, jnp.linalg.norm(w), jnp.linalg.norm(g)
 
-    _PROGRAMS[key] = (first_eval, iteration)
+    # compiled ahead of time under their own names (`jit_first_eval`,
+    # `jit_iteration` on a device trace's module line), the compiled HLO at
+    # hand for the scope map (obs/scopes.py)
+    programs = (Program(first_eval), Program(iteration))
+    _PROGRAMS[key] = programs
     while len(_PROGRAMS) > _PROGRAMS_MAX:
         _PROGRAMS.popitem(last=False)
-    return first_eval, iteration
+    return programs
 
 
 def minimize_lbfgs(
@@ -427,7 +436,8 @@ def minimize_lbfgs(
         sig_fn=lambda: profiler.abstract_signature(w0, reg, batch),
     ):
         pure, loss, g, wnorm, gnorm = first_eval(jnp.asarray(w0, dtype), reg, batch)
-    wnorm = max(float(wnorm), 1.0)
+        wnorm = max(float(wnorm), 1.0)  # the fetch settles the span
+    obs_inc("lbfgs.passes")  # the first evaluation is one data pass
     state = LBFGSState(
         w=jnp.asarray(w0, dtype),
         g=g,
@@ -462,35 +472,44 @@ def minimize_lbfgs(
         for it in range(1, config.max_iter + 1):
             # the span's ls_status fetch doubles as the device sync the loop
             # needs anyway — the duration is device-settled for free
-            with obs_span("lbfgs.iteration", it=it), profiler.LEDGER.program(
+            with obs_step_span("lbfgs.iteration", it, it=it) as sp, profiler.LEDGER.program(
                 "lbfgs.iteration",
                 sig_fn=lambda: profiler.abstract_signature(state, reg, batch),
             ):
                 state, wnorm, gnorm = iteration(state, reg, batch)
                 ls = int(state.ls_status)
-            obs_inc("lbfgs.iterations")
-            if health_on:
-                # outside the span so a strict escalation's flight dump
-                # carries the failing iteration's completed span in its ring
-                loss_val = float(state.loss)
-                if not health.check_loss("lbfgs.loss", loss_val, it=it):
-                    status = "nan_loss"
+                # every line-search trial is one loss+gradient pass over
+                # the data (a failed search reports its status, -1..-3,
+                # and counts as that many: it ends the run anyway)
+                sp.add(passes=abs(ls))
+            # the host's part of the step (counters, sentinels, the caller's
+            # callback, the convergence test) under a span of that step:
+            # while it runs the device has nothing to do
+            with obs_span("lbfgs.host", step=it):
+                obs_inc("lbfgs.iterations")
+                obs_inc("lbfgs.passes", abs(ls))
+                if health_on:
+                    # after the iteration's span, so a strict escalation's
+                    # flight dump carries it completed in its ring
+                    loss_val = float(state.loss)
+                    if not health.check_loss("lbfgs.loss", loss_val, it=it):
+                        status = "nan_loss"
+                        break
+                    guard.update(loss_val, it=it)
+                if ls > 1:
+                    # trials beyond the first = line-search retries (step rescales)
+                    obs_inc("lbfgs.ls_retries", ls - 1)
+                if ls < 0:
+                    obs_inc("lbfgs.ls_failures")
+                    status = f"line_search_failed({ls})"
                     break
-                guard.update(loss_val, it=it)
-            if ls > 1:
-                # trials beyond the first = line-search retries (step rescales)
-                obs_inc("lbfgs.ls_retries", ls - 1)
-            if ls < 0:
-                obs_inc("lbfgs.ls_failures")
-                status = f"line_search_failed({ls})"
-                break
-            if callback is not None and callback(it, state):
-                status = "callback_stop"
-                break
-            if float(gnorm) / max(float(wnorm), 1.0) <= config.eps:
-                status = "converged"
-                converged = True
-                break
+                if callback is not None and callback(it, state):
+                    status = "callback_stop"
+                    break
+                if float(gnorm) / max(float(wnorm), 1.0) <= config.eps:
+                    status = "converged"
+                    converged = True
+                    break
     return _result(state, it, status, converged)
 
 

@@ -32,8 +32,10 @@ from .obs import (
     health,
     inc as obs_inc,
     recorder,
+    root_span as obs_root_span,
     span as obs_span,
 )
+from .obs.scopes import Program
 from .optimize import LBFGSConfig, inv_hessian_vp, minimize_lbfgs
 from .resilience import trainer_guard
 
@@ -144,7 +146,9 @@ class HoagTrainer:
         # iteration callback, which dumps the current weights through the
         # ordinary checkpoint path and raises Preempted; the relaunch
         # resumes as a continue_train warm start (docs/fault_tolerance.md)
-        with trainer_guard(self):
+        # `train.run`: the root of every span of the run (the benchmark
+        # enters here; the CLI has opened it around the data load already)
+        with obs_root_span("train.run", family=self.model_name), trainer_guard(self):
             return self._train_impl(ingest)
 
     def _train_impl(self, ingest: Optional[IngestResult] = None) -> TrainResult:
@@ -217,12 +221,21 @@ class HoagTrainer:
         if row_chunk is not None:
             log.info("blocked evaluation: row chunk %d", row_chunk)
         nb = len(train_b)
-        jit_loss = jax.jit(
-            make_sum(model.pure_loss, row_chunk, row_mask, self.mesh, "data", nb)
+        sum_loss = make_sum(model.pure_loss, row_chunk, row_mask, self.mesh, "data", nb)
+        rows_predict = make_rows(
+            model.predicts, row_chunk, row_mask, self.mesh, "data", nb
         )
-        jit_predicts = jax.jit(
-            make_rows(model.predicts, row_chunk, row_mask, self.mesh, "data", nb)
-        )
+
+        # the two evaluation programs under names of their own
+        # (`jit_eval_loss`, `jit_eval_predicts` on a device trace)
+        def eval_loss(w, *batch):
+            return sum_loss(w, *batch)
+
+        def eval_predicts(w, *batch):
+            return rows_predict(w, *batch)
+
+        jit_loss = Program(eval_loss)
+        jit_predicts = Program(eval_predicts)
         jit_precision = (
             jax.jit(model.precision) if hasattr(model, "precision") else None
         )
@@ -306,7 +319,13 @@ class HoagTrainer:
             # guarding both here would double-count every incident)
             guard = health.ProgressGuard("train.convex_test", window=12)
 
-            def callback(
+            def callback(it, state):
+                # the whole host callback of an iteration under one span
+                # of that step: test loss, metrics, log, dumps
+                with obs_span("train.callback", step=it):
+                    return host_callback(it, state)
+
+            def host_callback(
                 it, state, _l1=l1, _l2=l2, _l1v=l1_vec, _l2v=l2_vec, _guard=guard
             ):
                 rec = {
@@ -318,9 +337,10 @@ class HoagTrainer:
                     "pure_loss": float(state.pure_loss),
                 }
                 if test_b is not None:
-                    rec["test_loss"] = float(jit_loss(state.w, *test_b)) / max(
-                        g_weight_test, 1e-12
-                    )
+                    with obs_span("train.test_loss"):  # settled by its float()
+                        rec["test_loss"] = float(jit_loss(state.w, *test_b)) / max(
+                            g_weight_test, 1e-12
+                        )
                 if health.enabled() and "test_loss" in rec:
                     health.check_loss("train.convex_test", rec["test_loss"], iter=it)
                     _guard.update(rec["test_loss"], iter=it)
@@ -490,11 +510,12 @@ class HoagTrainer:
     def _dump(
         self, model, w, ingest, l2_vec, g_weight, train_b, jit_precision=None
     ) -> None:
-        precision = None
-        if jit_precision is not None:
-            precision = np.asarray(
-                jit_precision(w, *train_b, l2_vec=l2_vec, g_weight=g_weight)
-            )
-        if jax.process_index() != 0:
-            return  # rank0-only dump (reference: HoagOptimizer.java:647-660)
-        model.dump_model(self.fs, np.asarray(w), precision, ingest.feature_map)
+        with obs_span("train.dump"):
+            precision = None
+            if jit_precision is not None:
+                precision = np.asarray(
+                    jit_precision(w, *train_b, l2_vec=l2_vec, g_weight=g_weight)
+                )
+            if jax.process_index() != 0:
+                return  # rank0-only dump (reference: HoagOptimizer.java:647-660)
+            model.dump_model(self.fs, np.asarray(w), precision, ingest.feature_map)
