@@ -4,6 +4,7 @@ program's own names on the device (scopes, programs, the instruction ->
 scope map). All on the CPU; nothing here describes a TPU topology."""
 
 import math
+import re
 import threading
 import time
 
@@ -326,15 +327,21 @@ def test_gbdt_stopped_by_sigterm_leaves_counters_and_whole_spans(
 # ---------------------------------------------------------------------------
 
 
-def test_scope_map_of_a_tiny_fm_pass_names_gather_and_scatter(obs_on):
+@pytest.mark.parametrize("latent,lookup_scope,width", [(4, "fm.gather_v", 5), (0, "fm.gather_w", 1)])
+def test_scope_map_of_a_tiny_fm_pass_names_gather_and_scatter(obs_on, latent, lookup_scope, width):
+    """One lookup a slot: with a latent part the one gather and, through
+    autodiff, the one scatter-add lie under `fm.gather_v` and nothing under
+    `fm.gather_w`; without one the single first-order gather keeps
+    `fm.gather_w`. The gauge says which without a trace."""
     from ytklearn_tpu.config.params import CommonParams
     from ytklearn_tpu.models.fm import FMModel
     from ytklearn_tpu.optimize.blocked import make_value_and_grad
 
     p = CommonParams.from_config({
-        "k": [1, 4], "model": {"data_path": "unused", "need_bias": True},
+        "k": [1, latent], "model": {"data_path": "unused", "need_bias": True},
         "data": {"train": {"data_path": "unused"}}})
     m = FMModel(p, 64)
+    assert obs.REGISTRY.gauges["fm.stat.gather_width"] == width
     rng = np.random.RandomState(0)
     idx = jnp.asarray(rng.randint(0, 64, size=(32, 5)), jnp.int32)
     val = jnp.asarray(rng.rand(32, 5), jnp.float32)
@@ -350,10 +357,17 @@ def test_scope_map_of_a_tiny_fm_pass_names_gather_and_scatter(obs_on):
     loss, grad = prog(w, idx, val, y, wt)
     assert np.isfinite(float(loss)) and grad.shape == w.shape
     ops = scopes.scope_map()["jit_fm_pass"]
-    under_v = [k for k, s in ops.items() if s == "fm.gather_v"]
-    assert any("gather" in k for k in under_v), under_v
-    assert any("scatter" in k for k in under_v), under_v  # through autodiff
-    assert "fm.gather_w" in set(ops.values())
+    # the CPU compiler leaves gather and scatter as instructions of their own
+    # (the TPU's wraps each in a custom fusion): count them among every
+    # instruction of the compiled pass, scoped or not
+    text = next(iter(prog._compiled.values())).as_text()
+    lookups = {op: set(re.findall(rf"^\s*(?:ROOT )?%?([\w.\-]+) = \S+ {op}\(", text, re.M))
+               for op in ("gather", "scatter")}
+    assert len(lookups["gather"]) == 1 and len(lookups["scatter"]) == 1, lookups
+    for names in lookups.values():
+        assert [ops.get(n) for n in names] == [lookup_scope], (names, ops)
+    other = ({"fm.gather_w", "fm.gather_v"} - {lookup_scope}).pop()
+    assert other not in ops.values()
     # once per compile: the second call compiles nothing and writes no map
     n_maps = len([e for e in obs.REGISTRY.events if e["name"] == "scope_map"])
     prog(w, idx, val, y, wt)

@@ -10,6 +10,15 @@ falls out of autodiff identically to the reference's closed form. Gradient
 masks (first/second order switches, bias latent) are applied by masking the
 *weights inside the score*: masked slots start at 0 and their chain-rule
 gradient is 0, which reproduces the reference's g[i]=0 zeroing exactly.
+
+One lookup a slot: with a latent part (`k[1] > 0`) `scores` builds one
+(1 + k)-row table from the flat vector (row 0 the first-order weights, rows
+1..k the latent rows) and gathers it once, under the scope `fm.gather_v`;
+autodiff makes one scatter-add of it (and XLA a sort in front), under the
+same scope. Without a latent part the single first-order gather runs under
+`fm.gather_w`, which holds nothing otherwise. The gauge
+`fm.stat.gather_width` (1 + k, or 1) says which path a model took. The flat
+layout the optimizer, the dump and `apply_model_line` see is unchanged.
 """
 
 from __future__ import annotations
@@ -21,6 +30,7 @@ import numpy as np
 
 from ..config.params import CommonParams
 from ..io.reader import SparseDataset
+from ..obs import gauge as obs_gauge
 from ..obs.scopes import scope
 from .base import ConvexModel, random_init
 
@@ -37,6 +47,8 @@ class FMModel(ConvexModel):
         self.sok = int(k[1])
         self.need_second_order = self.sok > 0
         self.v_start = n_features  # secondOrderIndexStart
+        # rows of the table `scores` looks a slot up in: which path a run took
+        obs_gauge("fm.stat.gather_width", 1 + self.sok)
 
     @property
     def dim(self) -> int:
@@ -71,21 +83,27 @@ class FMModel(ConvexModel):
     def scores(self, w, *xargs):
         idx, val = xargs
         w = self._apply_mask(w)
-        # the two gathers under scopes of their own: autodiff hands the
-        # name on to their transposes, the two scatter-adds of the gradient
-        with scope("fm.gather_w"):
-            w1x = w[: self.v_start][idx]
-        wx = jnp.sum(val * w1x, axis=-1)
         if not self.need_second_order:
-            return wx
-        # k-major latent gather: the (k, n, width) intermediate keeps width
-        # on the 128-lane axis (pad e.g. 39->128, ~3.3x) instead of k
-        # (8->128, 16x) — the k-minor layout is what OOM'd BENCH_r04
+            with scope("fm.gather_w"):
+                w1x = w[: self.v_start][idx]
+            return jnp.sum(val * w1x, axis=-1)
+        # one table, one lookup a slot: row 0 the first-order weights, rows
+        # 1..k the latent rows. On the chip a lookup costs per index and
+        # nothing per byte (gather 9.9 ns, scatter-add 13.6 ns an index at
+        # 8, 9 and 16 rows alike: PERF.md, PR 30), so a first-order gather
+        # of its own cost 70% of the latent one. Autodiff makes one
+        # scatter-add of the one gather, and the concat's transpose splits
+        # it back into the flat gradient.
+        # k-major: the (1+k, n, width) intermediate keeps width on the
+        # 128-lane axis (pad e.g. 39->128, ~3.3x) instead of k (8->128,
+        # 16x) — the k-minor layout is what OOM'd BENCH_r04
         # (f32[2M*39,8] lane-padded to 39.9 GB)
         Vt = w[self.v_start :].reshape(self.n_features, self.sok).T  # (k, nf)
+        table = jnp.concatenate([w[None, : self.v_start], Vt], axis=0)
         with scope("fm.gather_v"):
-            vg = Vt[:, idx]
-        vx = vg * val[None]  # (k, n, width)
+            g = table[:, idx]  # (1+k, n, width)
+        wx = jnp.sum(val * g[0], axis=-1)
+        vx = g[1:] * val[None]  # (k, n, width)
         S = jnp.sum(vx, axis=-1)  # Σ v x            (k, n)
         S2 = jnp.sum(vx * vx, axis=-1)  # Σ (v x)^2  (k, n)
         return wx + 0.5 * jnp.sum(S * S - S2, axis=0)
