@@ -188,10 +188,9 @@ def lbfgs_programs():
         loss=loss,
         pure_loss=pure,
         step=jnp.asarray(np.float64(1.0 / max(float(gnorm), 1e-300))),
-        S=jnp.asarray(np.zeros((cfg.m, dim))),
-        Y=jnp.asarray(np.zeros((cfg.m, dim))),
+        S=(jnp.asarray(np.zeros(dim)),) * cfg.m,
+        Y=(jnp.asarray(np.zeros(dim)),) * cfg.m,
         ys=jnp.asarray(np.ones(cfg.m)),
-        cursor=jnp.asarray(np.int32(0)),
         hist_len=jnp.asarray(np.int32(0)),
         ls_status=jnp.asarray(np.int32(1)),
     )

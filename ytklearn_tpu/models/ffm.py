@@ -9,8 +9,28 @@ TPU-first pairwise formulation: instead of the reference's O(width^2 * k)
 per-row double loop, aggregate per *field pair*:
     T[a, b, :] = Σ_{p: field_p = a} val_p · V[feat_p, b, :]      (n, F, F, k)
     fx = x·w1 + 0.5 ( Σ_{a,b} T[a,b]·T[b,a]  -  Σ_p val_p² |V[feat_p, field_p]|² )
-The T build is an einsum (MXU) over the one-hot field matrix; memory is
-n·F²·k instead of n·width²·k, and F (field count) is small.
+
+One lookup a slot: `scores` builds one table from the flat vector, a row an
+id: the id's F·k latent floats (field-major, as the flat layout has them)
+and its first-order weight last, 1 + F·k floats. It is gathered once, under
+the scope `ffm.gather`; autodiff makes one scatter-add of it under the same
+scope. The gathered array is (rows, width, 1 + F·k): the F·k floats lie on
+the 128-lane axis (156 -> 256, 1.6x), never k or F alone (k-minor pads 4 ->
+128, 32x: 1.6 MB a row at F = 39).
+
+The field-pair term, under the scope `ffm.pair`: T is built by one product
+of the one-hot field matrix with the scaled rows, batched over rows, at
+`Precision.HIGHEST` (a one-hot operand is exact in bfloat16, the float32
+rows are not: without the stated precision the MXU rounds them to
+bfloat16). T stays (rows, F, F·k); the (a, b) <-> (b, a) pairing is a
+transpose of its reshape, which XLA lays out with rows on the lanes. The
+diagonal is read off the scaled rows by a mask of each slot's own field.
+Rows whose fields repeat, or differ from row to row, take the same path:
+the one-hot is made from the `field` array of the chunk.
+
+The gauges `ffm.stat.gather_width` (1 + F·k, or 1 without a latent part)
+and `ffm.stat.fields` say what a model looks up. The flat layout the
+optimizer, the dump and `apply_model_line` see is unchanged.
 """
 
 from __future__ import annotations
@@ -19,10 +39,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..config.params import CommonParams
 from ..io.fs import FileSystem
 from ..io.reader import SparseDataset
+from ..obs import gauge as obs_gauge
+from ..obs.scopes import scope
 from .base import ConvexModel, random_init
 
 
@@ -51,6 +74,9 @@ class FFMModel(ConvexModel):
         self.need_second_order = self.sok > 0
         self.n_fields = n_fields
         self.v_start = n_features
+        # floats of the table row `scores` looks a slot up in, and the fields
+        obs_gauge("ffm.stat.gather_width", 1 + n_fields * self.sok)
+        obs_gauge("ffm.stat.fields", n_fields)
 
     @property
     def dim(self) -> int:
@@ -85,32 +111,66 @@ class FFMModel(ConvexModel):
             raise ValueError("FFM requires a dataset ingested with a field map")
         return (ds.idx, ds.val, ds.field, ds.y, ds.weight)
 
+    def _table(self, w):
+        """(n_features, F·k + 1) of the masked flat vector: an id's latent
+        floats, field-major as the flat layout has them, and its first-order
+        weight last."""
+        nf = self.n_features
+        w = self._apply_mask(w)
+        V = w[self.v_start :].reshape(nf, self.n_fields * self.sok)
+        return jnp.concatenate([V, w[:nf, None]], axis=1)
+
     def scores(self, w, *xargs):
         idx, val, field = xargs
-        w = self._apply_mask(w)
-        wx = jnp.sum(val * w[: self.v_start][idx], axis=-1)
         if not self.need_second_order:
-            return wx
+            w = self._apply_mask(w)
+            with scope("ffm.gather"):
+                w1x = w[: self.v_start][idx]
+            return jnp.sum(val * w1x, axis=-1)
         F, k = self.n_fields, self.sok
-        V = w[self.v_start :].reshape(self.n_features, F, k)
-        Vr = V[idx]  # (n, width, F, k)
-        onehot = jnp.asarray(field[..., None] == jnp.arange(F), val.dtype)  # (n, w, F)
-        # T[a, b] = Σ_p [field_p = a] val_p Vr[p, b]
-        T = jnp.einsum("nwa,nwbk->nabk", onehot * val[..., None], Vr)
-        cross = jnp.einsum("nabk,nbak->n", T, T)
-        # diagonal correction: p = q terms, each = val_p^2 |V[feat_p, field_p]|^2
-        own = jnp.take_along_axis(
-            Vr, field[..., None, None].astype(jnp.int32), axis=2
-        )[:, :, 0, :]  # (n, width, k)
-        diag = jnp.sum((val * val) * jnp.sum(own * own, axis=-1), axis=-1)
+        fk = F * k
+        table = self._table(w)
+        with scope("ffm.gather"):
+            g = table[idx]  # (n, width, F·k + 1): the one lookup a slot
+        wx = jnp.sum(val * g[..., fk], axis=-1)
+        with scope("ffm.pair"):
+            z = g[..., :fk] * val[..., None]  # x_p · V[feat_p, :, :]
+            # T[a, (b, c)] = Σ_p [field_p = a] z[p, (b, c)]; the one-hot is
+            # made inside the product's fusion, never stored
+            onehot = (field[..., None, :] == jnp.arange(F)[:, None]).astype(z.dtype)
+            T = jnp.einsum(
+                "...aw,...wc->...ac", onehot, z, precision=lax.Precision.HIGHEST
+            )
+            T = T.reshape(T.shape[:-1] + (F, k))  # (n, a, b, c)
+            cross = jnp.sum(T * jnp.swapaxes(T, -3, -2), axis=(-3, -2, -1))
+            # p = q terms: x_p² |V[feat_p, field_p]|², read off z by a mask
+            own = field[..., None] == jnp.arange(fk) // k
+            diag = jnp.sum(jnp.where(own, z * z, 0.0), axis=(-2, -1))
         return wx + 0.5 * (cross - diag)
 
     def score_bytes_per_row(self, width: int) -> int:
-        """Dominant per-row intermediates: the latent gather (width, F, k)
-        and the field-pair tensor (F, F, k), both k-minor (pad k->128)."""
-        F, kp = self.n_fields, -(-max(self.sok, 1) // 128) * 128
-        Fp = -(-F // 8) * 8
-        return (width * Fp + F * Fp) * kp * 4
+        """What a row of a chunk holds at once under autodiff, padded as the
+        chip tiles it (8 sublanes, 128 lanes): the scaled gathered rows
+        (width, F·k + 1), the field-pair sums (F, F·k) and one cotangent of
+        the larger, all with F·k on the lanes. At width 40, F 39, k 4: 120
+        KiB (the v5e compiler's own count for a chunk's loss+gradient: 108
+        KiB a row)."""
+        F, fk = self.n_fields, self.n_fields * max(self.sok, 1)
+
+        def pad(x, m):
+            return -(-x // m) * m
+
+        rows = pad(width, 8) * pad(fk + 1, 128)
+        pairs = pad(F, 8) * pad(fk, 128)
+        return (rows + pairs + max(rows, pairs)) * 4
+
+    def suggest_row_chunk(self, n_rows: int, width: int, n_shards: int = 1):
+        """`score_bytes_per_row` is the whole of a row here, backward
+        included, so the budget is divided by it as it is (the base's x4
+        stands for the cotangents it does not count)."""
+        from ..optimize.blocked import suggest_chunk
+
+        return suggest_chunk(n_rows, self.score_bytes_per_row(width), n_shards=n_shards)
 
     # -- model text I/O: name,w,v[field0 k..],v[field1 k..],... ----------
 

@@ -2,8 +2,8 @@
 
 Rebuild of reference optimizer/HoagOptimizer.java:306-1201 as *one jitted
 program per iteration*: the line search (each trial = full loss+grad) runs as
-a `lax.while_loop` on device, the two-loop recursion as `lax.fori_loop`s over
-a fixed-size (m, dim) history, and the OWL-QN pseudo-gradient / orthant
+a `lax.while_loop` on device, the two-loop recursion unrolled over a history
+of m (s, y) pairs kept newest first, and the OWL-QN pseudo-gradient / orthant
 projection / direction constraint as elementwise selects. The host loop only
 handles convergence checks, eval, and checkpoint dumps — the reference
 instead paid a full network allreduce per line-search trial
@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs import (
+    gauge as obs_gauge,
     health,
     inc as obs_inc,
     profiler,
@@ -89,11 +90,10 @@ class LBFGSState(NamedTuple):
     loss: jnp.ndarray  # regularized weighted-sum loss
     pure_loss: jnp.ndarray
     step: jnp.ndarray  # initial step for next line search
-    S: jnp.ndarray  # (m, dim) s history
-    Y: jnp.ndarray  # (m, dim) y history
-    ys: jnp.ndarray  # (m,)
-    cursor: jnp.ndarray  # next write slot
-    hist_len: jnp.ndarray
+    S: Tuple[jnp.ndarray, ...]  # m vectors (dim,), newest first
+    Y: Tuple[jnp.ndarray, ...]  # m vectors (dim,), newest first
+    ys: jnp.ndarray  # (m,), newest first
+    hist_len: jnp.ndarray  # how many of the m pairs are real
     ls_status: jnp.ndarray  # >0 ok (trial count), <0 failed
 
 
@@ -116,37 +116,30 @@ class Reg(NamedTuple):
     g_weight: jnp.ndarray  # scalar total train weight
 
 
-def _two_loop_core(g, S, Y, ys_arr, cursor, hist_len, m: int):
-    """-H⁻¹·g via the two-loop recursion over the (m, dim) ring buffer
-    (reference: HoagOptimizer.Hv:904-929; history replicated here — on a
-    TPU mesh the dots are local FLOPs, so the reference's history-slice
-    sharding + allgather dance is unnecessary at these dims; for very
-    large dim shard w/S/Y over the mesh and XLA re-inserts the psums)."""
-    dtype = g.dtype
+def _two_loop_core(g, S, Y, ys_arr, hist_len, m: int):
+    """-H⁻¹·g via the two-loop recursion over the m newest (s, y) pairs,
+    `S[0]` the newest (reference: HoagOptimizer.Hv:904-929; history
+    replicated here — on a TPU mesh the dots are local FLOPs, so the
+    reference's history-slice sharding + allgather dance is unnecessary at
+    these dims; for very large dim shard w/S/Y over the mesh and XLA
+    re-inserts the psums). The pairs are m separate vectors, so that an
+    iteration adds one pair without rewriting the others (at dim 41M a
+    rewritten (m, dim) history is a second 1.3 GB copy of each of S, Y)."""
     p = -g
-
-    def fwd(i, carry):
-        p, alphas = carry
-        idx = (cursor - 1 - i) % m
+    alphas = []
+    for i in range(m):  # newest first
         valid = i < hist_len
-        alpha = jnp.where(valid, jnp.vdot(S[idx], p) / ys_arr[idx], 0.0)
-        p = p - alpha * Y[idx]
-        return p, alphas.at[idx].set(alpha)
+        alpha = jnp.where(valid, jnp.vdot(S[i], p) / ys_arr[i], 0.0)
+        p = p - alpha * Y[i]
+        alphas.append(alpha)
 
-    p, alphas = lax.fori_loop(0, m, fwd, (p, jnp.zeros((m,), dtype)))
+    p = p * ys_arr[0] / jnp.vdot(Y[0], Y[0])
 
-    newest = (cursor - 1) % m
-    yy_newest = jnp.vdot(Y[newest], Y[newest])
-    p = p * ys_arr[newest] / yy_newest
-
-    def bwd(j, p):
-        i = m - 1 - j  # oldest valid first
-        idx = (cursor - 1 - i) % m
+    for i in reversed(range(m)):  # oldest valid first
         valid = i < hist_len
-        beta = jnp.where(valid, jnp.vdot(Y[idx], p) / ys_arr[idx], 0.0)
-        return p + jnp.where(valid, alphas[idx] - beta, 0.0) * S[idx]
-
-    return lax.fori_loop(0, m, bwd, p)
+        beta = jnp.where(valid, jnp.vdot(Y[i], p) / ys_arr[i], 0.0)
+        p = p + jnp.where(valid, alphas[i] - beta, 0.0) * S[i]
+    return p
 
 
 @partial(jax.jit, static_argnames=("m",))
@@ -157,7 +150,7 @@ def inv_hessian_vp(state: LBFGSState, v, m: int):
     to identity when no history exists."""
     return jnp.where(
         state.hist_len > 0,
-        -_two_loop_core(v, state.S, state.Y, state.ys, state.cursor, state.hist_len, m),
+        -_two_loop_core(v, state.S, state.Y, state.ys, state.hist_len, m),
         v,
     )
 
@@ -307,20 +300,18 @@ def _build_programs(
         pure = jnp.where(failed, pure0, pure)
         return w, g, loss, pure, status
 
-    def two_loop(g, S, Y, ys_arr, cursor, hist_len):
-        return _two_loop_core(g, S, Y, ys_arr, cursor, hist_len, m)
-
     def first_eval(w, reg, batch):
         pure, loss, g = lg(w, reg, batch)
         return pure, loss, g, jnp.linalg.norm(w), jnp.linalg.norm(g)
 
     def iteration(state: LBFGSState, reg: Reg, batch):
         """One full L-BFGS iteration: direction from history -> line search
-        -> history update (reference main loop :566-715)."""
+        -> the new (s, y) pair (reference main loop :566-715). Hands back
+        the pieces of the next state; `_Iteration` puts them together."""
         wprev, gprev = state.w, state.g
         p = jnp.where(
             state.hist_len > 0,
-            two_loop(gprev, state.S, state.Y, state.ys, state.cursor, state.hist_len),
+            _two_loop_core(gprev, state.S, state.Y, state.ys, state.hist_len, m),
             -gprev,
         )
         if has_l1:
@@ -336,15 +327,41 @@ def _build_programs(
         ys = jnp.vdot(y, s)
         yy = jnp.vdot(y, y)
         ys = jnp.where(ys < 1e-60, 0.01 * yy, ys)  # curvature guard (:678-681)
+        ys_arr = jnp.concatenate([ys[None], state.ys[:-1]])
+        new_len = jnp.minimum(state.hist_len + 1, m).astype(jnp.int32)
+        return (
+            (w, g, loss, pure, status),
+            (s, y, ys_arr, new_len),
+            jnp.linalg.norm(w),
+            jnp.linalg.norm(g),
+        )
 
-        ok = status > 0
-        cursor = state.cursor
-        S = jnp.where(ok, state.S.at[cursor].set(s), state.S)
-        Y = jnp.where(ok, state.Y.at[cursor].set(y), state.Y)
-        ys_arr = jnp.where(ok, state.ys.at[cursor].set(ys), state.ys)
-        new_cursor = jnp.where(ok, (cursor + 1) % m, cursor)
-        new_len = jnp.where(ok, jnp.minimum(state.hist_len + 1, m), state.hist_len)
+    # compiled ahead of time under their own names (`jit_first_eval`,
+    # `jit_iteration` on a device trace's module line), the compiled HLO at
+    # hand for the scope map (obs/scopes.py)
+    programs = (Program(first_eval), _Iteration(iteration))
+    _PROGRAMS[key] = programs
+    while len(_PROGRAMS) > _PROGRAMS_MAX:
+        _PROGRAMS.popitem(last=False)
+    return programs
 
+
+class _Iteration(Program):
+    """`iteration(state, reg, batch) -> (new state, ||w||, ||g||)`. The
+    jitted step hands back the iteration's own pair (s, y); here, on the
+    host, it goes to the head of the history and the oldest pair falls off,
+    so that a step allocates one pair and no second copy of S and Y. After a
+    failed line search (status < 0) the history stays as it was: the read of
+    the status is the sync the caller's own read of `ls_status` makes next."""
+
+    def __call__(self, state: LBFGSState, reg: Reg, batch):
+        (w, g, loss, pure, status), (s, y, ys_arr, new_len), wnorm, gnorm = (
+            super().__call__(state, reg, batch)
+        )
+        S, Y, ys, hist_len = state.S, state.Y, state.ys, state.hist_len
+        if int(jax.device_get(status)) > 0:
+            S, Y = (s,) + tuple(S[:-1]), (y,) + tuple(Y[:-1])
+            ys, hist_len = ys_arr, new_len
         new_state = LBFGSState(
             w=w,
             g=g,
@@ -353,21 +370,11 @@ def _build_programs(
             step=jnp.ones((), w.dtype),  # step=1 after first iteration (:707)
             S=S,
             Y=Y,
-            ys=ys_arr,
-            cursor=new_cursor.astype(jnp.int32),
-            hist_len=new_len.astype(jnp.int32),
+            ys=ys,
+            hist_len=hist_len,
             ls_status=status,
         )
-        return new_state, jnp.linalg.norm(w), jnp.linalg.norm(g)
-
-    # compiled ahead of time under their own names (`jit_first_eval`,
-    # `jit_iteration` on a device trace's module line), the compiled HLO at
-    # hand for the scope map (obs/scopes.py)
-    programs = (Program(first_eval), Program(iteration))
-    _PROGRAMS[key] = programs
-    while len(_PROGRAMS) > _PROGRAMS_MAX:
-        _PROGRAMS.popitem(last=False)
-    return programs
+        return new_state, wnorm, gnorm
 
 
 def minimize_lbfgs(
@@ -425,6 +432,9 @@ def minimize_lbfgs(
     )
 
     obs_inc("lbfgs.runs")
+    # what a run keeps on the device of its own, from shapes: the m (s, y)
+    # pairs once the history is full, w, g, the direction and the trial point
+    obs_gauge("lbfgs.stat.state_bytes", (2 * config.m + 4) * dim * dtype.itemsize)
     from ..obs import recorder
 
     recorder.auto_install()  # flight ring for postmortems (no-op when obs off)
@@ -438,16 +448,17 @@ def minimize_lbfgs(
         pure, loss, g, wnorm, gnorm = first_eval(jnp.asarray(w0, dtype), reg, batch)
         wnorm = max(float(wnorm), 1.0)  # the fetch settles the span
     obs_inc("lbfgs.passes")  # the first evaluation is one data pass
+    # one zero vector stands in for every pair not made yet
+    no_pair = jnp.zeros((dim,), dtype)
     state = LBFGSState(
         w=jnp.asarray(w0, dtype),
         g=g,
         loss=loss,
         pure_loss=pure,
         step=jnp.asarray(1.0 / max(float(gnorm), 1e-300), dtype),
-        S=jnp.zeros((config.m, dim), dtype),
-        Y=jnp.zeros((config.m, dim), dtype),
+        S=(no_pair,) * config.m,
+        Y=(no_pair,) * config.m,
         ys=jnp.ones((config.m,), dtype),
-        cursor=jnp.asarray(0, jnp.int32),
         hist_len=jnp.asarray(0, jnp.int32),
         ls_status=jnp.asarray(1, jnp.int32),
     )

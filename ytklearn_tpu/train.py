@@ -208,11 +208,10 @@ class HoagTrainer:
         # blocked evaluation: chunk row arrays so per-row score
         # intermediates (FM/FFM latent gathers) never scale peak memory
         # with n (reference blocked-CoreData contract, CoreData.java:51-52)
+        n_rows = int(train_b[0].shape[0])
         width = int(train_b[0].shape[1]) if train_b[0].ndim > 1 else 1
-        row_chunk = model.suggest_row_chunk(
-            int(train_b[0].shape[0]), width,
-            n_shards=int(self.mesh.devices.size) if self.mesh is not None else 1,
-        )
+        n_shards = int(self.mesh.devices.size) if self.mesh is not None else 1
+        row_chunk = model.suggest_row_chunk(n_rows, width, n_shards=n_shards)
         row_mask = model.batch_row_mask
         # mesh-aware when sharded: chunks stay shard-local (a plain scan on
         # a row-sharded array would all-gather the batch onto every device)
@@ -220,6 +219,12 @@ class HoagTrainer:
 
         if row_chunk is not None:
             log.info("blocked evaluation: row chunk %d", row_chunk)
+        # what a pass really scans at a time: the rows of a chunk (all of a
+        # shard's where nothing is chunked) and the chunks a pass makes
+        shard_rows = -(-n_rows // n_shards)
+        chunk_rows = min(row_chunk or shard_rows, shard_rows)
+        obs_gauge("blocked.stat.row_chunk", chunk_rows)
+        obs_gauge("blocked.stat.chunks_per_pass", -(-shard_rows // chunk_rows))
         nb = len(train_b)
         sum_loss = make_sum(model.pure_loss, row_chunk, row_mask, self.mesh, "data", nb)
         rows_predict = make_rows(
