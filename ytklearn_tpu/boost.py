@@ -203,6 +203,7 @@ class GBSTTrainer:
                 row_chunk=row_chunk,
                 row_mask=model.batch_row_mask,
                 mesh=self.mesh if row_chunk is not None else None,
+                split=model.loss_split,
             )
             per_tree_loss.append(res.loss / g_weight)
             if p.loss.just_evaluate:

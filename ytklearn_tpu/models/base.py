@@ -100,21 +100,59 @@ class ConvexModel:
         )
 
     # kernels ------------------------------------------------------------
-    def pure_loss(self, w, *batch):
+    #: A model MAY split its score in two: `prepare(w) -> p`, any pytree made
+    #: of `w` alone (masks, reshapes, transposes, concats: work at the
+    #: parameters' size that no row enters), and `scores_prepared(p, *xargs)`
+    #: on it. `scores` is then their composition. Blocked evaluation
+    #: (optimize/blocked.py) runs a declared `prepare` once a pass, outside
+    #: its chunk scan, and turns the gradient back through it once; a model
+    #: that leaves this None is evaluated `fn(w, chunk)` a chunk.
+    prepare = None
+
+    def scores_prepared(self, p, *xargs):
+        raise NotImplementedError
+
+    def scores(self, w, *xargs):
+        if self.prepare is None:
+            raise NotImplementedError
+        return self.scores_prepared(self.prepare(w), *xargs)
+
+    def _loss_of_scores(self, scores, y, weight):
         """Weighted-sum data loss; zero-weight padding rows masked via where
         (inf*0 from e.g. mape on padded labels must not NaN the sum)."""
-        *xargs, y, weight = batch
-        scores = self.scores(w, *xargs)
         # loss() reduces multiclass trailing axes, so per_row is always (n,)
         per_row = jnp.where(weight > 0, self.loss.loss(scores, y), 0.0)
         return jnp.sum(weight * per_row)
 
-    def scores(self, w, *xargs):
-        raise NotImplementedError
+    def pure_loss(self, w, *batch):
+        *xargs, y, weight = batch
+        return self._loss_of_scores(self.scores(w, *xargs), y, weight)
 
     def predicts(self, w, *batch):
         *xargs, _y, _w = batch
         return self.loss.predict(self.scores(w, *xargs))
+
+    def pure_loss_prepared(self, p, *batch):
+        *xargs, y, weight = batch
+        return self._loss_of_scores(self.scores_prepared(p, *xargs), y, weight)
+
+    def predicts_prepared(self, p, *batch):
+        *xargs, _y, _w = batch
+        return self.loss.predict(self.scores_prepared(p, *xargs))
+
+    def _split(self, fn_p) -> Optional[Tuple]:
+        return None if self.prepare is None else (self.prepare, fn_p)
+
+    @property
+    def loss_split(self) -> Optional[Tuple]:
+        """`(prepare, pure_loss_prepared)` where the model declares the
+        split, else None: what optimize/blocked.py's factories take beside
+        `pure_loss`."""
+        return self._split(self.pure_loss_prepared)
+
+    @property
+    def predicts_split(self) -> Optional[Tuple]:
+        return self._split(self.predicts_prepared)
 
     # model I/O ----------------------------------------------------------
     def _part_paths(self, rank: int) -> Tuple[str, str]:

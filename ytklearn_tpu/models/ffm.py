@@ -10,12 +10,17 @@ per-row double loop, aggregate per *field pair*:
     T[a, b, :] = Σ_{p: field_p = a} val_p · V[feat_p, b, :]      (n, F, F, k)
     fx = x·w1 + 0.5 ( Σ_{a,b} T[a,b]·T[b,a]  -  Σ_p val_p² |V[feat_p, field_p]|² )
 
-One lookup a slot: `scores` builds one table from the flat vector, a row an
+One lookup a slot: `prepare` builds one table from the flat vector, a row an
 id: the id's F·k latent floats (field-major, as the flat layout has them)
-and its first-order weight last, 1 + F·k floats. It is gathered once, under
-the scope `ffm.gather`; autodiff makes one scatter-add of it under the same
-scope. The gathered array is (rows, width, 1 + F·k): the F·k floats lie on
-the 128-lane axis (156 -> 256, 1.6x), never k or F alone (k-minor pads 4 ->
+and its first-order weight last, 1 + F·k floats; `scores_prepared` gathers
+it once, under the scope `ffm.gather`, and autodiff makes one scatter-add of
+it under the same scope. `scores(w, ...)` is the two composed (models/base.py).
+The table is 165 MB at 2^18 ids and F·k = 156 and nothing of a row enters
+it, so blocked evaluation (optimize/blocked.py) builds it once a pass,
+outside the chunk scan, sums the chunks' gradients in the table's layout and
+turns the sum back into the flat layout once (scope `blocked.prepare`).
+The gathered array is (rows, width, 1 + F·k): the F·k floats lie on the
+128-lane axis (156 -> 256, 1.6x), never k or F alone (k-minor pads 4 ->
 128, 32x: 1.6 MB a row at F = 39).
 
 The field-pair term, under the scope `ffm.pair`: T is built by one product
@@ -111,25 +116,27 @@ class FFMModel(ConvexModel):
             raise ValueError("FFM requires a dataset ingested with a field map")
         return (ds.idx, ds.val, ds.field, ds.y, ds.weight)
 
-    def _table(self, w):
-        """(n_features, F·k + 1) of the masked flat vector: an id's latent
-        floats, field-major as the flat layout has them, and its first-order
-        weight last."""
+    def prepare(self, w):
+        """What a pass looks up in, made of the flat vector alone (once a
+        pass under blocked evaluation): the (n_features, F·k + 1) table of
+        the masked vector, an id's latent floats, field-major as the flat
+        layout has them, and its first-order weight last. Without a latent
+        part, the masked first-order weights."""
         nf = self.n_features
         w = self._apply_mask(w)
+        if not self.need_second_order:
+            return w[: self.v_start]
         V = w[self.v_start :].reshape(nf, self.n_fields * self.sok)
         return jnp.concatenate([V, w[:nf, None]], axis=1)
 
-    def scores(self, w, *xargs):
+    def scores_prepared(self, table, *xargs):
         idx, val, field = xargs
         if not self.need_second_order:
-            w = self._apply_mask(w)
             with scope("ffm.gather"):
-                w1x = w[: self.v_start][idx]
+                w1x = table[idx]
             return jnp.sum(val * w1x, axis=-1)
         F, k = self.n_fields, self.sok
         fk = F * k
-        table = self._table(w)
         with scope("ffm.gather"):
             g = table[idx]  # (n, width, F·k + 1): the one lookup a slot
         wx = jnp.sum(val * g[..., fk], axis=-1)

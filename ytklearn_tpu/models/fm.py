@@ -11,11 +11,16 @@ masks (first/second order switches, bias latent) are applied by masking the
 *weights inside the score*: masked slots start at 0 and their chain-rule
 gradient is 0, which reproduces the reference's g[i]=0 zeroing exactly.
 
-One lookup a slot: with a latent part (`k[1] > 0`) `scores` builds one
+One lookup a slot: with a latent part (`k[1] > 0`) `prepare` builds one
 (1 + k)-row table from the flat vector (row 0 the first-order weights, rows
-1..k the latent rows) and gathers it once, under the scope `fm.gather_v`;
-autodiff makes one scatter-add of it (and XLA a sort in front), under the
-same scope. Without a latent part the single first-order gather runs under
+1..k the latent rows) and `scores_prepared` gathers it once, under the scope
+`fm.gather_v`; autodiff makes one scatter-add of it (and XLA a sort in
+front), under the same scope. `scores(w, ...)` is the two composed
+(models/base.py). Nothing of a row enters the table, so blocked evaluation
+(optimize/blocked.py) builds it once a pass, outside the chunk scan, sums
+the chunks' gradients in the table's layout and turns the sum back into the
+flat layout once (scope `blocked.prepare`). Without a latent part
+`prepare` is the masked first-order slice and its single gather runs under
 `fm.gather_w`, which holds nothing otherwise. The gauge
 `fm.stat.gather_width` (1 + k, or 1) says which path a model took. The flat
 layout the optimizer, the dump and `apply_model_line` see is unchanged.
@@ -80,26 +85,34 @@ class FMModel(ConvexModel):
             w = w.at[self.v_start : self.v_start + self.sok].set(0.0)
         return w
 
-    def scores(self, w, *xargs):
-        idx, val = xargs
+    def prepare(self, w):
+        """What a pass looks up in, made of the flat vector alone (once a
+        pass under blocked evaluation): with a latent part the (1 + k,
+        n_features) table of the masked vector, row 0 the first-order
+        weights, rows 1..k the latent rows; without, the masked first-order
+        weights."""
         w = self._apply_mask(w)
         if not self.need_second_order:
-            with scope("fm.gather_w"):
-                w1x = w[: self.v_start][idx]
-            return jnp.sum(val * w1x, axis=-1)
-        # one table, one lookup a slot: row 0 the first-order weights, rows
-        # 1..k the latent rows. On the chip a lookup costs per index and
-        # nothing per byte (gather 9.9 ns, scatter-add 13.6 ns an index at
-        # 8, 9 and 16 rows alike: PERF.md, PR 30), so a first-order gather
-        # of its own cost 70% of the latent one. Autodiff makes one
-        # scatter-add of the one gather, and the concat's transpose splits
-        # it back into the flat gradient.
+            return w[: self.v_start]
         # k-major: the (1+k, n, width) intermediate keeps width on the
         # 128-lane axis (pad e.g. 39->128, ~3.3x) instead of k (8->128,
         # 16x) — the k-minor layout is what OOM'd BENCH_r04
         # (f32[2M*39,8] lane-padded to 39.9 GB)
         Vt = w[self.v_start :].reshape(self.n_features, self.sok).T  # (k, nf)
-        table = jnp.concatenate([w[None, : self.v_start], Vt], axis=0)
+        return jnp.concatenate([w[None, : self.v_start], Vt], axis=0)
+
+    def scores_prepared(self, table, *xargs):
+        idx, val = xargs
+        if not self.need_second_order:
+            with scope("fm.gather_w"):
+                w1x = table[idx]
+            return jnp.sum(val * w1x, axis=-1)
+        # one table, one lookup a slot. On the chip a lookup costs per index
+        # and nothing per byte (gather 9.9 ns, scatter-add 13.6 ns an index
+        # at 8, 9 and 16 rows alike: PERF.md, PR 30), so a first-order
+        # gather of its own cost 70% of the latent one. Autodiff makes one
+        # scatter-add of the one gather, and prepare's transpose splits its
+        # sum over the chunks back into the flat gradient.
         with scope("fm.gather_v"):
             g = table[:, idx]  # (1+k, n, width)
         wx = jnp.sum(val * g[0], axis=-1)

@@ -17,6 +17,27 @@ program, so chunked and unchunked mesh evaluation are interchangeable.
 
 Batch elements that are NOT row-aligned (e.g. the GBST per-feature gate
 mask) are threaded through unchunked via `row_mask`.
+
+Once a pass, once a chunk. A chunk's function is `fn(w, *chunk)`, and by
+default all of it runs once a chunk. A caller may hand the same function in
+two parts, `split = (prepare, fn_p)` with `fn(w, *chunk) == fn_p(prepare(w),
+*chunk)` (a model declares them: models/base.py `ConvexModel.prepare`).
+Then what depends on the parameters alone runs once a pass, outside the
+scan, under the scope `blocked.prepare`:
+
+    p, pull = jax.vjp(prepare, w)        once a pass
+    scan:  l, gp += value_and_grad(fn_p)(p, *chunk)   once a chunk, the
+           gradient summed in p's layout
+    (g,) = pull(gp)                      once a pass: the gradient turned
+                                         back into w's layout
+
+`chunked_sum` and `blocked_rows` call `prepare` once before their scan.
+The sum over chunks is the same sum in the same order, element by element;
+what prepare's transpose does to a gradient (a mask's zeroing, a concat's
+split) is linear and applied to the sum instead of to every term. Without a
+split the scan's body is `value_and_grad(fn)(w, *chunk)` as it always was:
+which of the two is traced follows from whether a split was handed in,
+nothing else. The unchunked path (`chunk is None`) never looks at it.
 """
 
 from __future__ import annotations
@@ -28,8 +49,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..obs.scopes import scope
 
-def _split(batch, row_mask):
+#: `(prepare, fn_p)`: `prepare(w) -> p` once a pass, `fn_p(p, *chunk)` once
+#: a chunk (see the module text)
+Split = Tuple[Callable, Callable]
+
+
+def _split_rows(batch, row_mask):
     rows = tuple(a for a, r in zip(batch, row_mask) if r)
     consts = tuple(a for a, r in zip(batch, row_mask) if not r)
     return rows, consts
@@ -70,6 +97,7 @@ def chunked_value_and_grad(
     chunk: int,
     row_mask: Optional[Sequence[bool]] = None,
     vary_axes: Tuple[str, ...] = (),
+    split: Optional[Split] = None,
 ) -> Callable:
     """(w, *batch) -> (sum loss, sum grad), scanning row chunks.
 
@@ -80,26 +108,58 @@ def chunked_value_and_grad(
     *per-shard local* grad (AD would otherwise transpose the implicit
     pvary of replicated w into a psum, and the caller's own psum would
     then double-count) — the caller psums loss and grad exactly once.
+    `split`: `fn` in two parts; the gradient is then summed in the layout
+    of `prepare(w)` and turned back once, after the scan.
     """
 
     def run(w, *batch):
         mask = tuple(row_mask) if row_mask is not None else (True,) * len(batch)
-        rows, consts = _split(batch, mask)
+        rows, consts = _split_rows(batch, mask)
         xs, _ = _stack_chunks(rows, chunk)
         if vary_axes:
             w = lax.pcast(w, vary_axes, to="varying")
+        if split is None:
+            p, per_chunk = w, fn
+        else:
+            prepare, per_chunk = split
+            with scope("blocked.prepare"):
+                p, pull = jax.vjp(prepare, w)
 
         def body(carry, ch):
-            l, g = jax.value_and_grad(fn)(w, *_rebuild(mask, ch, consts))
-            return (carry[0] + l, carry[1] + g), None
+            l, g = jax.value_and_grad(per_chunk)(p, *_rebuild(mask, ch, consts))
+            if split is not None:
+                # a chunk's gradient is summed by itself, then added to the
+                # carry once, as it is where the transposes of `prepare`
+                # stand between the two. Left to itself XLA makes the carry
+                # the scatter-add's operand: every update of a row then
+                # meets the running sum of the whole pass one by one, and a
+                # hot id's millions of float32 updates lose what summing
+                # each chunk's among themselves first keeps (on the chip:
+                # 40 times the gap to a float32 reference and FM's check
+                # failed, PERF.md PR 32)
+                g = lax.optimization_barrier(g)
+            return (carry[0] + l, jax.tree.map(jnp.add, carry[1], g)), None
 
-        init = (jnp.zeros((), w.dtype), jnp.zeros_like(w))
+        init = (jnp.zeros((), w.dtype), jax.tree.map(jnp.zeros_like, p))
         if vary_axes:
             init = (lax.pcast(init[0], vary_axes, to="varying"), init[1])
         (loss, grad), _ = lax.scan(body, init, xs)
+        if split is not None:
+            with scope("blocked.prepare"):
+                (grad,) = pull(grad)
         return loss, grad
 
     return run
+
+
+def _prepared(fn: Callable, split: Optional[Split], w):
+    """(p, per_chunk) of a forward-only scan: `prepare(w)` made here, once,
+    where a split is given."""
+    if split is None:
+        return w, fn
+    prepare, per_chunk = split
+    with scope("blocked.prepare"):
+        return prepare(w), per_chunk
 
 
 def chunked_sum(
@@ -107,17 +167,19 @@ def chunked_sum(
     chunk: int,
     row_mask: Optional[Sequence[bool]] = None,
     vary_axes: Tuple[str, ...] = (),
+    split: Optional[Split] = None,
 ) -> Callable:
     """(w, *batch) -> sum loss only (no gradient) — the cheap evaluation
     path (per-iteration test loss, round selection)."""
 
     def run(w, *batch):
         mask = tuple(row_mask) if row_mask is not None else (True,) * len(batch)
-        rows, consts = _split(batch, mask)
+        rows, consts = _split_rows(batch, mask)
         xs, _ = _stack_chunks(rows, chunk)
+        p, per_chunk = _prepared(fn, split, w)
 
         def body(carry, ch):
-            return carry + fn(w, *_rebuild(mask, ch, consts)), None
+            return carry + per_chunk(p, *_rebuild(mask, ch, consts)), None
 
         init = jnp.zeros(())
         if vary_axes:
@@ -129,7 +191,10 @@ def chunked_sum(
 
 
 def blocked_rows(
-    fn: Callable, chunk: int, row_mask: Optional[Sequence[bool]] = None
+    fn: Callable,
+    chunk: int,
+    row_mask: Optional[Sequence[bool]] = None,
+    split: Optional[Split] = None,
 ) -> Callable:
     """Chunked per-row outputs: fn(w, *batch) -> (n, ...) evaluated as
     `lax.map` over row chunks, concatenated and sliced back to n rows.
@@ -139,9 +204,10 @@ def blocked_rows(
 
     def run(w, *batch):
         mask = tuple(row_mask) if row_mask is not None else (True,) * len(batch)
-        rows, consts = _split(batch, mask)
+        rows, consts = _split_rows(batch, mask)
         xs, n = _stack_chunks(rows, chunk)
-        out = lax.map(lambda ch: fn(w, *_rebuild(mask, ch, consts)), xs)
+        p, per_chunk = _prepared(fn, split, w)
+        out = lax.map(lambda ch: per_chunk(p, *_rebuild(mask, ch, consts)), xs)
         return out.reshape((-1,) + out.shape[2:])[:n]
 
     return run
@@ -154,13 +220,16 @@ def mesh_chunked_value_and_grad(
     mesh,
     axis: str,
     n_batch: int,
+    split: Optional[Split] = None,
 ) -> Callable:
     """`chunked_value_and_grad` run per-shard under shard_map with a final
     psum over the data axis — the reference's grad allreduce
     (optimizer/HoagOptimizer.java:1038) with the block loop inside each
-    rank, matching its per-thread CoreData block walk."""
+    rank, matching its per-thread CoreData block walk. With a `split` every
+    shard prepares its own copy and turns its local gradient back before
+    the psum, which stays one collective a pass on w's layout."""
     mask = tuple(row_mask) if row_mask is not None else (True,) * n_batch
-    cvg = chunked_value_and_grad(fn, chunk, mask, vary_axes=(axis,))
+    cvg = chunked_value_and_grad(fn, chunk, mask, vary_axes=(axis,), split=split)
     in_specs = (P(), tuple(P(axis) if r else P() for r in mask))
     out_specs = (P(), P())
 
@@ -181,12 +250,13 @@ def mesh_chunked_sum(
     mesh,
     axis: str,
     n_batch: int,
+    split: Optional[Split] = None,
 ) -> Callable:
     """`chunked_sum` per shard under shard_map + psum. Reshaping a
     row-sharded global array for the plain scan would make XLA all-gather
     the batch onto every device — this keeps each shard's chunks local."""
     mask = tuple(row_mask) if row_mask is not None else (True,) * n_batch
-    cs = chunked_sum(fn, chunk, mask, vary_axes=(axis,))
+    cs = chunked_sum(fn, chunk, mask, vary_axes=(axis,), split=split)
     in_specs = (P(), tuple(P(axis) if r else P() for r in mask))
 
     from ..parallel.collectives import psum
@@ -205,11 +275,12 @@ def mesh_blocked_rows(
     mesh,
     axis: str,
     n_batch: int,
+    split: Optional[Split] = None,
 ) -> Callable:
     """`blocked_rows` per shard under shard_map — per-row outputs stay
     row-sharded (out_specs P(axis)), no collective needed."""
     mask = tuple(row_mask) if row_mask is not None else (True,) * n_batch
-    br = blocked_rows(fn, chunk, mask)
+    br = blocked_rows(fn, chunk, mask, split=split)
     in_specs = (P(), tuple(P(axis) if r else P() for r in mask))
 
     def local(w, batch):
@@ -221,33 +292,40 @@ def mesh_blocked_rows(
 
 # -- dispatch factories: one place for the (unchunked | chunked | mesh-
 # chunked) selection so every call site (lbfgs programs, trainer eval
-# paths, HOAG test gradient) stays in sync ---------------------------------
+# paths, HOAG test gradient) stays in sync. `split` is `fn` in two parts
+# (module text); the unchunked path evaluates `fn` whole -------------------
 
 
 def make_value_and_grad(
-    fn, chunk=None, row_mask=None, mesh=None, axis="data", n_batch=0
+    fn, chunk=None, row_mask=None, mesh=None, axis="data", n_batch=0, split=None
 ):
     if chunk is None:
         return jax.value_and_grad(fn)
     if mesh is None:
-        return chunked_value_and_grad(fn, chunk, row_mask)
-    return mesh_chunked_value_and_grad(fn, chunk, row_mask, mesh, axis, n_batch)
+        return chunked_value_and_grad(fn, chunk, row_mask, split=split)
+    return mesh_chunked_value_and_grad(
+        fn, chunk, row_mask, mesh, axis, n_batch, split=split
+    )
 
 
-def make_sum(fn, chunk=None, row_mask=None, mesh=None, axis="data", n_batch=0):
+def make_sum(
+    fn, chunk=None, row_mask=None, mesh=None, axis="data", n_batch=0, split=None
+):
     if chunk is None:
         return fn
     if mesh is None:
-        return chunked_sum(fn, chunk, row_mask)
-    return mesh_chunked_sum(fn, chunk, row_mask, mesh, axis, n_batch)
+        return chunked_sum(fn, chunk, row_mask, split=split)
+    return mesh_chunked_sum(fn, chunk, row_mask, mesh, axis, n_batch, split=split)
 
 
-def make_rows(fn, chunk=None, row_mask=None, mesh=None, axis="data", n_batch=0):
+def make_rows(
+    fn, chunk=None, row_mask=None, mesh=None, axis="data", n_batch=0, split=None
+):
     if chunk is None:
         return fn
     if mesh is None:
-        return blocked_rows(fn, chunk, row_mask)
-    return mesh_blocked_rows(fn, chunk, row_mask, mesh, axis, n_batch)
+        return blocked_rows(fn, chunk, row_mask, split=split)
+    return mesh_blocked_rows(fn, chunk, row_mask, mesh, axis, n_batch, split=split)
 
 
 def pow2_floor(x: int) -> int:

@@ -209,8 +209,11 @@ def _build_programs(
     mesh=None,
     data_axis="data",
     n_batch=0,
+    split=None,
 ):
-    key = (pure_loss_fn, _trace_key(config), has_l1, row_chunk, row_mask, mesh)
+    key = (
+        pure_loss_fn, _trace_key(config), has_l1, row_chunk, row_mask, mesh, split
+    )
     hit = _PROGRAMS.get(key)
     if hit is not None:
         _PROGRAMS.move_to_end(key)
@@ -222,7 +225,7 @@ def _build_programs(
     from .blocked import make_value_and_grad
 
     vg_fn = make_value_and_grad(
-        pure_loss_fn, row_chunk, row_mask, mesh, data_axis, n_batch
+        pure_loss_fn, row_chunk, row_mask, mesh, data_axis, n_batch, split=split
     )
     lg = partial(_loss_grad, vg_fn, has_l1)
 
@@ -390,6 +393,7 @@ def minimize_lbfgs(
     row_mask: Optional[Tuple[bool, ...]] = None,
     mesh=None,
     data_axis: str = "data",
+    split=None,
 ) -> LBFGSResult:
     """Run distributed L-BFGS/OWL-QN to convergence.
 
@@ -403,6 +407,10 @@ def minimize_lbfgs(
     (dataflow/CoreData.java:51-52; see optimize/blocked.py). row_mask marks
     which batch elements are row-aligned (default: all). With `mesh`, the
     chunked scan runs per-shard under shard_map over `data_axis` + psum.
+    split: `pure_loss_fn` in two parts, `(prepare, loss_p)` with
+    `pure_loss_fn(w, *b) == loss_p(prepare(w), *b)`: the chunked scan then
+    runs `prepare` and its transpose once a pass instead of once a chunk
+    (optimize/blocked.py; a model's `loss_split`). Unused without row_chunk.
 
     callback(iter, state) runs on host once per iteration (eval/dump hook —
     the reference's per-iteration eval + dump_freq block :605-660); returning
@@ -429,6 +437,7 @@ def minimize_lbfgs(
         mesh=mesh,
         data_axis=data_axis,
         n_batch=len(batch),
+        split=split,
     )
 
     obs_inc("lbfgs.runs")

@@ -225,10 +225,17 @@ class HoagTrainer:
         chunk_rows = min(row_chunk or shard_rows, shard_rows)
         obs_gauge("blocked.stat.row_chunk", chunk_rows)
         obs_gauge("blocked.stat.chunks_per_pass", -(-shard_rows // chunk_rows))
+        # 1: the model declares its parameter-only work (`prepare`) and a
+        # chunked pass does it once, outside the scan; 0: it declares none
+        obs_gauge("blocked.stat.prepared", int(model.prepare is not None))
         nb = len(train_b)
-        sum_loss = make_sum(model.pure_loss, row_chunk, row_mask, self.mesh, "data", nb)
+        sum_loss = make_sum(
+            model.pure_loss, row_chunk, row_mask, self.mesh, "data", nb,
+            split=model.loss_split,
+        )
         rows_predict = make_rows(
-            model.predicts, row_chunk, row_mask, self.mesh, "data", nb
+            model.predicts, row_chunk, row_mask, self.mesh, "data", nb,
+            split=model.predicts_split,
         )
 
         # the two evaluation programs under names of their own
@@ -293,7 +300,7 @@ class HoagTrainer:
             hoag_t_old = 0.0
             _cvg = make_value_and_grad(
                 model.pure_loss, row_chunk, row_mask, self.mesh, "data",
-                len(test_b),
+                len(test_b), split=model.loss_split,
             )
             jit_grad_test = jax.jit(lambda w, *b: _cvg(w, *b)[1])
         else:
@@ -396,6 +403,7 @@ class HoagTrainer:
                     row_chunk=row_chunk,
                     row_mask=row_mask,
                     mesh=self.mesh if row_chunk is not None else None,
+                    split=model.loss_split,
                 )
             carry_w = np.asarray(res.w)
             # round selection: test loss when available, else the *pure*
