@@ -44,8 +44,8 @@ repeat runs cheap.
 Env knobs: BENCH_ROWS, BENCH_TEST_ROWS, BENCH_TREES, BENCH_WAVE,
 BENCH_HIST (int8|bf16|f32), BENCH_GOSS (default on at a=0.2,b=0.125;
 `0` disables, `a,b` overrides), BENCH_FM=0 to skip the FM axis,
-YTK_HIGGS_DIR, plus the engine's YTK_PARTITION / YTK_LADDER / YTK_FUSED /
-YTK_FUSED_MAX_ROWS and the YTK_GOSS_* / YTK_EFB* sampling knobs.
+YTK_HIGGS_DIR, plus the YTK_GOSS_* / YTK_EFB* sampling knobs. Which
+partitioned histogram passes run is chosen in code (gbdt/trainer.py LADDER).
 """
 
 from __future__ import annotations

@@ -257,7 +257,10 @@ def full_width_case():
     return np.ascontiguousarray(bins.T), g, h
 
 
-def full_width_spec(partition: bool, fused: bool):
+LADDER_C = (4, 16)  # stage C's budget divisors, see full_width_spec
+
+
+def full_width_spec(ladder, fused_max_rows):
     from ytklearn_tpu.gbdt.engine import GrowSpec
 
     # what GBDTTrainer._grow_spec builds for local_gbdt.conf on TPU, in int8
@@ -270,8 +273,8 @@ def full_width_spec(partition: bool, fused: bool):
         F=F, B=B, max_nodes=2 * LEAVES - 1, wave=WAVE, policy="loss",
         max_depth=-1, max_leaves=LEAVES, lr=0.1, l1=0.0, l2=0.0, min_h=100.0,
         max_abs=0.0, min_split_loss=0.0, min_split_samples=-1.0,
-        hist_mode="int8", force_dense=False, partition=partition,
-        ladder=(4, 16), fused=fused,
+        precision="int8", kernels="pallas", ladder=ladder,
+        fused_max_rows=fused_max_rows,
     )
 
 
@@ -310,21 +313,27 @@ def same_tree(a: dict, b: dict) -> bool:
 def stage_c(dev_line: str):
     import numpy as np
 
-    from scripts.cross_check import grow_single, make_case
+    from scripts.cross_check import LADDER, grow_single, make_case
+    from ytklearn_tpu.gbdt.trainer import FUSED_MAX_ROWS
 
     case = full_width_case()
     trees = {}
-    for name, partition, fused in (("full-scan", False, False),
-                                   ("xla-gather", True, False),
-                                   ("fused", True, True)):
-        trees[name], wlog, secs = grow_full_width(
-            case, full_width_spec(partition, fused))
+    for name, ladder, fused_max, impls in (
+            ("full-scan", (), 0, set()),
+            ("xla-gather", LADDER_C, 0, {"xla"}),
+            ("fused", LADDER_C, FUSED_MAX_ROWS, {"fused"})):
+        spec = full_width_spec(ladder, fused_max)
+        # the table the engine builds its passes from names the kernels
+        rungs = spec.rungs(N_A)
+        check({impl for _, impl in rungs} == impls,
+              f"stage C: {name} builds rungs {rungs}")
+        trees[name], wlog, secs = grow_full_width(case, spec)
         used = wlog[wlog[:, 3] > 0]
         budgets = sorted({int(r) for r in used[:, 0]})
         print(f"stage C [{dev_line}] {name}: {int(trees[name]['n_nodes'])} nodes, "
               f"{len(used)} histogram passes over row budgets {budgets}, "
               f"compile+run {secs:.1f}s")
-        if partition:  # the partitioned phases must really have run
+        if ladder:  # the partitioned phases must really have run
             check(len(budgets) > 1, f"stage C: {name} never left the full scan")
     ref = trees["full-scan"]
     check(int(ref["n_nodes"]) == 2 * LEAVES - 1,
@@ -338,10 +347,10 @@ def stage_c(dev_line: str):
     with open(os.path.join(ROOT, "tests", "data", "crosscheck_tree.json")) as f:
         golden = json.load(f)
     bins, g, h, _n, _F, Bt = make_case()
-    for name, kw in (("full-scan", dict(partition=False)),
-                     ("xla-gather", dict(partition=True)),
-                     ("fused", dict(partition=True, fused=True))):
-        sig = grow_single(bins, g, h, force_dense=False, B=Bt, **kw)
+    for name, kw in (("full-scan", dict(ladder=())),
+                     ("xla-gather", dict(ladder=LADDER)),
+                     ("fused", dict(ladder=LADDER, fused=True))):
+        sig = grow_single(bins, g, h, kernels="pallas", B=Bt, **kw)
         for k in ("n_nodes", "feat", "slot", "left", "right"):
             check(sig[k] == golden[k], f"stage C: toy {name} tree field {k} "
                   "differs from tests/data/crosscheck_tree.json")
@@ -356,6 +365,8 @@ def stage_c(dev_line: str):
 def stage_d(dev_line: str, paths, X_test, case, ref_tree) -> None:
     import jax
     import numpy as np
+
+    from ytklearn_tpu.gbdt.trainer import FUSED_MAX_ROWS
 
     devs = jax.devices()[:4]
     t0 = time.time()
@@ -376,7 +387,8 @@ def stage_d(dev_line: str, paths, X_test, case, ref_tree) -> None:
           f"{n_cols // 4} columns on devices {on}; bytes_in_use "
           f"{[b >> 20 for b in in_use]} MiB; wall {time.time() - t0:.1f}s")
 
-    tree4, _wlog, secs = grow_full_width(case, full_width_spec(True, True), devs)
+    tree4, _wlog, secs = grow_full_width(
+        case, full_width_spec(LADDER_C, FUSED_MAX_ROWS), devs)
     # The histograms are the same exact i32 sums, so every integer-valued
     # field must match bit for bit. leaf/hess/gain come out of f32 cumsums
     # over the 256 bins, which XLA orders differently for a shard's

@@ -9,11 +9,12 @@ late-tree waves cost O(wave rows) (partitioned budgets engaged) or O(n)
 
 Usage: python scripts/ablate_engine.py [n_rows] [config ...]
   configs: b256 (default), b64 (4x fewer hist FLOPs), notest, wave32,
-           part / nopart (leaf-partitioned phases on/off A/B),
-           fused / nofused (fused gather kernel vs XLA gather, TPU),
-           goss / efb / goss+efb (device-side GOSS row sampling and
+           part (the default program under the name the r6/r11 records
+           use), goss / efb / goss+efb (device-side GOSS row sampling and
            exclusive feature bundling, alone and combined; `part` is the
-           both-off baseline arm)
+           both-off baseline arm). Which partitioned passes run is chosen
+           in code (gbdt/trainer.py LADDER); chip_smoke.py stage C compares
+           the three row-selection strategies on the chip.
 
 Since r11 the generated data carries an 8-column mutually-exclusive
 sparse block next to the 28 dense features, so the efb arms exercise a
@@ -46,10 +47,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 logging.basicConfig(level=logging.INFO, stream=sys.stdout)
 
-_AB_VARS = (
-    "YTK_PARTITION", "YTK_NO_PARTITION", "YTK_FUSED",
-    "YTK_GOSS_A", "YTK_GOSS_B", "YTK_EFB", "YTK_EFB_CONFLICT",
-)
+_AB_VARS = ("YTK_GOSS_A", "YTK_GOSS_B", "YTK_EFB", "YTK_EFB_CONFLICT")
 
 
 def _goss_env():
@@ -59,9 +57,6 @@ def _goss_env():
 
 _ENV_OVERRIDES = {
     # config name -> env var settings applied for that run
-    "nopart": {"YTK_NO_PARTITION": "1"},
-    "fused": {"YTK_FUSED": "1"},
-    "nofused": {"YTK_FUSED": "0"},
     "goss": _goss_env,
     "efb": {"YTK_EFB": "1"},
     "goss+efb": lambda: dict(_goss_env(), YTK_EFB="1"),
@@ -266,7 +261,7 @@ def main() -> None:
     # AFTER the record is written (never destroy the artifact).
     band_fails = []
     base_arm = next(
-        (c for c in ("part", "b256", "nopart") if c in record["configs"]), None
+        (c for c in ("part", "b256") if c in record["configs"]), None
     )
     if base_arm is not None:
         base_auc = record["configs"][base_arm]["auc"]

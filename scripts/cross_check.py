@@ -51,16 +51,23 @@ def make_case():
     return bins, g, h, n, F, B
 
 
-def spec_for(F, B, force_dense, partition, fused=False):
+LADDER = (8, 32)  # the partitioned runs' budget divisors
+
+
+def spec_for(F, B, kernels, ladder, fused=False, fused_interpret=False):
     from ytklearn_tpu.gbdt.engine import GrowSpec
+    from ytklearn_tpu.gbdt.trainer import FUSED_MAX_ROWS
 
     return GrowSpec(
         F=F, B=B, max_nodes=31, wave=4, policy="loss", max_depth=20,
         max_leaves=16, lr=0.1, l1=0.0, l2=1.0, min_h=1.0, max_abs=0.0,
-        min_split_loss=0.0, min_split_samples=0.0, hist_mode="int8",
-        force_dense=force_dense, partition=partition, fused=fused,
+        min_split_loss=0.0, min_split_samples=0.0, precision="int8",
+        kernels=kernels, ladder=ladder,
         bm=4096,  # small blocks so the 32k-row case tiles on the TPU path
-        bm_g=1024, fused_max_rows=1 << 18,
+        bm_g=1024,
+        # unfused rungs take the XLA gather
+        fused_max_rows=FUSED_MAX_ROWS if fused or fused_interpret else 0,
+        fused_interpret=fused_interpret,
     )
 
 
@@ -76,11 +83,9 @@ def tree_sig(tr) -> dict:
 
 
 def grow_single(
-    bins, g, h, force_dense, partition, devices=None, B=None, fused=False,
+    bins, g, h, kernels, ladder, devices=None, B=None, fused=False,
     fused_interpret=False,
 ):
-    import dataclasses
-
     import jax
     import jax.numpy as jnp
 
@@ -93,9 +98,7 @@ def grow_single(
         from jax.sharding import Mesh
 
         mesh = Mesh(np.asarray(devices), ("data",))
-    spec = spec_for(F, B, force_dense, partition, fused=fused)
-    if fused_interpret:
-        spec = dataclasses.replace(spec, fused=True, fused_interpret=True)
+    spec = spec_for(F, B, kernels, ladder, fused, fused_interpret)
     grow = make_grow_tree(spec, mesh=mesh)
     bins_t = np.ascontiguousarray(bins.T)
     args = (
@@ -132,12 +135,12 @@ def main():
         print(f"default backend is {backend}, need the TPU chip", file=sys.stderr)
         return 2
 
-    sig_pallas = grow_single(bins, g, h, force_dense=False, partition=False, B=B)
-    sig_pallas_part = grow_single(bins, g, h, force_dense=False, partition=True, B=B)
+    sig_pallas = grow_single(bins, g, h, kernels="pallas", ladder=(), B=B)
+    sig_pallas_part = grow_single(bins, g, h, kernels="pallas", ladder=LADDER, B=B)
     # the r6 default TPU path: partitioned budgets through the FUSED
     # compact+gather+histogram kernel
     sig_pallas_fused = grow_single(
-        bins, g, h, force_dense=False, partition=True, fused=True, B=B
+        bins, g, h, kernels="pallas", ladder=LADDER, fused=True, B=B
     )
 
     # CPU 8-device sharded dense in-process (cpu backend coexists with tpu)
@@ -147,7 +150,7 @@ def main():
               "--xla_force_host_platform_device_count=8", file=sys.stderr)
         return 2
     sig_sharded = grow_single(
-        bins, g, h, force_dense=True, partition=False, devices=cpus[:8], B=B
+        bins, g, h, kernels="dense", ladder=(), devices=cpus[:8], B=B
     )
 
     ok = sig_pallas == sig_pallas_part == sig_pallas_fused == sig_sharded

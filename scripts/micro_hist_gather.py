@@ -1,6 +1,6 @@
 """Micro-benchmark the fused compact+gather+histogram kernel against the
 XLA gather+hist formulation at several wave budgets R — the tuning tool
-for YTK_LADDER / YTK_FUSED_MAX_ROWS on real hardware.
+for gbdt/trainer.py's LADDER / FUSED_MAX_ROWS on real hardware.
 
 K chained passes inside one program, one scalar fetched (dispatch cost
 stays out of the number), like micro_hist_chain.py. Run on the chip:
@@ -25,14 +25,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from ytklearn_tpu.gbdt.hist import gather_table, hist_wave_gather, hist_wave_q
+from ytklearn_tpu.gbdt.hist import gather_table, hist_wave, hist_wave_gather
 
 K = 10
 
 
-@partial(jax.jit, static_argnames=("F", "R", "B", "N", "bm_g", "interpret"))
+@partial(jax.jit, static_argnames=("F", "R", "B", "N", "bm_g", "kernels"))
 def chain_fused(table, pos, gq, hq, F: int, R: int, B: int, N: int, bm_g: int,
-                interpret: bool):
+                kernels: str):
     """Compaction + fused gather/hist, K times; the compaction (mask,
     cumsum, index scatter, 1-D grad gathers) is included — it is part of
     every partitioned wave's real cost."""
@@ -54,8 +54,8 @@ def chain_fused(table, pos, gq, hq, F: int, R: int, B: int, N: int, bm_g: int,
         gg = jnp.take(gq, idx)
         hg = jnp.take(hq, idx)
         out = hist_wave_gather(
-            table, idx, pg, gg, hg, ids, F, B, mode="int8", bm_g=bm_g,
-            interpret=interpret,
+            table, idx, pg, gg, hg, ids, F, B, precision="int8",
+            kernels=kernels, bm_g=bm_g, interpret=kernels != "pallas",
         )
         s = out[0, 0, 0, 0].astype(jnp.float32)
         return acc + s, ids0 + (s * 0).astype(jnp.int32)
@@ -64,15 +64,15 @@ def chain_fused(table, pos, gq, hq, F: int, R: int, B: int, N: int, bm_g: int,
     return acc
 
 
-@partial(jax.jit, static_argnames=("R", "B", "N", "bm"))
-def chain_xla(rows, bins_t, pos, gq, hq, R: int, B: int, N: int, bm: int):
+@partial(jax.jit, static_argnames=("R", "B", "N", "bm", "kernels"))
+def chain_xla(rows, bins_t, pos, gq, hq, R: int, B: int, N: int, bm: int,
+              kernels: str):
     """Compaction + XLA (R, F) row gather + transpose + full-scan kernel —
     the r5 partitioned path the fused kernel replaces."""
     n = pos.shape[0]
     F = rows.shape[1]
     ids0 = jnp.arange(N, dtype=jnp.int32)
     iota_n = jnp.arange(n, dtype=jnp.int32)
-    on_tpu = jax.default_backend() == "tpu"
 
     def body(i, carry):
         acc, ids = carry
@@ -88,9 +88,11 @@ def chain_xla(rows, bins_t, pos, gq, hq, R: int, B: int, N: int, bm: int):
         gg = jnp.take(gq, idx)
         hg = jnp.take(hq, idx)
         bt = jnp.transpose(jnp.take(rows, idx, axis=0)).astype(jnp.int32)
-        if on_tpu:
+        if kernels == "pallas":
             bt = bt.reshape(F, R // bm, 1, bm)
-        out = hist_wave_q(bt, pg, gg, hg, ids, B, bm=bm, force_dense=not on_tpu)
+        out = hist_wave(
+            bt, pg, gg, hg, ids, B, bm=bm, precision="int8", kernels=kernels
+        )
         s = out[0, 0, 0, 0].astype(jnp.float32)
         return acc + s, ids0 + (s * 0).astype(jnp.int32)
 
@@ -109,6 +111,7 @@ def timed(label, fn, *args, **kw):
 
 def main():
     on_tpu = jax.default_backend() == "tpu"
+    kernels = "pallas" if on_tpu else "dense"
     n = int(sys.argv[1]) if len(sys.argv) > 1 else (
         10_485_760 if on_tpu else 65_536
     )
@@ -132,11 +135,11 @@ def main():
             continue
         if R_x < n:
             timed(f"xla-gather  div={div:4d} R={R_x:9d}",
-                  chain_xla, rows, bins_t, pos, gq, hq, R_x, B, N, bm)
+                  chain_xla, rows, bins_t, pos, gq, hq, R_x, B, N, bm, kernels)
         if R_f < n:
             timed(f"fused       div={div:4d} R={R_f:9d}",
                   chain_fused, table, pos, gq, hq, F, R_f, B, N, 1024,
-                  not on_tpu)
+                  kernels)
 
 
 if __name__ == "__main__":

@@ -17,7 +17,7 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from scripts.cross_check import grow_single, make_case  # noqa: E402
+from scripts.cross_check import LADDER, grow_single, make_case  # noqa: E402
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "data", "crosscheck_tree.json")
 
@@ -29,7 +29,7 @@ def test_sharded_dense_matches_recorded_tpu_pallas_tree(mesh8):
         golden = json.load(f)
     bins, g, h, n, F, B = make_case()
     sig = grow_single(
-        bins, g, h, force_dense=True, partition=False,
+        bins, g, h, kernels="dense", ladder=(),
         devices=list(jax.devices()[:8]), B=B,
     )
     assert sig["n_nodes"] == golden["n_nodes"]
@@ -41,7 +41,7 @@ def test_sharded_dense_matches_recorded_tpu_pallas_tree(mesh8):
 
     # and the partitioned dense path lands on the same tree
     sig_part = grow_single(
-        bins, g, h, force_dense=True, partition=True,
+        bins, g, h, kernels="dense", ladder=LADDER,
         devices=list(jax.devices()[:8]), B=B,
     )
     assert sig_part["feat"] == golden["feat"]
@@ -57,7 +57,7 @@ def test_fused_partitioned_matches_recorded_tpu_pallas_tree():
         golden = json.load(f)
     bins, g, h, n, F, B = make_case()
     sig = grow_single(
-        bins, g, h, force_dense=True, partition=True, fused_interpret=True, B=B
+        bins, g, h, kernels="dense", ladder=LADDER, fused_interpret=True, B=B
     )
     assert sig["n_nodes"] == golden["n_nodes"]
     assert sig["feat"] == golden["feat"]

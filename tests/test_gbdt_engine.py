@@ -6,12 +6,18 @@ level policy exactly, loss policy exactly at wave=1 (strict best-first);
 wave>1 relaxes pop granularity and is checked for quality, not identity.
 """
 
+import os
+import signal
+
 import numpy as np
 import pytest
 
 from ytklearn_tpu.config.params import ApproximateSpec, GBDTParams, ModelParams
+from ytklearn_tpu.gbdt import trainer as trainer_mod
 from ytklearn_tpu.gbdt.data import GBDTData
+from ytklearn_tpu.gbdt.engine import GrowSpec
 from ytklearn_tpu.gbdt.trainer import GBDTTrainer
+from ytklearn_tpu.resilience.preempt import Preempted
 
 
 def _data(n=1200, F=6, seed=5):
@@ -72,7 +78,7 @@ def test_engine_matches_host(tmp_path, policy):
 
     res_h = GBDTTrainer(p_host, engine="host").train(train=_data())
     res_d = GBDTTrainer(
-        p_dev, engine="device", wave=1, use_bf16_hist=False
+        p_dev, engine="device", wave=1, hist_precision="f32"
     ).train(train=_data())
 
     assert len(res_h.model.trees) == len(res_d.model.trees)
@@ -135,10 +141,10 @@ def test_engine_multiclass_softmax(tmp_path):
 
 def test_int8_hist_exact_on_integer_grads():
     """With integer-valued g/h at max-abs 127 the int8 quantization is
-    lossless, so hist_wave_q must equal hist_wave exactly."""
+    lossless, so hist_wave at int8 must equal hist_wave at f32 exactly."""
     import jax.numpy as jnp
 
-    from ytklearn_tpu.gbdt.hist import hist_wave, hist_wave_q
+    from ytklearn_tpu.gbdt.hist import hist_wave
 
     rng = np.random.RandomState(0)
     n, F, B = 8192, 4, 16
@@ -150,13 +156,13 @@ def test_int8_hist_exact_on_integer_grads():
 
     ref = np.asarray(
         hist_wave(bins_t, pos, jnp.asarray(g_int), jnp.asarray(h_int), ids, B,
-                  use_bf16=False, force_dense=True)
+                  precision="f32", kernels="dense")
     )
     got = np.asarray(
-        hist_wave_q(
+        hist_wave(
             bins_t, pos,
             jnp.asarray(g_int), jnp.asarray(h_int),
-            ids, B, force_dense=True,
+            ids, B, precision="int8", kernels="dense",
         )
     ).astype(np.float32)
     np.testing.assert_array_equal(ref, got)
@@ -205,10 +211,10 @@ def test_engine_sharded_f32_quality(tmp_path, mesh8, policy):
     (tmp_path / "one").mkdir()
     (tmp_path / "eight").mkdir()
     res1 = GBDTTrainer(
-        p1, engine="device", wave=4, use_bf16_hist=False
+        p1, engine="device", wave=4, hist_precision="f32"
     ).train(train=_data(n=1600))
     res8 = GBDTTrainer(
-        p8, mesh=mesh8, engine="device", wave=4, use_bf16_hist=False
+        p8, mesh=mesh8, engine="device", wave=4, hist_precision="f32"
     ).train(train=_data(n=1600))
     assert res8.train_loss == pytest.approx(res1.train_loss, rel=1e-3)
     assert res8.train_metrics["auc"] == pytest.approx(
@@ -217,7 +223,7 @@ def test_engine_sharded_f32_quality(tmp_path, mesh8, policy):
 
 
 def test_partitioned_hist_matches_full_scan(tmp_path, monkeypatch):
-    """Leaf-partitioned histogram passes (GrowSpec.partition — per-wave row
+    """Leaf-partitioned histogram passes (GrowSpec.ladder — per-wave row
     compaction + gathered-budget kernels) must grow IDENTICAL trees to the
     full-scan path: the same rows enter every histogram, and in int8 mode
     the i32 sums are order-independent, so equality is exact."""
@@ -226,15 +232,15 @@ def test_partitioned_hist_matches_full_scan(tmp_path, monkeypatch):
     p_off = _params(tmp_path / "off", "loss", round_num=3, max_leaf_cnt=24)
     (tmp_path / "on").mkdir()
     (tmp_path / "off").mkdir()
-    monkeypatch.delenv("YTK_NO_PARTITION", raising=False)
-    monkeypatch.setenv("YTK_PARTITION", "1")  # explicit: also real on a TPU
-    res_on = GBDTTrainer(
-        p_on, engine="device", wave=8, hist_precision="int8"
-    ).train(train=data)
-    monkeypatch.setenv("YTK_NO_PARTITION", "1")
-    res_off = GBDTTrainer(
-        p_off, engine="device", wave=8, hist_precision="int8"
-    ).train(train=data)
+    tr_on = GBDTTrainer(p_on, engine="device", wave=8, hist_precision="int8")
+    res_on = tr_on.train(train=data)
+    # no ladder, no partitioned pass (whichever family this platform is)
+    monkeypatch.setattr(
+        trainer_mod, "LADDER", {k: () for k in trainer_mod.LADDER}
+    )
+    tr_off = GBDTTrainer(p_off, engine="device", wave=8, hist_precision="int8")
+    res_off = tr_off.train(train=data)
+    assert tr_on.time_stats["partition"] and not tr_off.time_stats["partition"]
     assert len(res_on.model.trees) == len(res_off.model.trees)
     for t_on, t_off in zip(res_on.model.trees, res_off.model.trees):
         assert _tree_sig(t_on) == _tree_sig(t_off)
@@ -242,12 +248,10 @@ def test_partitioned_hist_matches_full_scan(tmp_path, monkeypatch):
     assert res_on.train_loss == pytest.approx(res_off.train_loss, rel=1e-6)
 
 
-def test_partitioned_hist_sharded(tmp_path, mesh8, monkeypatch):
+def test_partitioned_hist_sharded(tmp_path, mesh8):
     """Partitioned hist under shard_map: shard-local budget choice with the
     psum_scatter outside the ladder conds — 8-device trees must still equal
     the single-device int8 trees exactly."""
-    monkeypatch.delenv("YTK_NO_PARTITION", raising=False)
-    monkeypatch.setenv("YTK_PARTITION", "1")  # explicit: also real on a TPU
     p1 = _params(tmp_path / "one", "loss", round_num=2, max_leaf_cnt=16)
     p8 = _params(tmp_path / "eight", "loss", round_num=2, max_leaf_cnt=16)
     (tmp_path / "one").mkdir()
@@ -255,8 +259,177 @@ def test_partitioned_hist_sharded(tmp_path, mesh8, monkeypatch):
     res1 = GBDTTrainer(
         p1, engine="device", wave=4, hist_precision="int8"
     ).train(train=_data(n=2560))
-    res8 = GBDTTrainer(
+    tr8 = GBDTTrainer(
         p8, mesh=mesh8, engine="device", wave=4, hist_precision="int8"
-    ).train(train=_data(n=2560))
+    )
+    res8 = tr8.train(train=_data(n=2560))
+    assert tr8.time_stats["partition"]  # 320 rows a shard: one 128-row rung
     for t1, t8 in zip(res1.model.trees, res8.model.trees):
         assert _tree_sig(t1) == _tree_sig(t8)
+
+
+# -- which histogram kernel runs: one table, no environment ----------------
+
+_HIGGS_ROWS = 10_502_144  # gbdt_higgs.train: 10.5M rows padded to bm 16384
+
+
+def _rung_spec(**kw):
+    base = dict(
+        F=28, B=256, max_nodes=509, wave=64, policy="loss", max_depth=0,
+        max_leaves=255, lr=0.1, l1=0.0, l2=0.0, min_h=100.0, max_abs=0.0,
+        min_split_loss=0.0, min_split_samples=0.0,
+    )
+    base.update(kw)
+    return GrowSpec(**base)
+
+
+def _family(kernels):
+    """What _grow_spec hands the engine for one implementation family."""
+    return dict(
+        kernels=kernels, ladder=trainer_mod.LADDER[kernels],
+        fused_max_rows=trainer_mod.FUSED_MAX_ROWS,
+    )
+
+
+@pytest.mark.parametrize(
+    "kw, n, want",
+    [
+        # the benchmark cell's shape on the chip: ceil(n/256) and ceil(n/64)
+        # up to bm_g 1024, both under 2^18 -> two fused rungs
+        (_family("pallas"), _HIGGS_ROWS,
+         ((41_984, "fused"), (164_864, "fused"))),
+        # the CPU family: XLA gathers at n/32 and n/8, in units of 128
+        (_family("dense"), 16_384, ((512, "xla"), (2_048, "xla"))),
+        # no ladder, no partitioned pass
+        (dict(kernels="pallas", ladder=()), _HIGGS_ROWS, ()),
+        # fused_max_rows 0: every rung takes the XLA gather, in units of bm
+        ({**_family("pallas"), "fused_max_rows": 0}, _HIGGS_ROWS,
+         ((49_152, "xla"), (180_224, "xla"))),
+        # a rung over the cap falls back to the XLA gather, the other fuses
+        ({**_family("pallas"), "fused_max_rows": 100_000}, _HIGGS_ROWS,
+         ((41_984, "fused"), (180_224, "xla"))),
+        # a budget that is not smaller than n is no rung
+        (dict(kernels="dense", ladder=(1, 8)), 256, ((128, "xla"),)),
+        # two divisors that round to one budget give one rung
+        (dict(kernels="dense", ladder=(16, 32)), 1_024, ((128, "xla"),)),
+        # the dense family fuses only through the interpreter (tests)
+        (dict(kernels="dense", ladder=(4,), bm_g=512, fused_interpret=True),
+         4_096, ((1_024, "fused"),)),
+    ],
+)
+def test_rung_table(kw, n, want):
+    assert _rung_spec(**kw).rungs(n) == want
+
+
+_RETIRED = {
+    "YTK_PARTITION": "0", "YTK_NO_PARTITION": "1", "YTK_LADDER": "2,4",
+    "YTK_FUSED": "0", "YTK_FUSED_MAX_ROWS": "1",
+}
+
+
+def test_grow_spec_ignores_retired_knobs(tmp_path, monkeypatch):
+    """The five environment variables that used to steer the kernel choice
+    are neither read nor declared: the choice is the platform's."""
+    import jax
+
+    from ytklearn_tpu.config import knobs
+
+    tr = GBDTTrainer(_params(tmp_path, "loss"), engine="device")
+    spec = tr._grow_spec(28, 256)
+    family = "pallas" if jax.default_backend() == "tpu" else "dense"
+    assert (spec.kernels, spec.ladder, spec.fused_max_rows) == (
+        family, trainer_mod.LADDER[family], trainer_mod.FUSED_MAX_ROWS,
+    )
+    for name, val in _RETIRED.items():
+        monkeypatch.setenv(name, val)
+    assert tr._grow_spec(28, 256) == spec
+    for name in _RETIRED:
+        assert name not in knobs.KNOBS
+        with pytest.raises(KeyError, match="undeclared knob"):
+            knobs.get_raw(name)
+
+
+def test_benchmark_contract(tmp_path):
+    """What perfbench/families/gbdt.py depends on, held at a toy size: the
+    constructor's keywords, train(train=, test=), the three methods it
+    wraps on the instance, and time_stats["preprocess"]."""
+    from ytklearn_tpu.io.fs import LocalFileSystem
+
+    p = _params(tmp_path, "loss", round_num=4, max_leaf_cnt=8)
+    tr = GBDTTrainer(p, mesh=None, fs=LocalFileSystem(), hist_precision="int8")
+    seen = {"probe": 0, "calls": 0, "rounds": 0, "preempt": []}
+    orig_probe, orig_rounds = tr._probe_compile, tr._run_rounds
+    orig_preempt = tr._preempt_checkpoint
+
+    def probe_compile(jit_round, carry, data, start_round):
+        compiled = orig_probe(jit_round, carry, data, start_round)
+        seen["probe"] += 1
+
+        def counted(carry, rnd, key, data):
+            seen["calls"] += 1
+            if seen["calls"] == 2:
+                os.kill(os.getpid(), signal.SIGTERM)  # the harness's stop
+            return compiled(carry, rnd, key, data)
+
+        return counted
+
+    def run_rounds(*a, **kw):
+        seen["rounds"] += 1
+        return orig_rounds(*a, **kw)
+
+    def preempt_checkpoint(model, bufs, bins, names, rnd):
+        seen["preempt"].append((rnd, tuple(np.asarray(bufs["wlog"]).shape)))
+        return orig_preempt(model, bufs, bins, names, rnd)
+
+    tr._probe_compile = probe_compile
+    tr._run_rounds = run_rounds
+    tr._preempt_checkpoint = preempt_checkpoint
+    with pytest.raises(Preempted):
+        tr.train(train=_data(), test=_data(seed=11))
+    assert seen["probe"] == 1 and seen["rounds"] == 1 and seen["calls"] == 2
+    # stopped at the boundary after the second round, wave log in hand
+    assert [r for r, _ in seen["preempt"]] == [2]
+    assert seen["preempt"][0][1][0] == 4 and seen["preempt"][0][1][2] == 5
+    assert tr.time_stats["preprocess"] > 0
+
+    # a run that ends by itself hands the carry back through _run_rounds
+    p2 = _params(tmp_path / "b", "loss", round_num=2, max_leaf_cnt=8)
+    (tmp_path / "b").mkdir()
+    tr2 = GBDTTrainer(p2, mesh=None, fs=LocalFileSystem())
+    out = {}
+    orig2 = tr2._run_rounds
+
+    def run_rounds2(*a, **kw):
+        out["carry"] = orig2(*a, **kw)
+        return out["carry"]
+
+    tr2._run_rounds = run_rounds2
+    tr2.train(train=_data(), test=_data(seed=11))
+    carry = out["carry"]
+    assert np.asarray(carry[2]["wlog"]).shape[0] == 2
+    assert np.asarray(carry[3]).shape == (2,)
+    assert np.all(np.asarray(carry[3]) > 0)
+
+
+@pytest.mark.parametrize("engine", ["host", "auto"])
+def test_precise_lad_reaches_host_engine(tmp_path, monkeypatch, engine):
+    """engine="host", and "auto" with precise LAD refinement, train through
+    gbdt/host_engine.py's train_host on the trainer."""
+    from ytklearn_tpu.gbdt import host_engine
+
+    calls = []
+    orig = host_engine.train_host
+
+    def spy(trainer, train=None, test=None):
+        calls.append(trainer)
+        return orig(trainer, train, test)
+
+    monkeypatch.setattr(trainer_mod, "train_host", spy)
+    p = _params(
+        tmp_path, "level", round_num=2, loss_function="l1",
+        eval_metric=[], lad_refine_appr=False,
+    )
+    tr = GBDTTrainer(p, engine=engine)
+    res = tr.train(train=_data())
+    assert calls == [tr] and tr.engine == "host"
+    assert len(res.model.trees) == 2

@@ -106,7 +106,7 @@ def _spec(F, B, **over):
     kw = dict(
         F=F, B=B, max_nodes=15, wave=2, policy="loss", max_depth=10,
         max_leaves=8, lr=0.3, l1=0.0, l2=1.0, min_h=1.0, max_abs=0.0,
-        min_split_loss=0.0, min_split_samples=0.0, force_dense=True,
+        min_split_loss=0.0, min_split_samples=0.0, kernels="dense",
     )
     kw.update(over)
     return GrowSpec(**kw)
@@ -254,7 +254,7 @@ def test_goss_mesh8_matches_manual_union(mesh8):
         top = np.argsort(-np.abs(g[sl]), kind="stable")[:k]
         keep[sl[top]] = True
 
-    spec_goss = _spec(F, B, hist_mode="int8", goss_a=0.5, goss_b=0.0)
+    spec_goss = _spec(F, B, precision="int8", goss_a=0.5, goss_b=0.0)
     grow8 = make_grow_tree(spec_goss, mesh=mesh8)
     args = (
         jnp.asarray(bins_np), jnp.ones((n,), bool),
@@ -264,7 +264,7 @@ def test_goss_mesh8_matches_manual_union(mesh8):
         lambda *a: grow8(*a, key=jax.random.PRNGKey(0))
     )(*args)
 
-    grow1 = make_grow_tree(_spec(F, B, hist_mode="int8"))
+    grow1 = make_grow_tree(_spec(F, B, precision="int8"))
     tr1, pos1, _a, _w1 = jax.jit(lambda *a: grow1(*a))(
         jnp.asarray(bins_np), jnp.asarray(keep),
         jnp.asarray(g), jnp.asarray(h), jnp.ones((F,), bool),

@@ -22,7 +22,6 @@ from ytklearn_tpu.gbdt.hist import (
     gather_table,
     hist_wave,
     hist_wave_gather,
-    hist_wave_q,
 )
 
 
@@ -60,7 +59,7 @@ def test_fused_kernel_matches_dense_f32():
         hist_wave(
             jnp.asarray(rows.T.astype(np.int32)), jnp.asarray(pos),
             jnp.asarray(g), jnp.asarray(h), jnp.asarray(ids), B,
-            use_bf16=False, force_dense=True,
+            precision="f32", kernels="dense",
         )
     )
     got = np.asarray(
@@ -68,7 +67,7 @@ def test_fused_kernel_matches_dense_f32():
             gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
             jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
             jnp.asarray(ids), rows.shape[1], B,
-            mode="mxu", use_bf16=False, bm_g=bm_g, interpret=True,
+            precision="f32", kernels="dense", bm_g=bm_g, interpret=True,
         )
     )
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
@@ -81,10 +80,10 @@ def test_fused_kernel_matches_dense_int8_exact():
     hi = np.round(np.clip(h * 20, 0, 127)).astype(np.float32)
     idx, pg, gg, hg = _compact(pos, gi, hi, ids, R)
     ref = np.asarray(
-        hist_wave_q(
+        hist_wave(
             jnp.asarray(rows.T.astype(np.int32)), jnp.asarray(pos),
             jnp.asarray(gi), jnp.asarray(hi), jnp.asarray(ids), B,
-            force_dense=True,
+            precision="int8", kernels="dense",
         )
     )
     got = np.asarray(
@@ -92,18 +91,18 @@ def test_fused_kernel_matches_dense_int8_exact():
             gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
             jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
             jnp.asarray(ids), rows.shape[1], B,
-            mode="int8", bm_g=bm_g, interpret=True,
+            precision="int8", kernels="dense", bm_g=bm_g, interpret=True,
         )
     )
     np.testing.assert_array_equal(got, ref)
-    # the dense fallback (what mode="int8" runs off-TPU in production)
-    # lands on the identical i32 sums
+    # the dense family (what runs off-TPU in production) lands on the
+    # identical i32 sums
     got_dense = np.asarray(
         hist_wave_gather(
             gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
             jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
             jnp.asarray(ids), rows.shape[1], B,
-            mode="int8", bm_g=bm_g, force_dense=True,
+            precision="int8", kernels="dense", bm_g=bm_g,
         )
     )
     np.testing.assert_array_equal(got_dense, ref)
@@ -120,9 +119,10 @@ def test_fused_kernel_wide_bins():
     ids = np.asarray([0, 1], np.int32)
     idx, pg, gg, hg = _compact(pos, g, h, ids, n)
     ref = np.asarray(
-        hist_wave_q(
+        hist_wave(
             jnp.asarray(rows.T), jnp.asarray(pos), jnp.asarray(g),
-            jnp.asarray(h), jnp.asarray(ids), B, force_dense=True,
+            jnp.asarray(h), jnp.asarray(ids), B,
+            precision="int8", kernels="dense",
         )
     )
     got = np.asarray(
@@ -130,7 +130,7 @@ def test_fused_kernel_wide_bins():
             gather_table(jnp.asarray(rows.T)), jnp.asarray(idx),
             jnp.asarray(pg), jnp.asarray(gg), jnp.asarray(hg),
             jnp.asarray(ids), rows.shape[1], B,
-            mode="int8", bm_g=256, interpret=True,
+            precision="int8", kernels="dense", bm_g=256, interpret=True,
         )
     )
     np.testing.assert_array_equal(got, ref)
@@ -156,9 +156,8 @@ def _spec(F, B, **over):
     kw = dict(
         F=F, B=B, max_nodes=31, wave=4, policy="loss", max_depth=20,
         max_leaves=16, lr=0.1, l1=0.0, l2=1.0, min_h=1.0, max_abs=0.0,
-        min_split_loss=0.0, min_split_samples=0.0, hist_mode="int8",
-        force_dense=True, partition=True, ladder=(4, 16),
-        fused=True, fused_max_rows=1 << 18, bm_g=512,
+        min_split_loss=0.0, min_split_samples=0.0, precision="int8",
+        kernels="dense", ladder=(4, 16), fused_max_rows=1 << 18, bm_g=512,
     )
     kw.update(over)
     return GrowSpec(**kw)
@@ -203,7 +202,7 @@ def test_fused_engine_matches_full_scan_exact():
     int8 i32 sums are order-independent."""
     bins, g, h = _grow_case()
     sig_fused, wlog = _grow_tree_sig(_spec(6, 32, fused_interpret=True), bins, g, h)
-    sig_full, _ = _grow_tree_sig(_spec(6, 32, partition=False), bins, g, h)
+    sig_full, _ = _grow_tree_sig(_spec(6, 32, ladder=()), bins, g, h)
     assert sig_fused == sig_full
     # the wave log proves late waves ran at partitioned budgets: at least
     # one histogram pass scanned fewer rows than the full 6144
@@ -238,5 +237,5 @@ def test_fused_rung_selection():
               bm_g=256),
         bins, g, h,
     )
-    sig_full, _ = _grow_tree_sig(_spec(6, 32, partition=False), bins, g, h)
+    sig_full, _ = _grow_tree_sig(_spec(6, 32, ladder=()), bins, g, h)
     assert sig_mixed == sig_full

@@ -49,7 +49,7 @@ def gbdt_wave():
 
     hist_fn = jax.jit(
         lambda bins_t, pos, g, h, ids: hist_wave(
-            bins_t, pos, g, h, ids, B=_B, use_bf16=False
+            bins_t, pos, g, h, ids, B=_B, precision="f32", kernels="dense"
         )
     )
     cfg = (0.0, 1.0, 1e-3, 0.0)  # (l1, l2, min_child_hessian, max_abs)
@@ -117,7 +117,7 @@ def goss_efb_grow():
     spec = GrowSpec(
         F=F, B=B, max_nodes=15, wave=2, policy="loss", max_depth=8,
         max_leaves=8, lr=0.3, l1=0.0, l2=1.0, min_h=1e-3, max_abs=0.0,
-        min_split_loss=0.0, min_split_samples=0.0, force_dense=True,
+        min_split_loss=0.0, min_split_samples=0.0, kernels="dense",
         goss_a=0.5, goss_b=0.25,
     )
     grow = jax.jit(make_grow_tree(spec, ranges=(rlo, rhi)))

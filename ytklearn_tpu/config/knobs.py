@@ -87,20 +87,6 @@ _knob("YTK_CHUNK_BUDGET_MB", "int", 1024,
       "score-intermediate memory budget that sizes the automatic row chunk")
 
 # -- gbdt engine ------------------------------------------------------------
-_knob("YTK_PARTITION", "bool", True,
-      "leaf-partitioned GBDT histogram phases (default on since r6; "
-      "`0` turns them off)")
-_knob("YTK_NO_PARTITION", "bool", False,
-      "hard-disable leaf-partitioned histograms everywhere "
-      "(wins over `YTK_PARTITION`)")
-_knob("YTK_LADDER", "str", None,
-      "comma-separated budget-ladder divisors for partitioned histogram "
-      "passes (default: `64,256` fused on TPU, `8,32` on CPU)")
-_knob("YTK_FUSED", "bool", True,
-      "fused compact+gather+histogram Pallas kernel for partitioned "
-      "passes (`0` falls back to XLA gather)")
-_knob("YTK_FUSED_MAX_ROWS", "int", 1 << 18,
-      "max gathered rows per fused-kernel call (VMEM sizing)")
 _knob("YTK_PROFILE_DIR", "str", None,
       "write a jax.profiler trace of the training loop for xprof")
 _knob("YTK_GOSS_A", "float", 1.0,

@@ -35,7 +35,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,6 @@ from .hist import (
     gather_table,
     hist_wave,
     hist_wave_gather,
-    hist_wave_q,
     tile_bins,
 )
 from .route import route_wave
@@ -238,30 +237,31 @@ class GrowSpec:
     min_split_loss: float
     min_split_samples: float
     bm: int = 16384  # keep in sync with hist.BM_DEFAULT (trainer padding)
-    use_bf16: bool = True
-    force_dense: bool = False
-    hist_mode: str = "mxu"  # "mxu" (bf16/f32 per use_bf16) | "int8"
+    precision: str = "bf16"  # histogram operands: "bf16" | "f32" | "int8"
+    # histogram and routing kernels' family: "pallas" (Mosaic, the chip) |
+    # "dense" (einsum / XLA twins, where Mosaic can't compile); resolved
+    # once from the platform (GBDTTrainer._grow_spec), asked nowhere below
+    kernels: str = "pallas"
     # leaf-partitioned histogram passes: once the frontier's waves need few
     # rows, compact the smaller-child rows into a static budget and
     # histogram only those — wave cost scales with rows-in-wave instead of
     # all n (the LightGBM data-partition idea; reference hot loop
     # HistogramBuilder.java:72-90 likewise iterates node intervals only).
-    # `ladder` lists the budget divisors; growth runs as phase-separated
-    # while_loops (full scan while waves are big, then each budget, then a
-    # full-scan safety tail) because lax.cond around Mosaic kernels is a
-    # compile catastrophe on the current toolchain.
-    partition: bool = True
+    # `ladder` lists the budget divisors, () = no partitioned pass; growth
+    # runs as phase-separated while_loops (full scan while waves are big,
+    # then each budget, then a full-scan safety tail) because lax.cond
+    # around Mosaic kernels is a compile catastrophe on the current
+    # toolchain.
     ladder: Tuple[int, ...] = (8, 32)
     # fused compact+gather+histogram kernel (hist.hist_wave_gather): budget
     # rungs at or under `fused_max_rows` skip the XLA (R, F) row gather +
     # transpose entirely — the kernel DMAs each selected row HBM->VMEM and
     # accumulates in place. Rungs above the cap keep the XLA gather (the
     # fused kernel's per-row DMA issue loop is O(R) scalar work, so huge
-    # budgets would pay more in descriptors than they save in MACs).
-    # `fused_interpret` runs the fused kernel through the Pallas
-    # interpreter off-TPU — equivalence tests of the REAL kernel logic on
-    # the CPU mesh.
-    fused: bool = True
+    # budgets would pay more in descriptors than they save in MACs); 0 =
+    # every rung takes the XLA gather. `fused_interpret` runs the fused
+    # kernel through the Pallas interpreter in the dense family —
+    # equivalence tests of the REAL kernel logic on the CPU mesh.
     fused_max_rows: int = 1 << 18
     fused_interpret: bool = False
     bm_g: int = BMG_DEFAULT
@@ -293,6 +293,42 @@ class GrowSpec:
         # unlimited -> whatever fits the fixed arrays (nodes = 2*leaves-1)
         return self.max_leaves if self.max_leaves > 0 else (self.max_nodes + 1) // 2
 
+    def goss_sizes(self, n_full: int) -> Tuple[int, int, int]:
+        """GOSS's static sizes over `n_full` (padded, per-shard) rows: (top
+        rows k_a, sampled remainder rows k_b, width R_fit of the compacted
+        fit matrix). Counted over the REAL rows (goss_scale discounts
+        padding; the engine re-masks so padding never leaks)."""
+        gunit = self.bm if self.kernels == "pallas" else 128
+        n_eff = max(1, min(n_full, int(np.ceil(self.goss_scale * n_full))))
+        k_a = max(1, min(n_eff, int(np.ceil(self.goss_a * n_eff))))
+        k_b = 0
+        if self.goss_b > 0.0:
+            k_b = min(n_eff - k_a, int(np.ceil(self.goss_b * (n_eff - k_a))))
+        R_fit = max(gunit, -(-(k_a + k_b) // gunit) * gunit)
+        return k_a, k_b, min(R_fit, n_full)
+
+    def rungs(self, n: int) -> Tuple[Tuple[int, str], ...]:
+        """The partitioned passes the growth program builds over `n` fit
+        rows (per shard), ascending ((R, impl), ...): static row budget R,
+        and "fused" (compact+gather+histogram in one Pallas kernel) or
+        "xla" (explicit row gather + the full-scan kernel: the only option
+        above fused_max_rows, where per-row DMA issue would dominate). A
+        wave hists only smaller children, so ceil(n/2) always fits the
+        largest budget. () = every pass is a full scan."""
+        can_fuse = self.fused_max_rows > 0 and (
+            self.kernels == "pallas" or self.fused_interpret
+        )
+        unit_xla = self.bm if self.kernels == "pallas" else 128
+        out = {}
+        for div in self.ladder:
+            want = -(-n // div)  # ceil(n / div)
+            fuse = can_fuse and want <= self.fused_max_rows
+            unit = self.bm_g if fuse else unit_xla
+            R = max(-(-want // unit) * unit, unit)
+            if R < n:
+                out.setdefault(R, "fused" if fuse else "xla")
+        return tuple(sorted(out.items()))
+
 
 class TreeArrays(NamedTuple):
     """Fixed-shape device tree (mirrors the host Tree fields that training
@@ -322,32 +358,6 @@ class _Frontier(NamedTuple):
     HR: jnp.ndarray
     CR: jnp.ndarray
     active: jnp.ndarray  # (M,) bool
-
-
-def _route_wave(
-    bins_t, pos, sel_valid, sel_nid, sel_feat, sel_slot, sel_lo, sel_hi,
-    sel_l, sel_r, NW,
-):
-    """Move samples of each wave node to its children: one bins_t row
-    dynamic-slice + compare per wave slot (masked no-op when invalid).
-
-    sel_lo/sel_hi bound the split's EFB member range: a row goes right
-    only when its bin is inside [lo, hi] AND above the slot — bins
-    outside the range are other bundle members (the split feature's
-    default/zero value, which sits left). Plain columns pass lo=0,
-    hi=B-1, reducing to the original `bin > slot` compare."""
-    n = pos.shape[0]
-
-    def body(i, pos):
-        f = jnp.maximum(sel_feat[i], 0)
-        row = jax.lax.dynamic_slice(bins_t, (f, jnp.zeros((), f.dtype)), (1, n))[0]
-        row = row.astype(jnp.int32)
-        go_right = (row > sel_slot[i]) & (row >= sel_lo[i]) & (row <= sel_hi[i])
-        child = jnp.where(go_right, sel_r[i], sel_l[i])
-        upd = jnp.where(pos == sel_nid[i], child, pos)
-        return jnp.where(sel_valid[i], upd, pos)
-
-    return jax.lax.fori_loop(0, NW, body, pos)
 
 
 def make_grow_tree(spec: GrowSpec, mesh=None, axis: str = "data", ranges=None):
@@ -514,16 +524,7 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         goss_rows = None  # per-shard GOSS-kept row count (wave-log col 4)
         if goss_on:
             n_full = bins_t.shape[1]
-            gunit = 128 if spec.force_dense else spec.bm
-            # static top/remainder counts over the REAL rows (goss_scale
-            # discounts padding; re-masked below so padding never leaks)
-            n_eff = max(1, min(n_full, int(np.ceil(spec.goss_scale * n_full))))
-            k_a = max(1, min(n_eff, int(np.ceil(spec.goss_a * n_eff))))
-            k_b = 0
-            if spec.goss_b > 0.0:
-                k_b = min(
-                    n_eff - k_a, int(np.ceil(spec.goss_b * (n_eff - k_a)))
-                )
+            k_a, k_b, R_fit = spec.goss_sizes(n_full)
             if key is None:
                 key = jax.random.PRNGKey(0)
             if n_shards > 1:
@@ -550,8 +551,6 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             # compact the kept rows into the static fit matrix (order-
             # preserving, so int8 histogram sums stay bit-stable); the
             # full matrix becomes aux[0] purely for final leaf assignment
-            R_fit = max(gunit, -(-(k_a + k_b) // gunit) * gunit)
-            R_fit = min(R_fit, n_full)
             idx_fit, goss_rows = compact_indices(keep, R_fit)
             valid_fit = jnp.arange(R_fit, dtype=jnp.int32) < goss_rows
             aux = (bins_t,) + tuple(aux)
@@ -566,27 +565,8 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         if goss_rows is None:
             goss_rows = jnp.sum(include, dtype=jnp.float32)
 
-        # leaf-partition budget ladder (static shapes, ascending): a wave
-        # hists only smaller children, so ceil(n/2) always fits budget 0.
-        # Each rung carries its implementation: "fused" (compact+gather+
-        # histogram in one Pallas kernel, small budgets) or "xla" (explicit
-        # row gather + the full-scan kernel, the only option above
-        # fused_max_rows where per-row DMA issue would dominate).
-        use_part = spec.partition
-        can_fuse = spec.fused and (not spec.force_dense or spec.fused_interpret)
-        unit_xla = 128 if spec.force_dense else spec.bm
-        if use_part:
-            rungs = []  # ascending [(R, impl)]
-            for div in spec.ladder:
-                want = -(-n // div)  # ceil(n / div)
-                fuse = can_fuse and want <= spec.fused_max_rows
-                unit = spec.bm_g if fuse else unit_xla
-                R = max(-(-want // unit) * unit, unit)
-                if R < n and R not in [r for r, _ in rungs]:
-                    rungs.append((R, "fused" if fuse else "xla"))
-            rungs.sort()
-            use_part = bool(rungs)
-        if use_part:
+        rungs = spec.rungs(n)  # ascending [(R, impl)]
+        if rungs:
             # row-major copies for the per-wave row gather, one per rung
             # implementation in use (shard-local under shard_map;
             # materialized once per tree): the fused kernel's lane-padded
@@ -602,14 +582,14 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         # tile once per tree: the Pallas kernels want (F, nblk, 1, bm); done
         # inside the wave loop XLA re-materializes the tiled copy EVERY wave
         # (~10 ms x 20 waves per tree at 10M rows, seen in xprof)
-        if not spec.force_dense:
+        if spec.kernels == "pallas":
             bins_k = tile_bins(bins_t, spec.bm)
             aux_k = tuple(tile_bins(bt, spec.bm) for bt in aux)
         else:
             bins_k = bins_t
             aux_k = aux
 
-        if spec.hist_mode == "int8":
+        if spec.precision == "int8":
             # per-tree symmetric int8 quantization of the (weighted) grads;
             # one-hot selection and counts stay exact, G/H sums carry a
             # bounded ~|g|max/(2*qmax)-per-sample rounding error in exchange
@@ -633,28 +613,19 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             inv = jnp.stack([1.0 / sg, 1.0 / sh, jnp.asarray(1.0)])
             G_, H_ = gq, hq
 
-            def hist_partial(bins_in, pos_v, g_v, h_v, ids):
-                return hist_wave_q(
-                    bins_in, pos_v, g_v, h_v, ids, B,
-                    bm=spec.bm, force_dense=spec.force_dense,
-                )  # (N, F, B, 3) i32 partial
-
-            def hist_finish(partial_h):
+            def hist_finish(partial_h):  # (N, F, B, 3) i32 partial
                 summed = combine_hist(partial_h)  # (N, F_loc, B, 3) global
                 return summed.astype(jnp.float32) * inv[None, None, None, :]
 
         else:
             G_, H_ = g, h
+            hist_finish = combine_hist
 
-            def hist_partial(bins_in, pos_v, g_v, h_v, ids):
-                return hist_wave(
-                    bins_in, pos_v, g_v, h_v, ids, B,
-                    bm=spec.bm, use_bf16=spec.use_bf16,
-                    force_dense=spec.force_dense,
-                )
-
-            def hist_finish(partial_h):
-                return combine_hist(partial_h)
+        def hist_partial(bins_in, pos_v, g_v, h_v, ids):
+            return hist_wave(
+                bins_in, pos_v, g_v, h_v, ids, B,
+                bm=spec.bm, precision=spec.precision, kernels=spec.kernels,
+            )
 
         def hist_call(pos_fit, ids):
             """Full-scan histogram (root + slow start + big-wave phases)."""
@@ -685,15 +656,13 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
                 if impl == "fused":
                     part = hist_wave_gather(
                         rows_fused, idx, pg, gg, hg, ids, F, B,
-                        mode=spec.hist_mode if spec.hist_mode == "int8" else "mxu",
-                        use_bf16=spec.use_bf16, bm_g=spec.bm_g,
-                        force_dense=spec.force_dense and not spec.fused_interpret,
-                        interpret=spec.fused_interpret,
+                        precision=spec.precision, kernels=spec.kernels,
+                        bm_g=spec.bm_g, interpret=spec.fused_interpret,
                     )
                     return hist_finish(part)
                 bg = jnp.take(rows_xla, idx, axis=0)  # (R, F) u8
                 bt = jnp.transpose(bg).astype(jnp.int32)
-                if not spec.force_dense:
+                if spec.kernels == "pallas":
                     bt = bt.reshape(F, R // spec.bm, 1, spec.bm)
                 return hist_finish(hist_partial(bt, pg, gg, hg, ids))
 
@@ -865,30 +834,17 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
 
             # routing (train + any aux sets)
             with scope("gbdt.route"):
-                if spec.force_dense:
-                    pos = _route_wave(
-                        bins_t, pos, sel_ok, nid, f_best, slot_l, sel_lo,
-                        sel_hi, lch, rch, nw,
+                pos = route_wave(
+                    bins_k, pos, sel_ok, nid, f_best, slot_l, lch, rch,
+                    sel_lo, sel_hi, kernels=spec.kernels, bm=spec.bm,
+                )
+                aux_pos = tuple(
+                    route_wave(
+                        bt, ap, sel_ok, nid, f_best, slot_l, lch, rch,
+                        sel_lo, sel_hi, kernels=spec.kernels, bm=spec.bm,
                     )
-                    aux_pos = tuple(
-                        _route_wave(
-                            bt, ap, sel_ok, nid, f_best, slot_l, sel_lo,
-                            sel_hi, lch, rch, nw,
-                        )
-                        for bt, ap in zip(aux, aux_pos)
-                    )
-                else:
-                    pos = route_wave(
-                        bins_k, pos, sel_ok, nid, f_best, slot_l, lch, rch,
-                        bm=spec.bm, lo=sel_lo, hi=sel_hi,
-                    )
-                    aux_pos = tuple(
-                        route_wave(
-                            bt, ap, sel_ok, nid, f_best, slot_l, lch, rch,
-                            bm=spec.bm, lo=sel_lo, hi=sel_hi,
-                        )
-                        for bt, ap in zip(aux_k, aux_pos)
-                    )
+                    for bt, ap in zip(aux_k, aux_pos)
+                )
 
             # smaller-child histogram + sibling subtraction
             small = jnp.where(CLs <= CRs, lch, rch)
@@ -949,7 +905,7 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             state = wave_body(state, nw_ss)
             nw_ss *= 2
 
-        if use_part:
+        if rungs:
             # phase-separated growth: full scans while waves are big, then
             # tighter partitioned budgets as the frontier's row need
             # shrinks, then a full-scan tail for any non-monotone leftovers

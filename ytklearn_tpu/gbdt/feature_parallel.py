@@ -16,7 +16,7 @@ on row-sharded g/h is XLA's all_gather — the same wire traffic the
 reference issued by hand at :274/:443.
 
 Growth is level-synchronous on the host (one jitted sharded step per
-level), mirroring GBDTTrainer.build_tree_level_wise so the two makers grow
+level), mirroring host_engine.build_tree_level_wise so the two makers grow
 identical trees on identical inputs.
 """
 
@@ -34,6 +34,7 @@ from ..parallel.collectives import pargmax_tuple
 from ..parallel.mesh import DATA_AXIS
 from .engine import split_kernel
 from .hist import hist_wave
+from .host_engine import _decide_split, _finish_split
 from .tree import Tree
 
 
@@ -72,7 +73,7 @@ def _make_level_step(mesh, F_pad: int, B: int, cfg, n_nodes: int):
         # f32 accumulation: this maker is the exactness-focused one (bf16
         # would desync its gains from the data-parallel maker's f32 scatter)
         hist = hist_wave(
-            bins_local, pos, g, h, node_ids, B, use_bf16=False, force_dense=True
+            bins_local, pos, g, h, node_ids, B, precision="f32", kernels="dense"
         )  # (N, F_loc, B, 3)
         out = split_kernel(hist, feat_mask_local, cfg)
         (chg, flat, slotl, GL, HL, CL, GR, HR, CR) = out
@@ -151,7 +152,7 @@ def build_tree_level_feature_parallel(
 ) -> Tree:
     """Level-synchronous exact-greedy growth with feature-sharded search.
 
-    Mirrors GBDTTrainer.build_tree_level_wise's host loop; only the
+    Mirrors host_engine.build_tree_level_wise's host loop; only the
     histogram/split/route kernels differ (sharded + merged)."""
     p = trainer.params
     tree = Tree()
@@ -198,11 +199,12 @@ def build_tree_level_feature_parallel(
             can = (
                 depth < max_depth
                 and leaves_after + 1 < max_leaves + 1
-                and trainer._decide_split(chg[k], CL[k], CR[k], HL[k], HR[k])
+                and _decide_split(trainer, chg[k], CL[k], CR[k], HL[k], HR[k])
             )
             if not can:
                 continue
-            left, right = trainer._finish_split(
+            left, right = _finish_split(
+                trainer,
                 tree,
                 names,
                 nid,

@@ -20,12 +20,15 @@ Samples whose pos is not in `node_ids` (including pos = -1 dead rows)
 match no one-hot row and vanish.
 
 bf16 operands halve MXU time; histogram sums accumulate in f32 either
-way (counts stay exact — 0/1 one-hots are exact in bf16). use_bf16=False
+way (counts stay exact — 0/1 one-hots are exact in bf16). precision="f32"
 forces true-f32 MXU passes (Precision.HIGHEST — TPU silently runs f32
-dots at bf16 input precision otherwise).
+dots at bf16 input precision otherwise); "int8" takes pre-quantized
+grads and accumulates in i32.
 
-A dense-einsum fallback provides the same math on CPU (tests run on the
-virtual mesh with JAX_PLATFORMS=cpu where Mosaic kernels can't compile).
+A dense-einsum twin provides the same math where Mosaic kernels can't
+compile (tests run on the virtual mesh with JAX_PLATFORMS=cpu). Which
+family runs is the caller's `kernels` ("pallas" | "dense"), resolved once
+from the platform (GBDTTrainer._grow_spec); nothing here asks it.
 """
 
 from __future__ import annotations
@@ -208,25 +211,6 @@ def _hist_dense_q(bins_t, pos, gq, hq, node_ids, B: int):
     return jnp.concatenate([hg, hh, hc], axis=1)  # (F, 3N, B) i32
 
 
-def hist_wave_q(
-    bins_t, pos, gq, hq, node_ids, B: int, bm: int = BM_DEFAULT,
-    force_dense: bool = False,
-):
-    """(N, F, B, 3) int32 histograms from int8-quantized grads."""
-    F = bins_t.shape[0]
-    N = node_ids.shape[0]
-    on_tpu = jax.default_backend() == "tpu"
-    with scope("gbdt.hist"):
-        if on_tpu and not force_dense:
-            bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
-            out = _hist_pallas_q(bins4, pos, gq, hq, node_ids, B, bm, _pick_fg(F))
-        else:
-            bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
-            out = _hist_dense_q(bins2, pos, gq, hq, node_ids, B)
-        out = out.reshape(F, 3, N, B)
-        return jnp.transpose(out, (2, 0, 3, 1))
-
-
 @partial(jax.jit, static_argnames=("B", "use_bf16"))
 def _hist_dense(bins_t, pos, g, h, node_ids, B: int, use_bf16: bool):
     """Same math as the Pallas kernel via einsum (CPU / fallback path)."""
@@ -241,6 +225,12 @@ def _hist_dense(bins_t, pos, g, h, node_ids, B: int, use_bf16: bool):
     hh = jnp.einsum("xn,fbn->fxb", P * hv[None, :], OH, preferred_element_type=jnp.float32)
     hc = jnp.einsum("xn,fbn->fxb", P, OH, preferred_element_type=jnp.float32)
     return jnp.concatenate([hg, hh, hc], axis=1)  # (F, 3N, B)
+
+
+def _hist_dense_at(precision: str, bins_t, pos, g, h, node_ids, B: int):
+    if precision == "int8":
+        return _hist_dense_q(bins_t, pos, g, h, node_ids, B)
+    return _hist_dense(bins_t, pos, g, h, node_ids, B, precision == "bf16")
 
 
 def _pick_fg(F: int) -> int:
@@ -259,29 +249,39 @@ def hist_wave(
     h,
     node_ids,
     B: int,
+    *,
+    precision: str,
+    kernels: str,
     bm: int = BM_DEFAULT,
-    use_bf16: bool = True,
-    force_dense: bool = False,
 ):
     """(N, F, B, 3) histograms for the nodes listed in `node_ids`.
 
-    bins_t   (F, n) int32 — transposed bin matrix (n padded to bm)
+    bins_t   (F, n) int32 — transposed bin matrix (n padded to bm), or
+                            pre-tiled (F, n/bm, 1, bm)
     pos      (n,) int32   — tree-node id per sample (-1 or absent = skip)
-    g, h     (n,) f32     — weighted grad / hess per sample
+    g, h     (n,) f32     — weighted grad / hess per sample; at "int8"
+                            f32 integers in [-127, 127] (caller's scales)
     node_ids (N,) int32   — node ids to histogram (-2 pads: match nothing)
+    precision "bf16" | "f32" (f32 sums) | "int8" (exact int32 sums)
+    kernels   "pallas" (Mosaic, the chip) | "dense" (einsum, elsewhere)
     """
     F = bins_t.shape[0]
     N = node_ids.shape[0]
-    on_tpu = jax.default_backend() == "tpu"
     with scope("gbdt.hist"):
-        if on_tpu and not force_dense:
+        if kernels == "pallas":
             bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
-            out = _hist_pallas(
-                bins4, pos, g, h, node_ids, B, bm, _pick_fg(F), use_bf16
-            )
+            if precision == "int8":
+                out = _hist_pallas_q(
+                    bins4, pos, g, h, node_ids, B, bm, _pick_fg(F)
+                )
+            else:
+                out = _hist_pallas(
+                    bins4, pos, g, h, node_ids, B, bm, _pick_fg(F),
+                    precision == "bf16",
+                )
         else:
             bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
-            out = _hist_dense(bins2, pos, g, h, node_ids, B, use_bf16)
+            out = _hist_dense_at(precision, bins2, pos, g, h, node_ids, B)
         # (F, 3N, B) -> (N, F, B, 3)
         out = out.reshape(F, 3, N, B)
         return jnp.transpose(out, (2, 0, 3, 1))
@@ -516,39 +516,35 @@ def hist_wave_gather(
     node_ids,
     F: int,
     B: int,
-    mode: str = "mxu",
-    use_bf16: bool = True,
+    *,
+    precision: str,
+    kernels: str,
     bm_g: int = BMG_DEFAULT,
-    force_dense: bool = False,
     interpret: bool = False,
 ):
     """(N, F, B, 3) partial histograms over a compacted row subset of
     `rows`, the gather_table() of the wave's (F, n) bin matrix.
 
-    The TPU path runs the fused gather+hist kernel; off-TPU (unless
-    `interpret` forces the Pallas interpreter, for tests) the same math
-    runs as an explicit (R, F) gather + dense einsum — bit-identical in
-    int8 mode. Output dtype matches hist_wave (f32) / hist_wave_q (i32).
+    kernels="pallas" runs the fused gather+hist kernel (`interpret` forces
+    it through the Pallas interpreter whatever the family, for tests);
+    "dense" runs the same math as an explicit (R, F) gather + dense einsum
+    — bit-identical at precision="int8". Output dtype as hist_wave's.
     """
     N = node_ids.shape[0]
-    on_tpu = jax.default_backend() == "tpu"
     with scope("gbdt.hist"):
-        if (on_tpu and not force_dense) or interpret:
-            if mode == "int8":
+        if kernels == "pallas" or interpret:
+            if precision == "int8":
                 out = _hist_gather_pallas_q(
                     rows, idx, pos_g, g, h, node_ids, F, B, bm_g, interpret
                 )
             else:
                 out = _hist_gather_pallas(
-                    rows, idx, pos_g, g, h, node_ids, F, B, bm_g, use_bf16,
-                    interpret,
+                    rows, idx, pos_g, g, h, node_ids, F, B, bm_g,
+                    precision == "bf16", interpret,
                 )
         else:
             bt = jnp.transpose(jnp.take(rows, idx, axis=0)[:, :F])
-            if mode == "int8":
-                out = _hist_dense_q(bt, pos_g, g, h, node_ids, B)
-            else:
-                out = _hist_dense(bt, pos_g, g, h, node_ids, B, use_bf16)
+            out = _hist_dense_at(precision, bt, pos_g, g, h, node_ids, B)
         out = out.reshape(F, 3, N, B)
         return jnp.transpose(out, (2, 0, 3, 1))
 

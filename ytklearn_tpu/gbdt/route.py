@@ -1,11 +1,11 @@
 """Sample-position routing kernel — the SamplePositionData equivalent.
 
-The XLA formulation (engine._route_wave) runs NW sequential full-array
+The XLA formulation (_route_dense) runs NW sequential full-array
 passes per wave: each slot re-reads one bins row (42 MB at 10.5M rows)
 AND rewrites the whole pos array — ~1.3 GB of HBM traffic per 16-slot
-wave. This kernel does the whole wave in ONE pass: per sample block it
-loads the block's bin rows once, resolves every slot's compare/select in
-VMEM, and writes pos once (~0.3 GB per wave with uint8 bins).
+wave. The Pallas kernel does the whole wave in ONE pass: per sample block
+it loads the block's bin rows once, resolves every slot's compare/select
+in VMEM, and writes pos once (~0.3 GB per wave with uint8 bins).
 
 Reference: SamplePositionData.resetPosition:115 (partition samples of a
 split node between its children).
@@ -17,6 +17,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from .hist import tile_bins
 
 
 @partial(jax.jit, static_argnames=("bm",))
@@ -77,33 +79,46 @@ def _route_pallas(bins4, pos, valid, nid, feat, slot, lo, hi, lch, rch, bm: int)
     )(tab, bins4, pos3).reshape(n)
 
 
-def route_wave(
-    bins_t, pos, valid, nid, feat, slot, lch, rch, bm: int = 8192,
-    lo=None, hi=None,
+def _route_dense(
+    bins_t, pos, sel_valid, sel_nid, sel_feat, sel_slot, sel_lo, sel_hi,
+    sel_l, sel_r,
 ):
-    """One-pass wave routing; XLA fallback off-TPU (see engine._route_wave).
+    """Move samples of each wave node to its children: one bins_t row
+    dynamic-slice + compare per wave slot (masked no-op when invalid).
 
-    bins_t: (F, n) or pre-tiled (F, nblk, 1, bm). lo/hi: optional per-slot
-    EFB member-range bounds (default: unbounded, the plain bin > slot
-    compare)."""
-    F = bins_t.shape[0]
-    NW = nid.shape[0]
-    if lo is None:
-        lo = jnp.zeros((NW,), jnp.int32)
-    if hi is None:
-        hi = jnp.full((NW,), 2**30, jnp.int32)
-    if jax.default_backend() == "tpu":
-        from .hist import tile_bins
+    sel_lo/sel_hi bound the split's EFB member range: a row goes right
+    only when its bin is inside [lo, hi] AND above the slot — bins
+    outside the range are other bundle members (the split feature's
+    default/zero value, which sits left). Plain columns pass lo=0,
+    hi=B-1, reducing to the original `bin > slot` compare."""
+    n = pos.shape[0]
 
+    def body(i, pos):
+        f = jnp.maximum(sel_feat[i], 0)
+        row = jax.lax.dynamic_slice(bins_t, (f, jnp.zeros((), f.dtype)), (1, n))[0]
+        row = row.astype(jnp.int32)
+        go_right = (row > sel_slot[i]) & (row >= sel_lo[i]) & (row <= sel_hi[i])
+        child = jnp.where(go_right, sel_r[i], sel_l[i])
+        upd = jnp.where(pos == sel_nid[i], child, pos)
+        return jnp.where(sel_valid[i], upd, pos)
+
+    return jax.lax.fori_loop(0, sel_nid.shape[0], body, pos)
+
+
+def route_wave(
+    bins_t, pos, valid, nid, feat, slot, lch, rch, lo, hi, *, kernels: str,
+    bm: int,
+):
+    """One wave's routing: the one-pass kernel (kernels="pallas") or its
+    XLA twin ("dense"), as the caller says (hist.hist_wave's field).
+
+    bins_t: (F, n) or pre-tiled (F, nblk, 1, bm). lo/hi: per-slot EFB
+    member-range bounds (see _route_dense)."""
+    if kernels == "pallas":
         bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
         return _route_pallas(
             bins4, pos, valid, nid,
             jnp.maximum(feat, 0), slot, lo, hi, lch, rch, bm,
         )
-    from .engine import _route_wave
-
-    bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
-    return _route_wave(
-        bins2, pos, valid, nid, jnp.maximum(feat, 0), slot, lo, hi, lch, rch,
-        NW,
-    )
+    bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(bins_t.shape[0], -1)
+    return _route_dense(bins2, pos, valid, nid, feat, slot, lo, hi, lch, rch)

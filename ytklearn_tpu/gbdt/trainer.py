@@ -8,14 +8,15 @@ TreeRefiner.java (LAD weighted-median leaves).
 
 Two growth engines share the split/gain kernels (gbdt/engine.py):
 
-  device (default) — the whole tree grows inside one XLA program
-    (engine.make_grow_tree): Pallas one-hot-matmul histograms, on-device
-    frontier selection, sibling subtraction in a device histogram pool,
-    and per-round score/loss updates — zero host round-trips per round.
-  host — the original per-level/per-split host loop. Kept as the
-    reference implementation for equivalence tests, and used
-    automatically for l1 loss (LAD leaf refinement is a host-side
-    weighted median, reference TreeRefiner.java:72-123).
+  device (default, this file) — the whole tree grows inside one XLA
+    program (engine.make_grow_tree): Pallas one-hot-matmul histograms,
+    on-device frontier selection, sibling subtraction in a device
+    histogram pool, and per-round score/loss updates — zero host
+    round-trips per round.
+  host (gbdt/host_engine.py) — the original per-level/per-split host
+    loop. Kept as the reference implementation for equivalence tests, and
+    used automatically for precise LAD leaf refinement and the
+    feature-parallel maker. It calls what both engines share here.
 
 TPU-first design notes:
   - the bin matrix lives transposed (F, n) so routing is a row
@@ -32,10 +33,8 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import math
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -60,7 +59,6 @@ from ..obs import (
     span as obs_span,
     step_span as obs_step_span,
 )
-from ..parallel.mesh import row_sharding
 from ..resilience import chaos_point, trainer_guard
 from .binning import (
     FeatureBins,
@@ -76,72 +74,39 @@ from .engine import (
     GrowSpec,
     make_gain_fns,
     make_grow_tree,
-    split_kernel,
     wave_log_rows,
 )
 from .hist import BM_DEFAULT, pad_inputs
-from .tree import GBDTModel, Tree, unbundle_tree
+from .host_engine import train_host
+from .tree import (
+    GBDTModel,
+    Tree,
+    _traverse_kernel,
+    _wavg_loss,
+    unbundle_tree,
+)
 
 log = logging.getLogger("ytklearn_tpu.gbdt")
 
 
 # ---------------------------------------------------------------------------
-# Host-path device kernels (the original level/loss-wise implementation)
-# ---------------------------------------------------------------------------
-
-
-@partial(jax.jit, static_argnames=("n_nodes", "F", "B"))
-def hist_kernel(bins, pos, g, h, n_nodes: int, F: int, B: int):
-    """(n_nodes, F, B, 3) histogram of (g, h, count) by level-local node.
-
-    pos < 0 = inactive sample -> dump segment. Scatter-add formulation —
-    fine on CPU, slow on TPU (the device engine uses gbdt/hist.py)."""
-    n = bins.shape[0]
-    active = pos >= 0
-    base = jnp.where(active, pos, n_nodes) * (F * B)
-    ids = base[:, None] + jnp.arange(F)[None, :] * B + bins  # (n, F)
-    vals = jnp.stack(
-        [g, h, jnp.where(active, 1.0, 0.0)], axis=1
-    )  # (n, 3)
-    flat = jnp.zeros(((n_nodes + 1) * F * B, 3), jnp.float32)
-    flat = flat.at[ids.reshape(-1)].add(
-        jnp.repeat(vals, F, axis=0).reshape(n, F, 3).reshape(-1, 3)
-    )
-    return flat[: n_nodes * F * B].reshape(n_nodes, F, B, 3)
-
-
-@jax.jit
-def pos_update_kernel(bins, pos, node_feat, node_slot, node_child_base):
-    """Route samples to next-level-local child indices.
-
-    node_child_base[k] = left-child index among next level's nodes, or -1 if
-    node k became a leaf (reference: SamplePositionData.resetPosition:115)."""
-    safe = jnp.maximum(pos, 0)
-    f = node_feat[safe]
-    slot = node_slot[safe]
-    base = node_child_base[safe]
-    b = jnp.take_along_axis(bins, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
-    go_right = b > slot
-    new = jnp.where(base >= 0, base + go_right.astype(jnp.int32), -1)
-    return jnp.where(pos >= 0, new, -1)
-
-
-@partial(jax.jit, static_argnames=("F", "B"))
-def node_hist_kernel(bins, in_node, g, h, F: int, B: int):
-    """(F, B, 3) histogram for one node's samples (host loss-wise growth)."""
-    ids = jnp.where(in_node[:, None], jnp.arange(F)[None, :] * B + bins, F * B)
-    vals = jnp.stack([g, h, jnp.where(in_node, 1.0, 0.0)], axis=1)
-    n = bins.shape[0]
-    flat = jnp.zeros((F * B + 1, 3), jnp.float32)
-    flat = flat.at[ids.reshape(-1)].add(
-        jnp.repeat(vals, F, axis=0).reshape(n, F, 3).reshape(-1, 3)
-    )
-    return flat[: F * B].reshape(F, B, 3)
-
-
-# ---------------------------------------------------------------------------
 # The trainer
 # ---------------------------------------------------------------------------
+
+
+# Which partitioned histogram passes the device engine builds: each
+# implementation family's budget divisors and the largest budget the fused
+# kernel takes (GrowSpec.rungs makes them the passes at a row count).
+# Chosen in code from the platform, not by the environment. On since r6
+# everywhere. The TPU ladder routes only genuinely late waves (<= n/64
+# rows) into partitioned passes, all through the fused kernel — the
+# XLA-gather rungs at n/8, n/32 measured as net losers on TPU in r5; the
+# dense family keeps the r5 ladder (gathers are cheap on CPU). All three
+# values date from r5/r6, a retired set-up (an experimental TPU plug-in,
+# on older code), not re-measured: in gbdt_higgs.train on the v5e neither
+# TPU rung ever runs (ledger `breakdown`). ROADMAP S2 re-measures them.
+LADDER = {"pallas": (64, 256), "dense": (8, 32)}
+FUSED_MAX_ROWS = 1 << 18
 
 
 @contextlib.contextmanager
@@ -199,6 +164,10 @@ class GBDTResult:
 
 
 class GBDTTrainer:
+    # "contract:" marks what perfbench/families/gbdt.py depends on: name,
+    # signature and place on the instance stay
+    # (tests/test_gbdt_engine.py::test_benchmark_contract). Here: params,
+    # mesh, fs and, for the int8 control, hist_precision
     def __init__(
         self,
         params: GBDTParams,
@@ -206,8 +175,7 @@ class GBDTTrainer:
         fs: Optional[FileSystem] = None,
         engine: str = "auto",
         wave: Optional[int] = None,
-        use_bf16_hist: bool = True,
-        hist_precision: Optional[str] = None,  # bf16 | f32 | int8
+        hist_precision: str = "bf16",  # bf16 | f32 | int8
         goss: Optional[Tuple[float, float]] = None,  # (a, b); a >= 1 = off
         efb: Optional[bool] = None,  # None = YTK_EFB knob
     ):
@@ -234,14 +202,11 @@ class GBDTTrainer:
             )
         self.engine = engine
         self.wave = wave
-        if hist_precision is None:
-            hist_precision = "bf16" if use_bf16_hist else "f32"
         if hist_precision not in ("bf16", "f32", "int8"):
             raise ValueError(
                 f"hist_precision must be bf16|f32|int8, got {hist_precision!r}"
             )
         self.hist_precision = hist_precision
-        self.use_bf16_hist = hist_precision != "f32"
         # GOSS (device engine): explicit ctor pair wins, else the knobs.
         # a >= 1 disables — the engine then takes the bit-identical
         # unsampled path.
@@ -353,6 +318,7 @@ class GBDTTrainer:
 
     # -- entry ------------------------------------------------------------
 
+    # contract: the benchmark calls train(train=, test=) with rows it made
     def train(
         self,
         train: Optional[GBDTData] = None,
@@ -373,7 +339,7 @@ class GBDTTrainer:
                     "(host-loop makers read per-row device state eagerly); "
                     f"got engine={self.engine!r}"
                 )
-            return self._train_host(train, test)
+            return train_host(self, train, test)
 
     # ======================================================================
     # DEVICE ENGINE
@@ -400,33 +366,11 @@ class GBDTTrainer:
             # for unused frontier slots
             NW = 64
         NW = max(1, min(NW, (M + 1) // 2))
-        # dense einsum only where Mosaic can't compile (CPU tests / virtual
-        # mesh); mesh>1 runs the SAME Pallas kernels per shard under
-        # shard_map (r3 VERDICT #1: no more force_dense on multi-chip)
-        force_dense = jax.default_backend() != "tpu"
-        # leaf-partitioned histogram phases: DEFAULT-ON everywhere since r6
-        # (the fused compact+gather+histogram kernel makes late-tree waves
-        # O(wave rows) on TPU too — r5 shipped this opt-in because the XLA
-        # row gather lost money there). YTK_PARTITION=0 or YTK_NO_PARTITION=1
-        # turns it off, so an A/B "off" run can never silently run
-        # partitioned; YTK_PARTITION=1 stays accepted (now a no-op).
-        partition = (
-            not knobs.get_bool("YTK_NO_PARTITION")
-            and knobs.get_bool("YTK_PARTITION")
-        )
-        # budget ladder divisors: the TPU default routes only genuinely
-        # late waves (<= n/64 rows) into partitioned passes, all through
-        # the fused kernel — the XLA-gather rungs at n/8, n/32 measured as
-        # net losers on TPU in r5 and stay off the default there. The CPU
-        # dense path keeps the r5 ladder (gathers are cheap on CPU).
-        # YTK_LADDER / YTK_FUSED / YTK_FUSED_MAX_ROWS override for tuning.
-        ladder_env = knobs.get_str("YTK_LADDER")
-        if ladder_env:
-            ladder = tuple(int(x) for x in ladder_env.split(",") if x.strip())
-        else:
-            ladder = (8, 32) if force_dense else (64, 256)
-        fused = knobs.get_bool("YTK_FUSED")
-        fused_max_rows = knobs.get_int("YTK_FUSED_MAX_ROWS")
+        # the implementation family, resolved here and nowhere below: the
+        # Pallas kernels on the chip, their dense einsum / XLA twins only
+        # where Mosaic can't compile (CPU tests / virtual mesh); mesh>1
+        # runs the SAME Pallas kernels per shard under shard_map
+        kernels = "pallas" if jax.default_backend() == "tpu" else "dense"
         return GrowSpec(
             F=F,
             B=B,
@@ -442,13 +386,10 @@ class GBDTTrainer:
             max_abs=p.max_abs_leaf_val,
             min_split_loss=p.min_split_loss,
             min_split_samples=float(p.min_split_samples),
-            use_bf16=self.use_bf16_hist,
-            force_dense=force_dense,
-            hist_mode="int8" if self.hist_precision == "int8" else "mxu",
-            partition=partition,
-            ladder=ladder,
-            fused=fused,
-            fused_max_rows=fused_max_rows,
+            precision=self.hist_precision,
+            kernels=kernels,
+            ladder=LADDER[kernels],
+            fused_max_rows=FUSED_MAX_ROWS,
             goss_a=self.goss[0],
             goss_b=self.goss[1],
             goss_scale=goss_scale,
@@ -805,6 +746,10 @@ class GBDTTrainer:
         )
         return self._make_round_step(dd, grow, has_test, spec)
 
+    # contract: the benchmark wraps this on the instance (and its planted
+    # faults on the class) to time and count the compiled round program's
+    # calls: (jit_round, carry, data, start_round) -> the callable that
+    # _run_rounds calls as f(carry, rnd, key, data)
     def _probe_compile(self, jit_round, carry, data, start_round: int):
         """AOT-compile the round program once; the compiled object is
         reused for every round, so this is not a second compile. A
@@ -856,11 +801,11 @@ class GBDTTrainer:
         ts["route_bytes"] = float(
             (route_waves_t * routed_rows * trees_used).sum()
         ) * (F * bins_bytes + 8)
-        ts["partition"] = bool(spec.partition)
-        ts["fused"] = bool(
-            spec.partition and spec.fused
-            and (not spec.force_dense or spec.fused_interpret)
-        )
+        # read from the table the engine builds the growth program from
+        n_dev = dd.n_score // max(dd.D, 1)
+        rungs = spec.rungs(spec.goss_sizes(n_dev)[2] if goss_on else n_dev)
+        ts["partition"] = bool(rungs)
+        ts["fused"] = any(impl == "fused" for _, impl in rungs)
         ts["goss"] = goss_on
         if goss_on:
             ts["goss_a"] = float(spec.goss_a)
@@ -902,6 +847,8 @@ class GBDTTrainer:
                 rows_needed=needed, splits=splits, rows_sampled=sampled,
             )
 
+    # contract: the benchmark wraps this on the instance and reads the
+    # carry it returns: [2]["wlog"] the wave log, [3] the per-round losses
     def _run_rounds(
         self, jit_round, carry, data, dd, model, feature_names,
         start_round: int, has_test: bool, t0: float, ts: dict,
@@ -1038,6 +985,7 @@ class GBDTTrainer:
         health.record_memory("gbdt.preprocess")
         bins = dd.bins
         y, weight, y_t, w_t = dd.y, dd.weight, dd.y_t, dd.w_t
+        # contract: the benchmark reads time_stats["preprocess"]
         ts["preprocess"] = time.time() - t0 - ts["load"]
         log.info("load+preprocess %.1fs", time.time() - t0)
 
@@ -1152,6 +1100,8 @@ class GBDTTrainer:
         else:
             self._retrace.check(sig=sig, round=rnd)
 
+    # contract: the benchmark wraps this on the instance to close its
+    # window where a SIGTERM stops the run: (model, bufs, bins, names, rnd)
     def _preempt_checkpoint(self, model, bufs, bins, names, rnd: int) -> None:
         """Emergency checkpoint at round boundary `rnd`, then Preempted.
         The wave-log counters and the `gbdt.stat.*` gauges are published
@@ -1363,388 +1313,6 @@ class GBDTTrainer:
         )
         return res
 
-    # ======================================================================
-    # HOST ENGINE (original implementation; reference for tests + LAD)
-    # ======================================================================
-
-    def _decide_split(self, chg, cl, cr, hl, hr) -> bool:
-        p = self.params
-        return (
-            np.isfinite(chg)
-            and chg > p.min_split_loss
-            and cl + cr >= p.min_split_samples
-            and (hl + hr) >= p.min_child_hessian_sum * 2.0
-        )
-
-    def _finish_split(self, tree, bins_meta, nid, fid, slot_l, slot_r, stats):
-        """Record a split on the host tree (slot-space; converted at dump)."""
-        gl, hl, cl, gr, hr, cr = stats
-        tree.feat[nid] = fid
-        tree.feat_name[nid] = bins_meta[fid] if bins_meta else str(fid)
-        tree.slot[nid] = slot_l
-        tree.split[nid] = float(slot_l)  # slot until convert
-        left, right = tree.add_children(nid)
-        # f32 multiply, bit-identical to the device engine's leaf values
-        lr = np.float32(self.params.learning_rate)
-        tree.leaf_value[left] = float(np.float32(self.node_value_fn(gl, hl)) * lr)
-        tree.leaf_value[right] = float(np.float32(self.node_value_fn(gr, hr)) * lr)
-        tree.hess_sum[left], tree.sample_cnt[left] = float(hl), int(cl)
-        tree.hess_sum[right], tree.sample_cnt[right] = float(hr), int(cr)
-        return left, right
-
-    def build_tree_level_wise(
-        self, bins_dev, g, h, pos0, F: int, B: int, feat_mask, names
-    ) -> Tree:
-        """Level-synchronous growth: one histogram scan + one split search +
-        one position update per level (reference level policy,
-        DataParallelTreeMaker.make with TreeGrowPolicy.LEVEL)."""
-        p = self.params
-        tree = Tree()
-        pos = pos0  # level-local node index per sample (-1 inactive)
-        level_nids = [0]  # tree nid per level-local index
-        # root stats
-        root_hist = hist_kernel(bins_dev, pos, g, h, 1, F, B)
-        ghc = np.asarray(jnp.sum(root_hist, axis=(1, 2)))[0] / F  # sums counted F times
-        tree.hess_sum[0], tree.sample_cnt[0] = float(ghc[1]), int(round(ghc[2]))
-        tree.leaf_value[0] = float(
-            np.float32(self.node_value_fn(ghc[0], ghc[1]))
-            * np.float32(p.learning_rate)
-        )
-        cfg = self._cfg()
-        max_leaves = p.max_leaf_cnt if p.max_leaf_cnt > 0 else 1 << 30
-        max_depth = p.max_depth if p.max_depth > 0 else 1 << 30
-
-        for depth in range(max_depth):
-            n_nodes = len(level_nids)
-            if n_nodes == 0:
-                break
-            n_pad = 1 << (n_nodes - 1).bit_length()  # pad node count: few shapes
-            hist = hist_kernel(bins_dev, pos, g, h, n_pad, F, B)
-            out = split_kernel(hist, feat_mask, cfg)
-            (chg, flat_idx, slot_l, GL, HL, CL, GR, HR, CR) = (
-                np.asarray(o) for o in out
-            )
-
-            node_feat = np.full((n_pad,), -1, np.int32)
-            node_slot = np.full((n_pad,), 0, np.int32)
-            child_base = np.full((n_pad,), -1, np.int32)
-            next_nids: List[int] = []
-            leaves_after = tree.leaf_cnt()
-            for k in range(n_nodes):
-                nid = level_nids[k]
-                can = (
-                    depth < max_depth
-                    and leaves_after + 1 < max_leaves + 1
-                    and self._decide_split(chg[k], CL[k], CR[k], HL[k], HR[k])
-                )
-                if not can:
-                    continue
-                fid = int(flat_idx[k]) // B
-                slot_right = int(flat_idx[k]) % B
-                left, right = self._finish_split(
-                    tree,
-                    names,
-                    nid,
-                    fid,
-                    int(slot_l[k]),
-                    slot_right,
-                    (GL[k], HL[k], CL[k], GR[k], HR[k], CR[k]),
-                )
-                tree.gain[nid] = float(chg[k])
-                # store the interval's right end for split-value conversion
-                tree.slot[nid] = int(slot_l[k])
-                tree.split[nid] = float(slot_right)
-                node_feat[k] = fid
-                node_slot[k] = int(slot_l[k])
-                child_base[k] = len(next_nids)
-                next_nids.extend([left, right])
-                leaves_after = tree.leaf_cnt()
-            if not next_nids:
-                break
-            pos = pos_update_kernel(
-                bins_dev,
-                pos,
-                jnp.asarray(node_feat),
-                jnp.asarray(node_slot),
-                jnp.asarray(child_base),
-            )
-            level_nids = next_nids
-
-        return tree
-
-    def build_tree_loss_wise(
-        self, bins_dev, g, h, pos_active, F: int, B: int, feat_mask, names
-    ) -> Tree:
-        """Best-first growth with per-node histograms + sibling subtraction
-        (reference TreeGrowPolicy.LOSS + HistogramPool)."""
-        p = self.params
-        tree = Tree()
-        cfg = self._cfg()
-        # tree_pos: tree nid per sample (-1 = excluded by instance sampling)
-        tree_pos = jnp.where(pos_active >= 0, 0, -1)
-
-        root_hist = node_hist_kernel(bins_dev, tree_pos >= 0, g, h, F, B)
-        hists: Dict[int, jnp.ndarray] = {0: root_hist}
-        s = np.asarray(jnp.sum(root_hist[..., :], axis=(0, 1)))  # counted once per f
-        Gt, Ht, Ct = s[0] / F, s[1] / F, s[2] / F
-        tree.hess_sum[0], tree.sample_cnt[0] = float(Ht), int(round(Ct))
-        tree.leaf_value[0] = float(
-            np.float32(self.node_value_fn(Gt, Ht)) * np.float32(p.learning_rate)
-        )
-
-        def best_of(nid):
-            out = split_kernel(hists[nid][None], feat_mask, cfg)
-            return tuple(np.asarray(o)[0] for o in out)
-
-        frontier = {0: best_of(0)}
-        max_leaves = p.max_leaf_cnt if p.max_leaf_cnt > 0 else 1 << 30
-        depth_of = {0: 0}
-        max_depth = p.max_depth if p.max_depth > 0 else 1 << 30
-
-        while tree.leaf_cnt() < max_leaves:
-            # pick the best expandable frontier node
-            cand = [
-                (v[0], nid)
-                for nid, v in frontier.items()
-                if depth_of[nid] < max_depth
-                and self._decide_split(v[0], v[5], v[8], v[4], v[7])
-            ]
-            if not cand:
-                break
-            chg, nid = max(cand, key=lambda t: (t[0], -t[1]))
-            (c, flat_idx, slot_l, GL, HL, CL, GR, HR, CR) = frontier.pop(nid)
-            fid = int(flat_idx) // B
-            slot_right = int(flat_idx) % B
-            left, right = self._finish_split(
-                tree, names, nid, fid, int(slot_l), slot_right, (GL, HL, CL, GR, HR, CR)
-            )
-            tree.gain[nid] = float(c)
-            tree.slot[nid] = int(slot_l)
-            tree.split[nid] = float(slot_right)
-            depth_of[left] = depth_of[right] = depth_of[nid] + 1
-
-            # route samples of nid to children
-            b = jnp.take_along_axis(bins_dev, jnp.full((bins_dev.shape[0], 1), fid), 1)[:, 0]
-            in_nid = tree_pos == nid
-            tree_pos = jnp.where(
-                in_nid, jnp.where(b > int(slot_l), right, left), tree_pos
-            )
-
-            # smaller child by scan; sibling by subtraction (HistogramPool)
-            small, big = (left, right) if CL <= CR else (right, left)
-            small_hist = node_hist_kernel(bins_dev, tree_pos == small, g, h, F, B)
-            parent_hist = hists.pop(nid)
-            hists[small] = small_hist
-            hists[big] = parent_hist - small_hist
-            frontier[small] = best_of(small)
-            frontier[big] = best_of(big)
-
-        return tree
-
-    def _tree_scores_dev(self, tree: Tree, bins_dev) -> jnp.ndarray:
-        """Slot-space tree traversal on device (bin <= slot goes left)."""
-        feat = jnp.asarray(np.asarray(tree.feat, np.int32))
-        slot = jnp.asarray(np.asarray(tree.slot, np.int32))
-        left = jnp.asarray(np.asarray(tree.left, np.int32))
-        right = jnp.asarray(np.asarray(tree.right, np.int32))
-        leaf = jnp.asarray(np.asarray(tree.leaf_value, np.float32))
-        depth = max(tree.max_depth(), 1)
-        return _traverse_kernel(bins_dev, feat, slot, left, right, leaf, depth)
-
-    # -- host boosting -----------------------------------------------------
-
-    def _train_host(
-        self,
-        train: Optional[GBDTData] = None,
-        test: Optional[GBDTData] = None,
-    ) -> GBDTResult:
-        p = self.params
-        t0 = time.time()
-        if train is None:
-            train, test = GBDTIngest(p, self.fs).load()
-        if self.mesh is not None:
-            train = train.pad_rows(self.mesh.devices.size)
-            test = test.pad_rows(self.mesh.devices.size) if test else None
-        n, F = train.X.shape
-        K = self.K
-
-        self._missing_fill = train.missing_fill
-        log.info("building bins (%d features)...", F)
-        bins = build_bins_global(train.X, train.weight, p, train.feature_names)
-        self._bins_sidecar = (list(train.feature_names or []), bins)
-        self._quality_features = self._build_quality_features(train)
-        B = bins.max_bins
-        bins_np = bin_matrix(train.X, bins)
-        bins_train = self._put(bins_np)
-
-        feature_parallel = p.tree_maker == "feature" and self.mesh is not None
-        if feature_parallel:
-            # columns sharded over the mesh (FeatureParallelTreeMakerByLevel);
-            # the maker is level-wise only, as in the reference
-            from .feature_parallel import shard_features
-
-            bins_t_fp, F_pad_fp = shard_features(self.mesh, bins_np)
-            if p.tree_grow_policy != "level":
-                log.info(
-                    "tree_maker=feature grows level-wise (reference maker is "
-                    "ByLevel); ignoring tree_grow_policy=%r", p.tree_grow_policy
-                )
-        del bins_np
-        y = self._put(train.y)
-        weight = self._put(train.weight)
-        log.info(
-            "load+preprocess %.1fs: %d rows, %d features, %d max bins",
-            time.time() - t0,
-            train.n_real,
-            F,
-            B,
-        )
-
-        base_np = self._base_score(train, K)
-        model = GBDTModel(
-            base_prediction=float(np.mean(base_np)),
-            num_tree_in_group=K,
-            obj_name=self.loss.name,
-        )
-
-        # continue_train: reload + replay scores
-        model, start_round = self._load_resume_model(
-            model, K, feature_names=train.feature_names
-        )
-
-        if K > 1:
-            scores = jnp.full((n, K), base_np, jnp.float32)
-        else:
-            scores = jnp.full((n,), float(base_np), jnp.float32)
-        for i, t in enumerate(model.trees):
-            add = self._tree_scores_from_raw(t, bins, bins_train)
-            if K > 1:
-                scores = scores.at[:, i % K].add(add)
-            else:
-                scores = scores + add
-
-        eval_set = EvalSet(p.eval_metric, K=max(K, 2)) if p.eval_metric else None
-        rng = np.random.RandomState(20170425)
-        feat_names = train.feature_names
-        round_log: List[Dict] = []
-
-        test_state = None
-        if test is not None:
-            bins_test = self._put(bin_matrix(test.X, bins))
-            y_t = self._put(test.y)
-            w_t = self._put(test.weight)
-            if K > 1:
-                scores_t = jnp.full((test.n, K), base_np, jnp.float32)
-            else:
-                scores_t = jnp.full((test.n,), float(base_np), jnp.float32)
-            for i, t in enumerate(model.trees):
-                add = self._tree_scores_from_raw(t, bins, bins_test)
-                if K > 1:
-                    scores_t = scores_t.at[:, i % K].add(add)
-                else:
-                    scores_t = scores_t + add
-            test_state = (bins_test, y_t, w_t, scores_t)
-
-        if p.just_evaluate:
-            return self._finalize(
-                model, scores, y, weight, test_state, eval_set, round_log, bins
-            )
-
-        for rnd in range(start_round, p.round_num):
-            if self._guard is not None and self._guard.triggered:
-                # host engine appends converted trees as it goes: the dump
-                # is the checkpoint, resume re-enters at this round
-                self._dump_model(model)
-                self._guard.preempt(
-                    p.model.data_path, family="gbdt_host", rounds=rnd,
-                    trees=len(model.trees),
-                )
-            # fast-path grads from predictions (reference:
-            # ILossFunction.getDerivativeFast, GBDTOptimizer:513)
-            preds = self.loss.predict(scores)
-            gs, hs = self.loss.grad_hess(preds, y)
-            # instance sampling + weight fold-in
-            inst = (rng.rand(n) <= p.instance_sample_rate).astype(np.float32)
-            inst[train.n_real :] = 0.0
-            pos0 = jnp.asarray(np.where(inst > 0, 0, -1).astype(np.int32))
-            fmask = (rng.rand(F) <= p.feature_sample_rate).astype(bool)
-            if not fmask.any():
-                fmask[rng.randint(F)] = True
-            fmask_dev = jnp.asarray(fmask)
-
-            obs_inc("gbdt.rounds")
-            for grp in range(K):
-                g = (gs[:, grp] if K > 1 else gs) * weight
-                h = (hs[:, grp] if K > 1 else hs) * weight
-                if feature_parallel:
-                    from .feature_parallel import build_tree_level_feature_parallel
-
-                    tree = build_tree_level_feature_parallel(
-                        self, self.mesh, bins_t_fp, F_pad_fp, g, h, pos0,
-                        F, B, fmask_dev, feat_names,
-                    )
-                elif p.tree_grow_policy == "loss":
-                    tree = self.build_tree_loss_wise(
-                        bins_train, g, h, pos0, F, B, fmask_dev, feat_names
-                    )
-                else:
-                    tree = self.build_tree_level_wise(
-                        bins_train, g, h, pos0, F, B, fmask_dev, feat_names
-                    )
-                if self.loss.name == "l1" and K == 1:
-                    self._refine_lad(tree, bins_train, y, scores, weight)
-                add = self._tree_scores_dev(tree, bins_train)
-                if K > 1:
-                    scores = scores.at[:, grp].add(add)
-                else:
-                    scores = scores + add
-                if test_state is not None:
-                    add_t = self._tree_scores_dev(tree, test_state[0])
-                    bins_test, y_t, w_t, scores_t = test_state
-                    if K > 1:
-                        scores_t = scores_t.at[:, grp].add(add_t)
-                    else:
-                        scores_t = scores_t + add_t
-                    test_state = (bins_test, y_t, w_t, scores_t)
-                self._convert_tree(tree, bins)
-                model.trees.append(tree)
-
-            rec = {"round": rnd, "elapsed": time.time() - t0}
-            rec["train_loss"] = float(_wavg_loss(self.loss, scores, y, weight))
-            if test_state is not None:
-                rec["test_loss"] = float(
-                    _wavg_loss(self.loss, test_state[3], test_state[1], test_state[2])
-                )
-            if eval_set is not None and (p.watch_train or p.watch_test or rnd == p.round_num - 1):
-                if p.watch_train:
-                    rec["train_metrics"] = eval_set.evaluate(
-                        self.loss.predict(scores), y, weight
-                    )
-                if p.watch_test and test_state is not None:
-                    rec["test_metrics"] = eval_set.evaluate(
-                        self.loss.predict(test_state[3]), test_state[1], test_state[2]
-                    )
-            round_log.append(rec)
-            log.info(
-                "[round=%d] %.1fs train loss=%.6f%s",
-                rnd,
-                rec["elapsed"],
-                rec["train_loss"],
-                f" test loss={rec['test_loss']:.6f}" if "test_loss" in rec else "",
-            )
-
-            if p.model.dump_freq > 0 and (rnd + 1) % p.model.dump_freq == 0:
-                self._dump_model(model)
-
-        if test_state is not None:
-            self._stash_quality_scores(test_state[3], test_state[2])
-        else:
-            self._stash_quality_scores(scores, weight)
-        self._dump_model(model)
-        return self._finalize(
-            model, scores, y, weight, test_state, eval_set, round_log, bins
-        )
-
     # -- helpers ----------------------------------------------------------
 
     def _convert_tree(self, tree: Tree, bins: FeatureBins) -> None:
@@ -1795,34 +1363,6 @@ class GBDTTrainer:
             jnp.asarray(np.asarray(tree.leaf_value, np.float32)),
             depth,
         )
-
-    def _refine_lad(self, tree: Tree, bins_dev, y, scores, weight) -> None:
-        """LAD leaf refinement: leaf value = lr * weighted median of
-        (y - current score) over the leaf's samples (reference:
-        optimizer/gbdt/TreeRefiner.java:72-123, precise mode)."""
-        pos = np.asarray(self._tree_leaf_assignment(tree, bins_dev))
-        resid = np.asarray(y) - np.asarray(scores)
-        w = np.asarray(weight)
-        lr = self.params.learning_rate
-        for nid in range(tree.n_nodes()):
-            if not tree.is_leaf(nid):
-                continue
-            m = (pos == nid) & (w > 0)
-            if not m.any():
-                continue
-            r, ww = resid[m], w[m]
-            order = np.argsort(r, kind="stable")
-            cw = np.cumsum(ww[order])
-            cut = 0.5 * cw[-1]
-            tree.leaf_value[nid] = float(r[order][np.searchsorted(cw, cut)]) * lr
-
-    def _tree_leaf_assignment(self, tree: Tree, bins_dev):
-        feat = jnp.asarray(np.asarray(tree.feat, np.int32))
-        slot = jnp.asarray(np.asarray(tree.slot, np.int32))
-        left = jnp.asarray(np.asarray(tree.left, np.int32))
-        right = jnp.asarray(np.asarray(tree.right, np.int32))
-        depth = max(tree.max_depth(), 1)
-        return _assign_kernel(bins_dev, feat, slot, left, right, depth)
 
     def _build_quality_features(self, train) -> Optional[dict]:
         """Feature block of the `<model>.sketch.json` quality sidecar
@@ -1969,45 +1509,8 @@ def _lad_refine_device(tr, pos, y, scores, weight, real_mask, lr):
     )
 
 
-def _wavg_loss(loss, scores, y, weight):
-    per = jnp.where(weight > 0, loss.loss(scores, y), 0.0)
-    return jnp.sum(weight * per) / jnp.maximum(jnp.sum(weight), 1e-12)
-
-
 def _pad0(arr: np.ndarray, n_pad: int) -> np.ndarray:
     n = arr.shape[0]
     if n == n_pad:
         return arr
     return np.pad(arr, ((0, n_pad - n),) + ((0, 0),) * (arr.ndim - 1))
-
-
-@partial(jax.jit, static_argnames=("depth",))
-def _traverse_kernel(bins, feat, slot, left, right, leaf, depth: int):
-    """Fixed-depth slot-space traversal: leaves self-loop via feat<0."""
-    n = bins.shape[0]
-    node = jnp.zeros((n,), jnp.int32)
-
-    def step(_, node):
-        f = feat[node]
-        is_leaf = f < 0
-        b = jnp.take_along_axis(bins, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
-        nxt = jnp.where(b <= slot[node], left[node], right[node])
-        return jnp.where(is_leaf, node, nxt)
-
-    node = jax.lax.fori_loop(0, depth, step, node)
-    return leaf[node]
-
-
-@partial(jax.jit, static_argnames=("depth",))
-def _assign_kernel(bins, feat, slot, left, right, depth: int):
-    n = bins.shape[0]
-    node = jnp.zeros((n,), jnp.int32)
-
-    def step(_, node):
-        f = feat[node]
-        is_leaf = f < 0
-        b = jnp.take_along_axis(bins, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
-        nxt = jnp.where(b <= slot[node], left[node], right[node])
-        return jnp.where(is_leaf, node, nxt)
-
-    return jax.lax.fori_loop(0, depth, step, node)

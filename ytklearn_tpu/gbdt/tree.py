@@ -20,8 +20,11 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 # Reference Tree.java:47-48 uses greedy \S+ everywhere but its stats fields
@@ -383,3 +386,28 @@ class GBDTModel:
         for i, t in enumerate(self.trees):
             s[:, i % K] += t.predict(X)
         return s
+
+
+# -- what both training engines (trainer.py, host_engine.py) use --
+
+
+@partial(jax.jit, static_argnames=("depth",))
+def _traverse_kernel(bins, feat, slot, left, right, leaf, depth: int):
+    """Fixed-depth slot-space traversal: leaves self-loop via feat<0."""
+    n = bins.shape[0]
+    node = jnp.zeros((n,), jnp.int32)
+
+    def step(_, node):
+        f = feat[node]
+        is_leaf = f < 0
+        b = jnp.take_along_axis(bins, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
+        nxt = jnp.where(b <= slot[node], left[node], right[node])
+        return jnp.where(is_leaf, node, nxt)
+
+    node = jax.lax.fori_loop(0, depth, step, node)
+    return leaf[node]
+
+
+def _wavg_loss(loss, scores, y, weight):
+    per = jnp.where(weight > 0, loss.loss(scores, y), 0.0)
+    return jnp.sum(weight * per) / jnp.maximum(jnp.sum(weight), 1e-12)
