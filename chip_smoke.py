@@ -9,7 +9,9 @@ exception is caught):
   A  `ytklearn_tpu.cli.train_main(["gbdt", experiment/higgs/local_gbdt.conf,
      ...])` in-process on a seeded 2^20 + 2^16 row text file: native ingest,
      falling loss, 8 trees, the fused+partitioned program un-downgraded, no
-     recompiles after the first sync, dumped model == device scores.
+     recompiles after the first sync, dumped model == device scores; the
+     round program looked its leaf values up through the one-pass kernel,
+     and that kernel equals `leaf[pos]` bit for bit at 2^20 rows, 509 nodes.
   B  the same trainer construction on 10.5M + 500k device-generated rows,
      5 rounds: binning, histogram pool and round program at the real n.
   C  int8 histograms are exact, so the full-scan, XLA-gather and fused
@@ -123,13 +125,17 @@ def check_training(res, snap, rounds: int, what: str) -> None:
     check(g.get("gbdt.stat.fused") == 1.0 and g.get("gbdt.stat.partition") == 1.0,
           f"{what}: not the fused+partitioned program: "
           f"fused={g.get('gbdt.stat.fused')} partition={g.get('gbdt.stat.partition')}")
+    check(g.get("gbdt.stat.leaf_lookup_kernel") == 1.0,
+          f"{what}: the round program's leaf lookup is not the kernel: "
+          f"leaf_lookup_kernel={g.get('gbdt.stat.leaf_lookup_kernel')}")
     down = {k: v for k, v in c.items()
             if (k.startswith("gbdt.downgrade") or k == "gbdt.efb.downgrade") and v}
     check(not down, f"{what}: downgrade counters {down}")
     check(not c.get("compile.retraces.unexpected"),
           f"{what}: {c.get('compile.retraces.unexpected')} compiles after the first sync")
     print(f"{what}: {rounds} trees, leaves {min(leaves)}..{max(leaves)}, "
-          f"fused=1 partition=1, downgrades 0, unexpected compiles 0")
+          f"fused=1 partition=1 leaf_lookup_kernel=1, downgrades 0, "
+          f"unexpected compiles 0")
 
 
 def wave_summary(trainer, what: str) -> None:
@@ -141,6 +147,36 @@ def wave_summary(trainer, what: str) -> None:
     part = int((used[:, 0] < used[:, 0].max()).sum())
     print(f"{what}: {len(used)} histogram passes over {wl.shape[0]} trees, "
           f"{part} of them partitioned (row budgets {budgets})")
+
+
+def check_leaf_lookup(what: str) -> None:
+    """route.leaf_values' kernel against XLA's gather and numpy at the
+    acceptance tree's size: every node id hit, leaf values of every sign and
+    magnitude; equality is of bits."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ytklearn_tpu.gbdt.hist import BM_DEFAULT
+    from ytklearn_tpu.gbdt.route import leaf_values
+
+    M = 2 * LEAVES - 1
+    rng = np.random.RandomState(11)
+    leaf = (rng.randn(M) * np.exp(6.0 * rng.randn(M))).astype(np.float32)
+    leaf[:6] = np.array([-0.0, 0.0, 1e-42, -1.1754944e-38, 3e38, -3e38], np.float32)
+    pos = rng.randint(0, M, size=N_A).astype(np.int32)
+    pos[:M] = np.arange(M)
+    pos = rng.permutation(pos)
+    got, gather = (
+        np.asarray(leaf_values(jnp.asarray(leaf), jnp.asarray(pos),
+                               kernels=k, bm=BM_DEFAULT)).view(np.uint32)
+        for k in ("pallas", "dense"))
+    want = leaf[pos].view(np.uint32)
+    check(np.array_equal(got, want) and np.array_equal(gather, want),
+          f"{what}: leaf_values differs from leaf[pos] in "
+          f"{int((got != want).sum())} (kernel) / "
+          f"{int((gather != want).sum())} (gather) of {N_A} rows")
+    print(f"{what}: gbdt_leaf_values == leaf[pos] bit for bit, {N_A} rows, "
+          f"{M} nodes")
 
 
 def check_model_scores(model_path: str, X_test, scores_t, what: str) -> None:
@@ -193,6 +229,7 @@ def stage_a(dev_line: str, paths):
     check_training(res, snap, ROUNDS_A, "stage A")
     wave_summary(trainer, "stage A")
     check_model_scores(model_path, Xt, scores_t, "stage A")
+    check_leaf_lookup("stage A")
     ts = trainer.time_stats
     print(f"stage A [{dev_line}]: wall {wall:.1f}s = load {ts['load']:.1f} + "
           f"preprocess {ts['preprocess']:.1f} + train {ts['train']:.1f} + "

@@ -329,6 +329,16 @@ class GrowSpec:
                 out.setdefault(R, "fused" if fuse else "xla")
         return tuple(sorted(out.items()))
 
+    def leaf_lookup(self, kernel_max_nodes: int) -> str:
+        """The family route.leaf_values takes for this tree size at the end
+        of a tree: "pallas" (the one-pass kernel, whose cost grows with
+        max_nodes / 128) up to `kernel_max_nodes` in the Pallas family,
+        "dense" (XLA's gather, flat in the table's size) above it and
+        wherever the family is dense."""
+        if self.kernels == "pallas" and self.max_nodes <= kernel_max_nodes:
+            return "pallas"
+        return "dense"
+
 
 class TreeArrays(NamedTuple):
     """Fixed-shape device tree (mirrors the host Tree fields that training
@@ -899,7 +909,11 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         # slow start: after k waves at most 2^k nodes are expandable, so the
         # first waves run right-sized (N = 1, 2, 4, ...) — identical split
         # decisions to full-width waves at a fraction of the one-hot matmul
-        # rows (each wave's hist cost is proportional to its slot count)
+        # rows. What that buys is measured, not proportional: on the v5e a
+        # full scan costs the same from 3 to 48 slots (0.713-0.721 s each
+        # over 14 trees, 51 ms a pass: the VPU's one-hot build, rows x F x B
+        # compares whatever the width) and grows only above that (96 slots
+        # 87.5 ms, 192 slots 508 ms a tree): PERF.md section 5
         nw_ss = 1
         while nw_ss < NW:
             state = wave_body(state, nw_ss)

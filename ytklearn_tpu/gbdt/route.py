@@ -9,6 +9,13 @@ in VMEM, and writes pos once (~0.3 GB per wave with uint8 bins).
 
 Reference: SamplePositionData.resetPosition:115 (partition samples of a
 split node between its children).
+
+`leaf_values` is the read at the end of that walk: each row's final node
+id looked up in the tree's leaf table. XLA lowers `leaf[pos]` to its
+general gather at 8.2 ns an index whatever the table's size (86 ms a tree
+at 10.5M rows for one of 509 floats: ledger PR 33); the Pallas kernel
+resolves a block's rows in registers in one pass over `pos` (0.26 ms a
+tree in the round program: my chip runs, PR 34, PERF.md section 5).
 """
 
 from __future__ import annotations
@@ -103,6 +110,79 @@ def _route_dense(
         return jnp.where(sel_valid[i], upd, pos)
 
     return jax.lax.fori_loop(0, sel_nid.shape[0], body, pos)
+
+
+@partial(jax.jit, static_argnames=("bm", "interpret"))
+def _leaf_values_pallas(leaf, pos, bm: int, interpret: bool = False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, M = pos.shape[0], leaf.shape[0]
+    rows = bm // 128
+    assert bm % 1024 == 0 and n % bm == 0, (n, bm)
+    # the table as (nseg, 128): node id = 128 * row + lane. Both reshapes
+    # of the row axis are bitcasts on the chip (a block is the bm rows
+    # gbdt_route's is, as `rows` full registers instead of one sublane)
+    nseg = -(-M // 128)
+    tab = jnp.pad(leaf, (0, nseg * 128 - M)).reshape(nseg, 128)
+    pos2 = pos.reshape(n // 128, 128)
+
+    def kernel(tab_ref, pos_ref, out_ref):
+        p = pos_ref[...]  # (rows, 128)
+        lane, seg = p & 127, p >> 7
+        acc = jnp.zeros(p.shape, jnp.float32)
+        for j in range(nseg):
+            # in-register lane gather from table row j, kept where the
+            # node id lies in that row: moves bits, rounds nothing
+            tj = jnp.broadcast_to(tab_ref[j : j + 1, :], p.shape)
+            gj = jnp.take_along_axis(
+                tj, lane, axis=1, mode="promise_in_bounds"
+            )
+            acc = jnp.where(seg == j, gj, acc)
+        out_ref[...] = acc
+
+    return pl.pallas_call(
+        kernel,
+        name="gbdt_leaf_values",
+        grid=(n // bm,),
+        in_specs=[
+            pl.BlockSpec((nseg, 128), lambda k: (0, 0)),
+            pl.BlockSpec((rows, 128), lambda k: (k, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, 128), lambda k: (k, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // 128, 128), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+        ),
+        interpret=interpret,
+    )(tab, pos2).reshape(n)
+
+
+def leaf_values(
+    leaf, pos, *, kernels: str, bm: int, mesh=None, axis: str = "data",
+    interpret: bool = False,
+):
+    """Each row's leaf value, f32[n], bit for bit `leaf[pos]` for node ids
+    in [0, len(leaf)): the one-pass kernel (kernels="pallas"; cost grows
+    with len(leaf) / 128, so the caller asks GrowSpec.leaf_lookup which
+    family a tree's size takes) or XLA's gather ("dense").
+
+    leaf: (M,) f32, replicated; pos: (n,) i32, n a multiple of bm per
+    shard. mesh (of > 1 devices): the rows are sharded over `axis`, so the
+    kernel runs a shard under shard_map and no device fetches for rows it
+    does not hold. `interpret` runs the kernel through the Pallas
+    interpreter (CPU tests)."""
+    if kernels != "pallas":
+        return leaf[pos]
+    fn = partial(_leaf_values_pallas, bm=bm, interpret=interpret)
+    if mesh is None or mesh.devices.size == 1:
+        return fn(leaf, pos)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
+        check_vma=False,
+    )(leaf, pos)
 
 
 def route_wave(

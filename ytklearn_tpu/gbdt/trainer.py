@@ -35,6 +35,7 @@ import contextlib
 import logging
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -78,6 +79,7 @@ from .engine import (
 )
 from .hist import BM_DEFAULT, pad_inputs
 from .host_engine import train_host
+from .route import leaf_values
 from .tree import (
     GBDTModel,
     Tree,
@@ -107,6 +109,14 @@ log = logging.getLogger("ytklearn_tpu.gbdt")
 # TPU rung ever runs (ledger `breakdown`). ROADMAP S2 re-measures them.
 LADDER = {"pallas": (64, 256), "dense": (8, 32)}
 FUSED_MAX_ROWS = 1 << 18
+# The largest tree (GrowSpec.max_nodes) whose end-of-tree leaf lookup the
+# one-pass kernel takes (GrowSpec.leaf_lookup; route.leaf_values): it costs
+# a lane gather and a select per 128 nodes a row, XLA's gather 8.2 ns an
+# index whatever the table holds. At 10.5M rows on the v5e: 0.35 ms at 509
+# nodes, 0.79 at 2,045, 2.9 at 8,189 against the gather's 90-102 ms (my
+# chip runs, PR 34); the kernel unrolls a step per 128 nodes and nothing
+# larger was compiled or timed, so a larger tree keeps the gather.
+LEAF_KERNEL_MAX_NODES = 1 << 13
 
 
 @contextlib.contextmanager
@@ -661,6 +671,14 @@ class GBDTTrainer:
                 "engine uses the approximate rank-grid refine instead "
                 "(pass engine='host' or leave engine='auto' for precise)"
             )
+        # a row's leaf value at the end of a tree: the one-pass kernel or
+        # XLA's gather, as the tree's size says; a shard's rows under a mesh
+        leaf_of = partial(
+            leaf_values,
+            kernels=spec.leaf_lookup(LEAF_KERNEL_MAX_NODES),
+            bm=spec.bm,
+            mesh=self.mesh if dd.D > 1 else None,
+        )
 
         def round_step(carry, rnd, key, data):
             bins_t, y, weight, real_mask = data[:4]
@@ -701,13 +719,13 @@ class GBDTTrainer:
                         p.learning_rate,
                     )
                 with obs_scopes.scope("gbdt.score_update"):
-                    add = tr.leaf[pos_train]
+                    add = leaf_of(tr.leaf, pos_train)
                     if K > 1:
                         scores = scores.at[:, grp].add(add)
                     else:
                         scores = scores + add
                     if has_test:
-                        add_t = tr.leaf[aux_pos[0]]
+                        add_t = leaf_of(tr.leaf, aux_pos[0])
                         if K > 1:
                             scores_t = scores_t.at[:, grp].add(add_t)
                         else:
@@ -806,6 +824,9 @@ class GBDTTrainer:
         rungs = spec.rungs(spec.goss_sizes(n_dev)[2] if goss_on else n_dev)
         ts["partition"] = bool(rungs)
         ts["fused"] = any(impl == "fused" for _, impl in rungs)
+        ts["leaf_lookup_kernel"] = (
+            spec.leaf_lookup(LEAF_KERNEL_MAX_NODES) == "pallas"
+        )
         ts["goss"] = goss_on
         if goss_on:
             ts["goss_a"] = float(spec.goss_a)
