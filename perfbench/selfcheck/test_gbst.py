@@ -1,0 +1,138 @@
+"""The `gbmlr_higgs.train` cell's self-checks on the CPU, as `test_ffm.py`
+keeps them for the cell before it: the program as configured comes out
+`correct` at a tiny size against the committed limits; each planted fault
+(the skipped fold among them) and the bfloat16 control put in the program's
+place come out not correct; the window's passes are the program's own count;
+the stop through the preemption guard leaves no thread or handler behind;
+the two work counts against values computed by hand at the cell's sizes.
+
+    JAX_PLATFORMS=cpu python3 -m pytest perfbench/selfcheck -q -p no:cacheprovider
+"""
+
+import json
+import os
+import signal
+import sys
+import threading
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import tiny  # noqa: E402
+
+import run as harness  # noqa: E402
+from pb import manifest, work  # noqa: E402
+
+NAME = "gbmlr_higgs.train"
+SIZES = {"train_rows": 8192, "test_rows": 1024}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    import jax
+
+    jax.config.update("jax_enable_compilation_cache", False)
+
+
+@pytest.fixture(autouse=True)
+def files_of_its_own(tmp_path, monkeypatch):
+    """The dumped trees are read back: a test's model directory is its own
+    (tests of this file may run side by side), and so are its flight dumps."""
+    monkeypatch.setenv("YTK_FLIGHT_DIR", str(tmp_path / "flight"))
+    monkeypatch.setattr(harness, "WORK_DIR", str(tmp_path / "work"))
+
+
+def drive(fault=None, after=None, seed=2147483659, seconds=0.2):
+    cell = tiny.tiny_cell(NAME, SIZES)
+    cell.config["compare"]["reference_block_rows"] = 2048
+    family = manifest.load_module("families", cell.config["family"])
+    mend = family.plant(fault) if fault else None
+    try:
+        return harness.drive(cell, seed, seconds, False, tiny.CPU_DEVICE, after=after)
+    finally:
+        if mend is not None:
+            mend()
+
+
+def test_the_program_as_configured_is_correct():
+    from ytklearn_tpu.resilience import PreemptionGuard
+
+    got = {}
+
+    def after(run, state):
+        got.update(window=run.window, counters=dict(run.counters_window),
+                   gauges=dict(run.gauges), facts=dict(run.facts),
+                   trees=manifest.load_module("metrics", "trees_in_window.gbst").read(run),
+                   boundary=manifest.load_module("metrics", "tree_boundary_share.gbst").read(run))
+
+    threads = threading.active_count()
+    res = drive(after=after, seconds=1.0)
+    assert res["correct"], res["compared"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert set(res["compared"]) == {
+        "loss_gap", "grad_gap", "dw_gap", "handback_gap", "fold_loss_gap",
+        "fold_test_loss_gap", "next_tree_loss_gap"}
+    assert set(res["metrics"]) == {"examples_per_s", "peak_hbm_gib", "setup_s"}
+    # the window's passes are the program's own count between its boundaries
+    assert got["window"].steps == got["counters"]["lbfgs.passes"] > 0
+    # tree 0 ran its six iterations; the job ended at a later tree's boundary
+    assert got["facts"]["iterations"][0] == 6 and got["facts"]["trees_started"] >= 2
+    assert got["gauges"]["gbst.stat.k"] == 16 and got["gauges"]["gbst.stat.stride"] == 31
+    assert got["gauges"]["blocked.stat.chunks_per_pass"] >= 1
+    if got["trees"]:  # a boundary fell into the window: the spans say how long
+        assert 0 < got["boundary"] < 100
+    # stopped through the trainer's guard: no thread, no handler of it is left
+    assert threading.active_count() == threads
+    handler = signal.getsignal(signal.SIGTERM)
+    assert not isinstance(getattr(handler, "__self__", None), PreemptionGuard)
+
+
+@pytest.mark.parametrize(
+    "fault", manifest.load_module("families", "gbst").FAULTS)
+def test_a_planted_fault_comes_out_not_correct(fault):
+    res = drive(fault=fault)
+    assert not res["correct"], (fault, res["compared"])
+    failing = {k for k, v in res["compared"].items() if v["value"] > v["limit"]}
+    if fault == "fold_skipped":
+        assert failing & {"fold_loss_gap", "next_tree_loss_gap"}, res["compared"]
+    if fault == "altered_answer":
+        assert "handback_gap" in failing, res["compared"]
+
+
+def test_control_bfloat16_reference_is_not_correct():
+    got = {}
+    cell = tiny.tiny_cell(NAME, {})
+    family = manifest.load_module("families", "gbst")
+
+    def after(run, state):
+        got.update(family.control_checks(run, state, cell.config["control"]))
+
+    res = drive(after=after)
+    assert res["correct"]
+    assert got and not harness.verdict(got), got
+
+
+def sizes():
+    with open(os.path.join(manifest.BENCH_DIR, "configs", "gbmlr_higgs.json")) as f:
+        return json.load(f)["sizes"]
+
+
+def test_gbst_floors_at_higgs():
+    s = sizes()
+    assert (s["train_rows"], s["row_width"], s["k"], s["stride"], s["dim"]) == (
+        10_500_000, 29, 16, 31, 899)
+    assert s["stride"] == 2 * s["k"] - 1 and s["dim"] == s["row_width"] * s["stride"]
+    out = work.counter("gbst_tree_output")(s)
+    whole = work.counter("gbst_pass")(s)
+    # every slot's value once, the 29 x 31 table read and written; no ids,
+    # no table row a slot
+    assert out["bytes"] == 10_500_000 * 29 * 4 + 2 * 899 * 4 == 1_218_007_192
+    assert out["flops"] == 3 * 10_500_000 * 29 * 31 * 2 == 56_637_000_000
+    assert 1e3 * work.floor_seconds(out, "TPU v5 lite") == pytest.approx(1.4872, abs=1e-3)
+    # the pass: z, y and the weight of every row besides
+    assert whole["bytes"] == out["bytes"] + 10_500_000 * 12 == 1_344_007_192
+    assert whole["flops"] == out["flops"]
+    assert 1e3 * work.floor_seconds(whole, "TPU v5 lite") == pytest.approx(1.6410, abs=1e-3)
+    # HBM-bound, and the part never exceeds the whole
+    assert whole["flops"] / 197e12 < 0.2 * whole["bytes"] / 819e9
+    assert out["bytes"] < whole["bytes"]

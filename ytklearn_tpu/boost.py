@@ -7,6 +7,13 @@ the learning rate (GBMLRDataFlow.accumulate:540), re-randomize the
 instance/feature Bernoulli masks, re-init weights, and continue. Supports
 gradient_boosting and random_forest types, continue_train via the
 tree-info + tree-%05d model files.
+
+A tree's turn, as the spans have it (docs/observability.md): `gbst.tree`
+(a step) > `gbst.masks` (the host's mask draws, the weights' round trip,
+the re-init), the fit's `lbfgs.*`, `gbst.fold` (the tree folded into the
+train and test scores and the ensemble losses, device-settled), `gbst.dump`.
+The fold scans the rows in the fit's chunks (`optimize/blocked.py`): whole,
+its gathered `(rows, width, stride)` intermediate is rows x 16 KiB.
 """
 
 from __future__ import annotations
@@ -26,7 +33,17 @@ from .io.fs import FileSystem, LocalFileSystem
 from .io.reader import DataIngest, IngestResult
 from .losses import create_loss
 from .models.gbst import GBSTModel
+from .obs import (
+    gauge as obs_gauge,
+    health,
+    inc as obs_inc,
+    root_span as obs_root_span,
+    span as obs_span,
+    step_span as obs_step_span,
+)
+from .obs.scopes import Program
 from .optimize import LBFGSConfig, minimize_lbfgs
+from .optimize.blocked import make_rows
 from .resilience import trainer_guard
 
 log = logging.getLogger("ytklearn_tpu.boost")
@@ -75,14 +92,18 @@ class GBSTTrainer:
         # every finished tree is already dumped (tree-%05d + tree-info), so
         # the boundary just exits via Preempted and `--resume auto`
         # continues at the last finished tree (docs/fault_tolerance.md)
-        with trainer_guard(self):
+        # `train.run`: the root of every span of the run (the CLI has opened
+        # it around the data load already; the benchmark enters here)
+        with obs_root_span("train.run", family=self.variant), trainer_guard(self):
             return self._train_impl(ingest)
 
     def _train_impl(self, ingest: Optional[IngestResult] = None) -> BoostResult:
         p = self.params
         t0 = time.time()
+        health.install_trace_counters()
         if ingest is None:
-            ingest = DataIngest(p, fs=self.fs).load()
+            with obs_span("train.load", model=self.variant):
+                ingest = DataIngest(p, fs=self.fs).load()
         ds_train = ingest.train
         ds_test = ingest.test
         if self.mesh is not None:
@@ -107,6 +128,23 @@ class GBSTTrainer:
             g_weight = float(sum(host_allgather_objects(g_weight)))
             g_weight_test = float(sum(host_allgather_objects(g_weight_test)))
 
+        # one row chunk for the fit and the fold, chosen once. A chunked
+        # scan pads its rows to whole chunks. Padded here, once, with
+        # zero-weight rows (as a mesh's are), every pass finds whole chunks
+        # and pads nothing; padded inside the program, every pass and every
+        # fold copies idx and val whole before its scan (2 x 1.2 GB at
+        # 10.5M rows x 29)
+        width = int(ds_train.idx.shape[1]) if ds_train.idx.ndim > 1 else 1
+        n_shards = int(self.mesh.devices.size) if self.mesh is not None else 1
+        n_draw = ds_train.n  # the masks' draws keep their count
+
+        def whole_chunks(ds):
+            chunk = model.suggest_row_chunk(ds.n, width, n_shards=n_shards)
+            if chunk is not None and jax.process_count() == 1:
+                ds = ds.pad_rows_to(-(-ds.n // (chunk * n_shards)) * chunk * n_shards)
+            return ds, chunk
+
+        ds_train, row_chunk = whole_chunks(ds_train)
         idx = self._put(ds_train.idx)
         val = self._put(ds_train.val)
         y = self._put(ds_train.y)
@@ -114,6 +152,7 @@ class GBSTTrainer:
         # padding rows keep weight 0; z starts at the base score
         z = self._put(np.full((ds_train.n,), base_score, np.float32))
         if ds_test is not None:
+            ds_test, row_chunk_test = whole_chunks(ds_test)
             idx_t = self._put(ds_test.idx)
             val_t = self._put(ds_test.val)
             y_t = self._put(ds_test.y)
@@ -123,9 +162,52 @@ class GBSTTrainer:
         eval_set = EvalSet(p.loss.evaluate_metric) if p.loss.evaluate_metric else None
         cfg = LBFGSConfig.from_params(p.line_search)
 
-        jit_tree_out = jax.jit(model.tree_output)
-        jit_ens_loss = jax.jit(lambda s, yy, ww: _ensemble_loss(loss_fn, s, yy, ww))
+        # what a pass really scans at a time: the rows of a chunk (all of a
+        # shard's where nothing is chunked) and the chunks a pass makes, as
+        # train.py reports them
+        n_rows = int(idx.shape[0])
+        shard_rows = -(-n_rows // n_shards)
+        chunk_rows = min(row_chunk or shard_rows, shard_rows)
+        chunks_per_pass = -(-shard_rows // chunk_rows)
+        obs_gauge("blocked.stat.row_chunk", chunk_rows)
+        obs_gauge("blocked.stat.chunks_per_pass", chunks_per_pass)
+        obs_gauge("blocked.stat.prepared", int(model.prepare is not None))
+        obs_gauge("gbst.stat.row_chunk", chunk_rows)
+        obs_gauge("gbst.stat.chunks_per_pass", chunks_per_pass)
+        obs_gauge("gbst.stat.k", model.K)
+        obs_gauge(
+            "gbst.stat.stride",
+            model.K - 1 if model.scalar_leaves else 2 * model.K - 1,
+        )
+
+        def fold_program(name: str, chunk):
+            """`z + lr * tree(w)` over a set of rows, a chunk at a time."""
+            out = make_rows(
+                model.tree_output, chunk, (True, True, False), self.mesh, "data", 3
+            )
+
+            def fold(z, w, idx, val, gate_mask):
+                return z + lr * out(w, idx, val, gate_mask)
+
+            fold.__name__ = name
+            return Program(fold)
+
+        def gbst_ensemble_loss(s, yy, ww):
+            return _ensemble_loss(loss_fn, s, yy, ww)
+
+        fold_train = fold_program("gbst_fold", row_chunk)
+        jit_ens_loss = Program(gbst_ensemble_loss)
         l1_vec, l2_vec = model.reg_vectors(p.loss.l1[0], p.loss.l2[0])
+        # the tree boundary's programs are made here, before the first fit
+        w_like = self._put_rep(np.zeros((model.dim,), np.float32))
+        full_mask = self._put_rep(np.ones((model.n_features,), np.float32))
+        with obs_span("gbst.compile"):
+            fold_train.compile(z, w_like, idx, val, full_mask)
+            jit_ens_loss.compile(z, y, weight)
+            if ds_test is not None:
+                fold_test = fold_program("gbst_fold_test", row_chunk_test)
+                fold_test.compile(z_t, w_like, idx_t, val_t, full_mask)
+                jit_ens_loss.compile(z_t, y_t, weight_t)
 
         # continue_train: replay finished trees into z
         # (reference: GBMLRDataFlow.loadModel + per-tree accumulate).
@@ -137,7 +219,6 @@ class GBSTTrainer:
         info = load_on_rank0(lambda: model.load_tree_info(self.fs))
         if (p.model.continue_train or p.loss.just_evaluate) and info is not None:
             finished = int(info["finished_tree_num"])
-            full_mask = self._put_rep(np.ones((model.n_features,), np.float32))
             trees_w = load_on_rank0(
                 lambda: [
                     model.load_tree(self.fs, ingest.feature_map, t)
@@ -148,9 +229,9 @@ class GBSTTrainer:
                 if wt is None:
                     raise FileNotFoundError(f"tree-{t:05d} missing for continue_train")
                 wt = self._put_rep(wt)
-                z = z + lr * jit_tree_out(wt, idx, val, full_mask)
+                z = fold_train(z, wt, idx, val, full_mask)
                 if ds_test is not None:
-                    z_t = z_t + lr * jit_tree_out(wt, idx_t, val_t, full_mask)
+                    z_t = fold_test(z_t, wt, idx_t, val_t, full_mask)
             log.info("continue_train: replayed %d finished trees", finished)
 
         # two rng streams: the feature stream draws fixed-size vectors so it
@@ -169,69 +250,73 @@ class GBSTTrainer:
             if self._guard is not None and self._guard.triggered:
                 # trees [0, tree) are on disk (dump_tree + tree-info per
                 # round) — the dump trail IS the checkpoint
-                self._guard.preempt(
-                    p.model.data_path, family=self.variant, trees=tree,
+                with obs_span("gbst.preempt", step=tree):
+                    self._guard.preempt(
+                        p.model.data_path, family=self.variant, trees=tree,
+                    )
+            with obs_step_span("gbst.tree", tree, tree=tree):
+                with obs_span("gbst.masks"):
+                    # per-tree Bernoulli masks (reference: randomNextSample)
+                    inst = np.zeros((ds_train.n,), np.float32)
+                    inst[:n_draw] = rng_inst.rand(n_draw) <= p.instance_sample_rate
+                    inst[ds_train.n_real :] = 0.0
+                    gmask_np = (
+                        rng_feat.rand(model.n_features) <= p.feature_sample_rate
+                    ).astype(np.float32)
+                    if p.model.need_bias:
+                        gmask_np[0] = 1.0
+                    gmask = self._put_rep(gmask_np)
+                    w_eff = self._put(np.asarray(ds_train.weight) * inst * compensate)
+                    w0 = self._put_rep(model.init_weights(tree_seed=tree))
+
+                res = minimize_lbfgs(
+                    model.pure_loss,
+                    w0,
+                    cfg,
+                    batch=(idx, val, z, gmask, y, w_eff),
+                    l1_vec=l1_vec,
+                    l2_vec=l2_vec,
+                    g_weight=g_weight,
+                    callback=(lambda it, st: True) if p.loss.just_evaluate else None,
+                    row_chunk=row_chunk,
+                    row_mask=model.batch_row_mask,
+                    mesh=self.mesh if row_chunk is not None else None,
+                    split=model.loss_split,
                 )
-            # per-tree Bernoulli masks (reference: randomNextSample)
-            inst = (rng_inst.rand(ds_train.n) <= p.instance_sample_rate).astype(np.float32)
-            inst[ds_train.n_real :] = 0.0
-            gmask_np = (rng_feat.rand(model.n_features) <= p.feature_sample_rate).astype(
-                np.float32
-            )
-            if p.model.need_bias:
-                gmask_np[0] = 1.0
-            gmask = self._put_rep(gmask_np)
-            w_eff = self._put(np.asarray(ds_train.weight) * inst * compensate)
+                per_tree_loss.append(res.loss / g_weight)
+                if p.loss.just_evaluate:
+                    break
 
-            w0 = model.init_weights(tree_seed=tree)
-            batch = (idx, val, z, gmask, y, w_eff)
-            row_chunk = model.suggest_row_chunk(
-                int(idx.shape[0]), int(idx.shape[1]) if idx.ndim > 1 else 1,
-                n_shards=(
-                    int(self.mesh.devices.size) if self.mesh is not None else 1
-                ),
-            )
-            res = minimize_lbfgs(
-                model.pure_loss,
-                self._put_rep(w0),
-                cfg,
-                batch=batch,
-                l1_vec=l1_vec,
-                l2_vec=l2_vec,
-                g_weight=g_weight,
-                callback=(lambda it, st: True) if p.loss.just_evaluate else None,
-                row_chunk=row_chunk,
-                row_mask=model.batch_row_mask,
-                mesh=self.mesh if row_chunk is not None else None,
-                split=model.loss_split,
-            )
-            per_tree_loss.append(res.loss / g_weight)
-            if p.loss.just_evaluate:
-                break
+                # accumulate (reference: GBMLRDataFlow.accumulate — lr-shrunk)
+                # and the ensemble's losses, whose fetches settle the span
+                w_tree = res.w
+                with obs_span("gbst.fold") as sp:
+                    z = fold_train(z, w_tree, idx, val, gmask)
+                    ens = self._ensemble_scores(z, tree + 1)
+                    tl = float(jit_ens_loss(ens, y, weight)) / g_weight
+                    sp.add(train_loss=tl)
+                    msg = f"ensemble avg loss={tl:.6f}"
+                    if ds_test is not None:
+                        z_t = fold_test(z_t, w_tree, idx_t, val_t, gmask)
+                        ens_t = self._ensemble_scores(z_t, tree + 1)
+                        ttl = float(jit_ens_loss(ens_t, y_t, weight_t)) / max(
+                            g_weight_test, 1e-12
+                        )
+                        sp.add(test_loss=ttl)
+                        msg += f" test={ttl:.6f}"
 
-            # accumulate (reference: GBMLRDataFlow.accumulate — lr-shrunk)
-            w_tree = res.w
-            z = z + lr * jit_tree_out(w_tree, idx, val, gmask)
-            if ds_test is not None:
-                z_t = z_t + lr * jit_tree_out(w_tree, idx_t, val_t, gmask)
-
-            # dump tree + info, rank0-only (reference: dumpModel + dumpModelInfo)
-            if jax.process_index() == 0:
-                model.dump_tree(
-                    self.fs, np.asarray(w_tree), gmask_np, ingest.feature_map, tree
+                # dump tree + info, rank0-only (reference: dumpModel + dumpModelInfo)
+                with obs_span("gbst.dump"):
+                    if jax.process_index() == 0:
+                        model.dump_tree(
+                            self.fs, np.asarray(w_tree), gmask_np, ingest.feature_map, tree
+                        )
+                        model.dump_tree_info(self.fs, tree + 1, base_score)
+                obs_inc("gbst.trees")
+                log.info(
+                    "[tree=%d] %.1fs fit avg loss=%.6f %s",
+                    tree, time.time() - t0, per_tree_loss[-1], msg,
                 )
-                model.dump_tree_info(self.fs, tree + 1, base_score)
-
-            ens = self._ensemble_scores(z, tree + 1)
-            tl = float(jit_ens_loss(ens, y, weight)) / g_weight
-            msg = f"[tree={tree}] {time.time()-t0:.1f}s fit avg loss={per_tree_loss[-1]:.6f} ensemble avg loss={tl:.6f}"
-            if ds_test is not None:
-                ens_t = self._ensemble_scores(z_t, tree + 1)
-                ttl = float(jit_ens_loss(ens_t, y_t, weight_t)) / max(
-                    g_weight_test, 1e-12
-                )
-                msg += f" test={ttl:.6f}"
-            log.info(msg)
 
         n_built = max(tree_num - finished, 0) + finished
         ens = self._ensemble_scores(z, max(n_built, 1))

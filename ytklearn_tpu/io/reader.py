@@ -213,13 +213,22 @@ class SparseDataset:
         if target <= n:
             return self
         pad = target - n
+
+        def rows(a):
+            # rows handed over on the device (a benchmark's, a caller's) stay there
+            if isinstance(a, np.ndarray):
+                xp = np
+            else:
+                import jax.numpy as xp
+            return xp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+
         return dataclasses.replace(
             self,
-            idx=np.pad(self.idx, ((0, pad), (0, 0))),
-            val=np.pad(self.val, ((0, pad), (0, 0))),
-            y=np.pad(self.y, ((0, pad),) + ((0, 0),) * (self.y.ndim - 1)),
-            weight=np.pad(self.weight, (0, pad)),
-            field=None if self.field is None else np.pad(self.field, ((0, pad), (0, 0))),
+            idx=rows(self.idx),
+            val=rows(self.val),
+            y=rows(self.y),
+            weight=rows(self.weight),
+            field=None if self.field is None else rows(self.field),
         )
 
 

@@ -30,6 +30,7 @@ import numpy as np
 
 from ..config.params import CommonParams
 from ..io.fs import FileSystem
+from ..obs.scopes import scope
 from .base import ConvexModel, random_init
 
 
@@ -127,21 +128,31 @@ class GBSTModel(ConvexModel):
     def tree_output(self, w, idx, val, gate_mask):
         """Current tree's output fx_tree(x) (no z). gate_mask is the
         per-feature Bernoulli mask (n_features,) f32 — multiplied into gate
-        weights so masked features neither contribute nor get gradients."""
+        weights so masked features neither contribute nor get gradients.
+
+        Two scopes, flat: `gbst.lookup` holds the per-slot gathers (and,
+        through autodiff, their scatter-add with its sort), `gbst.mixture`
+        the contractions, the gate probabilities and the weighted sum."""
         K = self.K
-        gm = gate_mask[idx]  # (n, width)
         if self.scalar_leaves:
             U = w[K:].reshape(self.n_features, K - 1)
-            gate_in = jnp.einsum("nw,nwk->nk", val * gm, U[idx])
-            experts = w[:K]  # scalar leaves, broadcast
-            pi = self._gate_probs(gate_in)
-            return pi @ experts
+            with scope("gbst.lookup"):
+                gm = gate_mask[idx]  # (n, width)
+                Ur = U[idx]  # (n, width, K-1)
+            with scope("gbst.mixture"):
+                gate_in = jnp.einsum("nw,nwk->nk", val * gm, Ur)
+                experts = w[:K]  # scalar leaves, broadcast
+                pi = self._gate_probs(gate_in)
+                return pi @ experts
         W = w.reshape(self.n_features, 2 * K - 1)
-        Wr = W[idx]  # (n, width, 2K-1)
-        gate_in = jnp.einsum("nw,nwk->nk", val * gm, Wr[..., : K - 1])
-        experts = jnp.einsum("nw,nwk->nk", val, Wr[..., K - 1 :])  # (n, K)
-        pi = self._gate_probs(gate_in)
-        return jnp.sum(pi * experts, axis=-1)
+        with scope("gbst.lookup"):
+            gm = gate_mask[idx]  # (n, width)
+            Wr = W[idx]  # (n, width, 2K-1)
+        with scope("gbst.mixture"):
+            gate_in = jnp.einsum("nw,nwk->nk", val * gm, Wr[..., : K - 1])
+            experts = jnp.einsum("nw,nwk->nk", val, Wr[..., K - 1 :])  # (n, K)
+            pi = self._gate_probs(gate_in)
+            return jnp.sum(pi * experts, axis=-1)
 
     def _gate_probs(self, gate_in):
         """(n, K-1) gate logits -> (n, K) mixture probabilities."""

@@ -173,9 +173,15 @@ class Program:
         self.jit = jax.jit(fn, **jit_kwargs)
         self._compiled: Dict[tuple, object] = {}
 
-    def __call__(self, *args):
+    def compile(self, *args):
+        """The compiled program for these arguments' signature, made now if
+        it is not there: lowering runs nothing, so a loop can have the
+        programs of a later step made before its first."""
         key = _signature(args)
         compiled = self._compiled.get(key)
         if compiled is None:
             compiled = self._compiled[key] = compile_lowered(self.jit.lower(*args))
-        return compiled(*args)
+        return compiled
+
+    def __call__(self, *args):
+        return self.compile(*args)(*args)
