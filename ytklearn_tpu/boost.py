@@ -14,6 +14,12 @@ the re-init), the fit's `lbfgs.*`, `gbst.fold` (the tree folded into the
 train and test scores and the ensemble losses, device-settled), `gbst.dump`.
 The fold scans the rows in the fit's chunks (`optimize/blocked.py`): whole,
 its gathered `(rows, width, stride)` intermediate is rows x 16 KiB.
+
+Which id each slot holds is read off the rows once at set-up, train and
+test rows each by themselves (`io/reader.py::constant_slots`): where every
+slot of a set of rows holds one id in every row, that set's model instance
+evaluates a tree as a product with the table's rows and looks nothing up a
+slot (`models/gbst.py`); any other rows keep the lookup.
 """
 
 from __future__ import annotations
@@ -30,7 +36,7 @@ import numpy as np
 from .config.params import CommonParams
 from .eval import EvalSet
 from .io.fs import FileSystem, LocalFileSystem
-from .io.reader import DataIngest, IngestResult
+from .io.reader import DataIngest, IngestResult, constant_slots
 from .losses import create_loss
 from .models.gbst import GBSTModel
 from .obs import (
@@ -158,6 +164,12 @@ class GBSTTrainer:
             y_t = self._put(ds_test.y)
             weight_t = self._put(ds_test.weight)
             z_t = self._put(np.full((ds_test.n,), base_score, np.float32))
+            model_t = GBSTModel(
+                p, model.n_features, self.variant, dense_ids=_dense_ids(idx_t, val_t)
+            )
+        # the fit and the train fold evaluate the train rows, the test fold
+        # the test rows: each by what its own rows are
+        model.dense_ids = _dense_ids(idx, val)
 
         eval_set = EvalSet(p.loss.evaluate_metric) if p.loss.evaluate_metric else None
         cfg = LBFGSConfig.from_params(p.line_search)
@@ -175,15 +187,20 @@ class GBSTTrainer:
         obs_gauge("gbst.stat.row_chunk", chunk_rows)
         obs_gauge("gbst.stat.chunks_per_pass", chunks_per_pass)
         obs_gauge("gbst.stat.k", model.K)
+        # slots the fit evaluates by the product (0: it looks every slot up)
+        obs_gauge(
+            "gbst.stat.dense_slots",
+            0 if model.dense_ids is None else len(model.dense_ids),
+        )
         obs_gauge(
             "gbst.stat.stride",
             model.K - 1 if model.scalar_leaves else 2 * model.K - 1,
         )
 
-        def fold_program(name: str, chunk):
+        def fold_program(name: str, rows_model: GBSTModel, chunk):
             """`z + lr * tree(w)` over a set of rows, a chunk at a time."""
             out = make_rows(
-                model.tree_output, chunk, (True, True, False), self.mesh, "data", 3
+                rows_model.tree_output, chunk, (True, True, False), self.mesh, "data", 3
             )
 
             def fold(z, w, idx, val, gate_mask):
@@ -195,7 +212,7 @@ class GBSTTrainer:
         def gbst_ensemble_loss(s, yy, ww):
             return _ensemble_loss(loss_fn, s, yy, ww)
 
-        fold_train = fold_program("gbst_fold", row_chunk)
+        fold_train = fold_program("gbst_fold", model, row_chunk)
         jit_ens_loss = Program(gbst_ensemble_loss)
         l1_vec, l2_vec = model.reg_vectors(p.loss.l1[0], p.loss.l2[0])
         # the tree boundary's programs are made here, before the first fit
@@ -205,7 +222,7 @@ class GBSTTrainer:
             fold_train.compile(z, w_like, idx, val, full_mask)
             jit_ens_loss.compile(z, y, weight)
             if ds_test is not None:
-                fold_test = fold_program("gbst_fold_test", row_chunk_test)
+                fold_test = fold_program("gbst_fold_test", model_t, row_chunk_test)
                 fold_test.compile(z_t, w_like, idx_t, val_t, full_mask)
                 jit_ens_loss.compile(z_t, y_t, weight_t)
 
@@ -354,6 +371,17 @@ class GBSTTrainer:
         if self.params.gbst_type == "random_forest":
             return z / n_trees
         return z
+
+
+def _dense_ids(idx, val) -> Optional[np.ndarray]:
+    """The one id each slot of these rows holds in every row, or None where
+    some slot's differs by row (then every slot is looked up). Processes
+    that each see their own rows would have to agree before they trace one
+    program: they keep the lookup."""
+    if jax.process_count() > 1:
+        return None
+    ids = constant_slots(idx, val)
+    return ids if (ids >= 0).all() else None
 
 
 def _ensemble_loss(loss_fn, scores, y, weight):

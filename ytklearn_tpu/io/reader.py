@@ -232,6 +232,38 @@ class SparseDataset:
         )
 
 
+def _slot_id_range(xp, idx, val):
+    """Per slot, the least and the largest id among its non-zero entries
+    (int32 max and -1 where it has none)."""
+    live = val != 0
+    lo = xp.min(xp.where(live, idx, np.iinfo(np.int32).max), axis=0)
+    hi = xp.max(xp.where(live, idx, -1), axis=0)
+    return lo, hi
+
+
+def constant_slots(idx, val) -> np.ndarray:
+    """Which id does slot j hold in every row? -> (width,) int32, -1 where
+    the slot's id differs between rows.
+
+    Slot j is constant with id c iff every row has `idx[r, j] == c` or
+    `val[r, j] == 0`: a zero-valued entry (a padded-ELL pad `(0, 0.0)`, a
+    zero-weight pad row) adds exactly 0 to any score and any gradient
+    whatever its id, so it decides nothing. A slot whose values are all
+    zero is constant with any id and reads 0. Two reductions over the rows:
+    numpy rows are read on the host, device rows (row-sharded ones too) by
+    one jitted program where they lie."""
+    if isinstance(idx, np.ndarray) and isinstance(val, np.ndarray):
+        lo, hi = _slot_id_range(np, idx, val)
+    else:
+        import jax
+        import jax.numpy as jnp
+
+        lo, hi = jax.jit(_slot_id_range, static_argnums=0)(jnp, idx, val)
+        lo, hi = np.asarray(lo), np.asarray(hi)
+    ids = np.where(lo == hi, hi, -1)
+    return np.where(hi < 0, 0, ids).astype(np.int32)
+
+
 @dataclass
 class _Cols:
     """Columnar rows from the native parser (post label-expansion, hashing,

@@ -17,6 +17,18 @@ fx = z + Σ_p π_p(x)·expert_p(x)   (z = accumulated previous trees; RF: 0)
 All four gradients fall out of autodiff; the reference's feature-mask
 g[i]=0 zeroing is reproduced by multiplying gate weights with the mask
 inside the score (chain rule zeroes the same slots).
+
+Two evaluations of the same sum Σ_j val[r, j]·W[idx[r, j]], chosen from the
+rows (`tree_output`). Rows handed over as `(idx, val)` and nothing else are
+looked up: `W[idx]`, a `(rows, width, stride)` tensor, and by autodiff a
+scatter-add of rows × width updates. Rows whose slots each hold ONE id in
+every row (dense tabular rows: `io/reader.py::constant_slots`, observed once
+at set-up by `boost.py` and handed to the model as `dense_ids`) need no
+lookup a slot: the table's `width` rows are read once an evaluation and the
+sum is the product `val @ W[dense_ids]` on the MXU at `Precision.HIGHEST`
+(float32 means float32: at the default precision the MXU rounds both
+operands to bfloat16); by autodiff the table's gradient is `val.T @
+cotangent` and a scatter of `width` rows. `idx` is then not read at all.
 """
 
 from __future__ import annotations
@@ -27,6 +39,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..config.params import CommonParams
 from ..io.fs import FileSystem
@@ -50,9 +63,20 @@ def heap_leaf_probs(sig):
 
 
 class GBSTModel(ConvexModel):
-    """All four GBST variants; `variant` picks layout + gating."""
+    """All four GBST variants; `variant` picks layout + gating.
 
-    def __init__(self, params: CommonParams, n_features: int, variant: str):
+    `dense_ids`: (width,) ids, the one id each slot holds in every row of
+    the rows this instance evaluates, or None where some slot's id differs
+    by row (a fact of the rows, observed by the caller; one instance a set
+    of rows). It picks the evaluation inside `tree_output`, nothing else."""
+
+    def __init__(
+        self,
+        params: CommonParams,
+        n_features: int,
+        variant: str,
+        dense_ids: Optional[np.ndarray] = None,
+    ):
         super().__init__(params, n_features)
         assert variant in ("gbmlr", "gbsdt", "gbhmlr", "gbhsdt")
         self.variant = variant
@@ -63,6 +87,7 @@ class GBSTModel(ConvexModel):
             raise ValueError(f"{variant} requires K a power of two, got {self.K}")
         self.is_rf = params.gbst_type == "random_forest"
         self.name = variant
+        self.dense_ids = dense_ids
 
     # -- layout ----------------------------------------------------------
 
@@ -117,8 +142,10 @@ class GBSTModel(ConvexModel):
     batch_row_mask = (True, True, True, False, True, True)
 
     def score_bytes_per_row(self, width: int) -> int:
-        """Dominant per-row intermediate: the (width, 2K-1) weight gather
-        (k-minor, pads 2K-1 -> 128)."""
+        """Dominant per-row intermediate of the lookup: the (width, 2K-1)
+        weight gather (k-minor, pads 2K-1 -> 128). It sizes the row chunk
+        whatever the rows are, so on dense rows (`dense_ids`), where no such
+        tensor is made, it is an upper bound."""
         wp = -(-width // 8) * 8
         stride = 2 * self.K - 1 if not self.scalar_leaves else self.K - 1
         return wp * (-(-stride // 128) * 128) * 4
@@ -130,12 +157,26 @@ class GBSTModel(ConvexModel):
         per-feature Bernoulli mask (n_features,) f32 — multiplied into gate
         weights so masked features neither contribute nor get gradients.
 
-        Two scopes, flat: `gbst.lookup` holds the per-slot gathers (and,
-        through autodiff, their scatter-add with its sort), `gbst.mixture`
-        the contractions, the gate probabilities and the weighted sum."""
+        Two scopes, flat. Looked-up rows: `gbst.lookup` holds the per-slot
+        gathers (and, through autodiff, their scatter-add with its sort),
+        `gbst.mixture` the contractions, the gate probabilities and the
+        weighted sum. Dense rows (`dense_ids`): `gbst.lookup` holds the
+        one lookup of the masked table's `width` rows an evaluation,
+        `gbst.mixture` the mask, the product and the rest; the 0/1 mask
+        multiplies the table's rows where the lookup multiplies `val`,
+        which is the same sum."""
         K = self.K
+        c = self.dense_ids
         if self.scalar_leaves:
             U = w[K:].reshape(self.n_features, K - 1)
+            if c is not None:
+                with scope("gbst.mixture"):
+                    Um = gate_mask[:, None] * U  # the mask on the table
+                with scope("gbst.lookup"):
+                    Uc = Um[c]  # (width, K-1)
+                with scope("gbst.mixture"):
+                    gate_in = jnp.dot(val, Uc, precision=lax.Precision.HIGHEST)
+                    return self._gate_probs(gate_in) @ w[:K]
             with scope("gbst.lookup"):
                 gm = gate_mask[idx]  # (n, width)
                 Ur = U[idx]  # (n, width, K-1)
@@ -145,6 +186,19 @@ class GBSTModel(ConvexModel):
                 pi = self._gate_probs(gate_in)
                 return pi @ experts
         W = w.reshape(self.n_features, 2 * K - 1)
+        if c is not None:
+            with scope("gbst.mixture"):
+                # the mask on the gates' columns of the table
+                Wm = jnp.concatenate(
+                    [gate_mask[:, None] * W[:, : K - 1], W[:, K - 1 :]], axis=1
+                )
+            with scope("gbst.lookup"):
+                Wc = Wm[c]  # (width, 2K-1)
+            with scope("gbst.mixture"):
+                # one product for gates and experts
+                out = jnp.dot(val, Wc, precision=lax.Precision.HIGHEST)  # (n, 2K-1)
+                pi = self._gate_probs(out[:, : K - 1])
+                return jnp.sum(pi * out[:, K - 1 :], axis=-1)
         with scope("gbst.lookup"):
             gm = gate_mask[idx]  # (n, width)
             Wr = W[idx]  # (n, width, 2K-1)
