@@ -8,16 +8,34 @@ rows, line-search trials included, by the program's own count: one pass for
 a tree's first evaluation and, for each iteration, the trials its
 `ls_status` reports. The two forward evaluations of a fold are no steps;
 their seconds stay in the window. The recorder wraps `boost.minimize_lbfgs`,
-lives across trees and changes nothing the fits do. A boundary is the moment
-the fit's callback has returned (the device is drained: the trainer has read
-`ls_status` or the first evaluation's norms). Set-up ends at the boundary of
-iteration `warm_steps` of tree 0; the window closes at the first boundary at
-or after `--seconds`.
+lives across trees and changes nothing the fits do.
+
+The window holds whole trees. A tree boundary is the moment the callback of a
+tree's first evaluation has returned: every earlier tree is fitted, folded
+and dumped, this tree's masks are drawn and its weights re-drawn, and the
+device is drained (the trainer has read the first evaluation's norms). Set-up
+ends at the boundary of tree `warm_trees` (at least 1: tree 0, its fold and
+tree 1's first evaluation, which `correct` compares, are set-up); the window
+closes at the first tree boundary at or after `--seconds`. So every window
+holds as many fits as folds, dumps and mask draws, and two runs differ by
+whole trees only. The iteration boundaries in between go to `run.boundary`
+for placing a stall and open or close nothing.
+
+What a window held is counted here and printed in the result line
+(`window`): `passes`, `iterations` (line searches that ended well), `trees`,
+`failed_searches` and `trials_per_iteration` (passes but the trees' first
+evaluations, over iterations). A fit whose `LBFGSResult.status` reads
+`line_search_failed(...)` is one failed search: the run's `failed` counts
+them over the whole job, `attempted` every search begun. The passes of a
+failed search cannot be counted from outside the program: `optimize/lbfgs.py`
+leaves its loop before the callback, and its status carries -1..-3, the
+reason, not the trials (some fifty: the line search's `max_iter` is 55); they are in
+the window's seconds and in none of its steps.
 
 The stop. Answering the callback with "stop" ends one tree's fit, not the
 job: `boost.py` folds that tree and starts the next. So the job is ended the
-way a user's is: once the window has closed and the clock is read, at the
-first boundary of a later tree than tree 0 (tree 1's first evaluation is
+way a user's is: once the window has closed and the clock is read, at that
+same tree boundary (tree 2's or a later one's: tree 1's first evaluation is
 compared), the recorder raises SIGTERM, which the trainer's preemption guard
 defers to the next tree boundary, and answers "stop"; the trainer folds and
 dumps the tree it was on and leaves through `Preempted`.
@@ -39,7 +57,14 @@ def make_rows(seed: int, data_seed: int, sizes: dict):
     labels are `families/gbdt.py::make_rows`'s (loaded by its path, left as
     it is); every row is then laid out as the same `row_width` slots: slot 0
     the bias (id 0, value 1), slot j feature j (id j, value x_j). All weights
-    are 1. -> ((idx, val, y, weight) train, the same for test)."""
+    are 1. -> ((idx, val, y, weight) train, the same for test).
+
+    The order of the train rows is drawn from `data_seed` alone and the seed
+    reorders the test rows: a float32 sum over the train rows in another
+    order differs in its last digit, a boosted chain of fits amplifies that,
+    and from the second tree on two seeds would take other line-search
+    trials, so that a window's work, not its speed, would differ between
+    them (PERF.md section 2). Every seed fits the same trees."""
     import jax
     import jax.numpy as jnp
 
@@ -47,7 +72,14 @@ def make_rows(seed: int, data_seed: int, sizes: dict):
     if width != F + 1:
         raise SystemExit("perfbench: row_width is not the features and the bias slot")
     train_d, test_d = load_module("families", "gbdt").make_rows(
-        seed, data_seed, int(sizes["train_rows"]), int(sizes["test_rows"]), F)
+        data_seed, data_seed, int(sizes["train_rows"]), int(sizes["test_rows"]), F)
+
+    @jax.jit
+    def reordered(X, y, key):
+        order = jax.random.permutation(key, X.shape[0])
+        return X[order], y[order]
+
+    test_d.X, test_d.y = reordered(test_d.X, test_d.y, jax.random.PRNGKey(seed % (2**31)))
 
     @jax.jit
     def slots(X):
@@ -123,7 +155,10 @@ def train(run, overrides: dict) -> dict:
     check_program()
     program = {**run.cell.config["program"], **overrides}
     sizes = run.cell.sizes
-    warm = int(run.cell.traffic["warm_steps"])
+    warm = int(run.cell.traffic["warm_trees"])
+    if warm < 1:
+        raise SystemExit("perfbench: warm_trees under 1: tree 0, its fold and tree 1's "
+                         "first evaluation are compared and belong to set-up")
     follow = int(run.cell.config["compare"]["follow_iterations"])
     obs.configure(enabled=True)
     obs.health.install_trace_counters()
@@ -140,7 +175,7 @@ def train(run, overrides: dict) -> dict:
     train_b, test_b = ((d.idx, d.val, d.y, d.weight) for d in (train_ds, test_ds))
     ingest = IngestResult(train=train_ds, test=test_ds, feature_map=feature_names(sizes))
     del train_ds, test_ds
-    rec = {"passes": 0, "fits": [], "stopped": False}
+    rec = Recorder()
     orig_minimize = boost_mod.minimize_lbfgs
 
     def end_job() -> None:
@@ -148,21 +183,22 @@ def train(run, overrides: dict) -> dict:
         if guard is None or not guard.installed:
             raise SystemExit("perfbench: the trainer runs under no preemption guard "
                              "(YTK_PREEMPT=0?): the job cannot be ended as a user's is")
-        rec["stopped"] = True
+        rec.stopped = True
         signal.raise_signal(signal.SIGTERM)  # the guard sets its flag; nothing else
 
     def minimize(*a, callback=None, **kw):
-        tree = len(rec["fits"])
+        tree = len(rec.fits)
         fit = {"loss": [], "trials": [], "iters": 0}
-        rec["fits"].append(fit)
+        rec.fits.append(fit)
 
         def recording(it, state):
             if it == 0:
-                rec["passes"] += 1  # a tree's first evaluation is a pass
+                rec.passes += 1  # a tree's first evaluation is a pass
                 fit["w0"], fit["g0"] = state.w, state.g
             else:
                 ls = int(state.ls_status)
-                rec["passes"] += abs(ls) if ls else 0
+                rec.passes += abs(ls) if ls else 0
+                rec.iterations += 1
                 fit["trials"].append(ls)
                 fit["iters"] = it
             if it <= follow:
@@ -171,18 +207,25 @@ def train(run, overrides: dict) -> dict:
                 fit["w_follow"] = state.w
             fit["w_last"] = state.w
             stop = callback(it, state) if callback is not None else False
-            run.boundary(rec["passes"])
-            if tree == 0 and it == warm:
-                run.open_window(rec["passes"])
+            run.boundary(rec.passes)
+            if it != 0:
+                return stop
+            # a tree boundary: the only place the window opens or closes
+            if tree == warm:
+                run.open_window(rec.passes)
+                rec.at_open = rec.counts(tree)
             elif run.window is not None and run.window.due(time.perf_counter()):
-                run.close_window(rec["passes"])
-            closed = run.window is not None and run.window.t_close is not None
-            if closed and tree >= 1 and not rec["stopped"]:
+                run.close_window(rec.passes)
+                rec.at_close = rec.counts(tree)
+            if rec.at_close is not None and not rec.stopped:
                 end_job()
                 return True
             return stop
 
-        return orig_minimize(*a, callback=recording, **kw)
+        res = orig_minimize(*a, callback=recording, **kw)
+        if str(res.status).startswith("line_search_failed"):
+            rec.failed_trees.append(tree)
+        return res
 
     boost_mod.minimize_lbfgs = minimize
     t_train = time.perf_counter()
@@ -193,13 +236,18 @@ def train(run, overrides: dict) -> dict:
     finally:
         boost_mod.minimize_lbfgs = orig_minimize
     if run.window is not None and run.window.is_open:
-        run.close_window(rec["passes"], exhausted=True)  # ended by itself
+        run.close_window(rec.passes, exhausted=True)  # ended by itself
+        rec.at_close = rec.counts(len(rec.fits))
     folds = sorted((s for s in obs.spans_between(t_train, float("inf"))
                     if s["name"] == "gbst.fold"), key=lambda s: s["start"])
+    run.window_counts = rec.window_counts()
     run.facts.update(
-        trees_started=len(rec["fits"]), passes_total=rec["passes"],
-        iterations=[f["iters"] for f in rec["fits"]],
-        trials=[f["trials"] for f in rec["fits"]],
+        run.window_counts, trees_held=[rec.at_open["trees"], rec.at_close["trees"]],
+        trees_started=len(rec.fits), passes_total=rec.passes,
+        failed_trees=rec.failed_trees,
+        iterations_a_tree=[f["iters"] for f in rec.fits],
+        trials_a_tree=[sum(abs(t) for t in f["trials"]) for f in rec.fits],
+        trials_tree_0=rec.fits[0]["trials"],
         fold_s=[s["end"] - s["start"] for s in folds],
         trees_in_window=run.counters_window.get("gbst.trees", 0.0))
     state = {"rec": rec, "train": train_b, "test": test_b,
@@ -208,6 +256,36 @@ def train(run, overrides: dict) -> dict:
              "seed": int(trainer.params.random.seed)}
     del trainer, ingest
     return state
+
+
+class Recorder:
+    """What the wrapper around `boost.minimize_lbfgs` has seen of the job so
+    far: passes, iterations whose line search ended well, a record a fit
+    (`fits`, by tree) and the trees whose fit ended in a failed search.
+    `at_open` and `at_close` are `counts` at the window's two tree
+    boundaries."""
+
+    def __init__(self):
+        self.passes = self.iterations = 0
+        self.fits, self.failed_trees = [], []
+        self.at_open = self.at_close = None
+        self.stopped = False
+
+    def counts(self, tree: int) -> dict:
+        """At the boundary of `tree`: trees [0, tree) are done and this
+        tree's first evaluation is counted."""
+        return {"trees": tree, "passes": self.passes, "iterations": self.iterations,
+                "failed_searches": len(self.failed_trees)}
+
+    def window_counts(self) -> dict:
+        """The five counts that say whether two windows held the same work."""
+        if self.at_open is None or self.at_close is None:
+            raise SystemExit("perfbench: the job never reached the window's tree boundaries")
+        held = {k: self.at_close[k] - self.at_open[k] for k in self.at_open}
+        trials = held["passes"] - held["trees"]  # a first evaluation a tree is no trial
+        held["trials_per_iteration"] = (
+            trials / held["iterations"] if held["iterations"] else None)
+        return held
 
 
 def _reference(run):
@@ -242,7 +320,7 @@ def reference_pass(run, state: dict, compute=None) -> dict:
     l2 = jnp.asarray(ref.l2_vector(v, nf, K, mdl["need_bias"], mdl["l2"]))
     pass_fn = ref.make_pass(v, nf, K, c["block"], compute=compute or jnp.float32)
     n_iter = min(int(run.cell.config["compare"]["follow_iterations"]),
-                 int(state["rec"]["fits"][0]["iters"]))
+                 int(state["rec"].fits[0]["iters"]))
     g_weight = float(jnp.sum(wt))
     out = ref.follow(pass_fn, w0, batch, l2, g_weight, n_iter, mdl["line_search"], m=c["m"])
     out.update(w0=w0, base=base, feat=feat, masks=masks, l2=l2, g_weight=g_weight,
@@ -271,7 +349,7 @@ def reference_fold(run, state: dict, out: dict, compute=None) -> dict:
         got[name + "_loss"] = ref.mean_loss(z1, y, wt, float(jnp.sum(wt)))
         if name == "train":
             got["z1"] = z1
-    if len(state["rec"]["fits"]) > 1 and compute is None:
+    if len(state["rec"].fits) > 1 and compute is None:
         idx, val, y, wt = state["train"]
         keep, feat = out["masks"].next()
         w1 = ref.init_weights(v, nf, K, mdl["need_bias"], state["seed"], 1, mdl["init"])
@@ -298,9 +376,10 @@ def compare(run, state: dict) -> dict:
     v, nf, K = c["variant"], c["nf"], c["K"]
     rec = state["rec"]
     limits = run.cell.config["compare"]["limits"]
-    fit0 = rec["fits"][0]
-    run.attempted = int(sum(f["iters"] for f in rec["fits"]))
-    run.failed = int(sum(1 for f in rec["fits"] for t in f["trials"] if t < 0))
+    fit0 = rec.fits[0]
+    # every line search the job began, and those that failed (a fit's status)
+    run.failed = len(rec.failed_trees)
+    run.attempted = rec.iterations + run.failed
     out = state["ref_out"] = reference_pass(run, state)
     g = ref.gaps(v, {"loss": fit0["loss"], "g0": fit0["g0"], "w0": fit0["w0"],
                      "w": fit0.get("w_follow", fit0["w_last"])}, out, nf, K)
@@ -310,16 +389,16 @@ def compare(run, state: dict) -> dict:
     missing = 1e30  # the run never crossed the tree boundary
     g.update(fold_loss_gap=missing, fold_test_loss_gap=missing,
              next_tree_loss_gap=missing, handback_gap=missing)
-    if state["folds"] and len(rec["fits"]) > 1:
+    if state["folds"] and len(rec.fits) > 1:
         fold = state["ref_fold"] = reference_fold(run, state, out)
         prog = state["folds"][0]
         dumped_as = ref.masked(v, np.asarray(fit0["w_last"]), out["feat"], nf, K)
         g.update(
             fold_loss_gap=rel(prog["train_loss"], fold["train_loss"]),
             fold_test_loss_gap=rel(prog["test_loss"], fold["test_loss"]),
-            next_tree_loss_gap=rel(rec["fits"][1]["loss"][0], fold["next_loss"]),
+            next_tree_loss_gap=rel(rec.fits[1]["loss"][0], fold["next_loss"]),
             next_init_gap=float(np.max(np.abs(
-                np.asarray(rec["fits"][1]["w0"]) - fold["next_w0"]))),
+                np.asarray(rec.fits[1]["w0"]) - fold["next_w0"]))),
             handback_gap=float(np.max(np.abs(fold["w_tree"] - dumped_as))))
     run.readings = g
     print("perfbench readings: " + json.dumps(

@@ -9,11 +9,9 @@ SCOPES = ("gbst.lookup", "gbst.mixture")
 
 
 def read(run):
-    pd = spans.profile(run)
-    scope_map = spans.program_scope_map()
-    if pd is None or not scope_map or run.window.steps <= 0:
+    by_scope = spans.scope_seconds(run)
+    if by_scope is None or run.window.steps <= 0:
         return None
-    by_scope = spans.scope_self_seconds(spans.ops_with_modules(pd), scope_map)
     seconds = sum(by_scope.get(s, 0.0) for s in SCOPES)
     if not seconds:
         return None

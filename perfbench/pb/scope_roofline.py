@@ -14,11 +14,10 @@ from pb import spans, work
 def scope_roofline_pct(run, scope: str) -> Optional[float]:
     """None in an untraced run, where the program wrote no scope map, or
     where no operation of the trace lies under `scope`."""
-    pd = spans.profile(run)
-    scope_map = spans.program_scope_map()
-    if pd is None or not scope_map or run.window.steps <= 0:
+    by_scope = spans.scope_seconds(run)
+    if by_scope is None or run.window.steps <= 0:
         return None
-    seconds = spans.scope_self_seconds(spans.ops_with_modules(pd), scope_map).get(scope)
+    seconds = by_scope.get(scope)
     if not seconds:
         return None
     count = work.counter(run.cell.config["work"]["scopes"][scope])

@@ -296,17 +296,33 @@ def scope_self_seconds(ops: Sequence[Tuple[float, float, str, str]],
     return xplane._reduce_op_line(events)[1]
 
 
+_BY_SCOPE: Dict[int, Dict[str, float]] = {}  # id(ProfileData) -> seconds by scope
+
+
+def scope_seconds(run) -> Optional[Dict[str, float]]:
+    """Device self seconds of the traced window by the program's scopes,
+    reduced once a run (a window of soft trees holds millions of operations
+    and three readers ask); None in an untraced run or where the program
+    wrote no scope map."""
+    pd = profile(run)
+    scope_map = program_scope_map()
+    if pd is None or not scope_map:
+        return None
+    if id(pd) not in _BY_SCOPE:
+        _BY_SCOPE.clear()
+        _BY_SCOPE[id(pd)] = scope_self_seconds(ops_with_modules(pd), scope_map)
+        print("perfbench scopes: " + json.dumps(
+            sorted(_BY_SCOPE[id(pd)].items(), key=lambda kv: -kv[1])), file=sys.stderr)
+    return _BY_SCOPE[id(pd)]
+
+
 def scope_share_pct(run, scopes: Sequence[str]) -> Optional[float]:
     """Device self seconds of the operations under `scopes` over the busy
     seconds of the traced window; None where the program wrote no map or no
     operation of the trace is under one of them."""
-    pd = profile(run)
-    scope_map = program_scope_map()
-    if pd is None or not scope_map or run.trace.busy_s <= 0:
+    by_scope = scope_seconds(run)
+    if by_scope is None or run.trace.busy_s <= 0:
         return None
-    by_scope = scope_self_seconds(ops_with_modules(pd), scope_map)
     if not any(s in by_scope for s in scopes):
         return None
-    print("perfbench scopes: " + json.dumps(
-        sorted(by_scope.items(), key=lambda kv: -kv[1])), file=sys.stderr)
     return 100.0 * sum(by_scope.get(s, 0.0) for s in scopes) / run.trace.busy_s

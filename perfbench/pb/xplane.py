@@ -183,16 +183,22 @@ def summarize(path: str, chips: int = 1) -> TraceSummary:
         if not DEVICE_PLANE.match(plane.name):
             continue
         evs = []
+        named: Dict[str, Tuple[str, str]] = {}  # event name -> (key, text)
         for line in plane.lines:
             if line.name != OP_LINE:
                 continue
             for ev in line.events:
-                st = _stats(ev)
-                key = op_key(ev.name)
-                text = " ".join(
-                    [ev.name] + [v for v in st.values() if isinstance(v, str)]
-                )
-                evs.append((ev.start_ns, ev.duration_ns, key, text[:4000]))
+                name = ev.name
+                if name not in named:
+                    # an operation's key and text are its first event's: a
+                    # window holds the same few hundred operations millions
+                    # of times, and reading an event's stats is the slow part
+                    st = _stats(ev)
+                    text = " ".join(
+                        [name] + [v for v in st.values() if isinstance(v, str)]
+                    )
+                    named[name] = (op_key(name), text[:4000])
+                evs.append((ev.start_ns, ev.duration_ns) + named[name])
         n_events += len(evs)
         busy, self_s, descr, gaps, first, last = _reduce_op_line(evs)
         devices.append(DeviceTrace(busy, first, last, self_s, descr, gaps))

@@ -116,6 +116,7 @@ class Run:
         self.memory_peak_bytes = None
         self.trace = None  # pb.xplane.TraceSummary
         self.facts = {}  # family-specific readings (iterations, ...)
+        self.window_counts = {}  # what the window held, where a family counts it
         self.boundaries = []  # (seconds since the window opened, steps) a family saw
         self.checks = {}  # name -> (value, limit)
         self.readings = {}  # every gap the comparison read, compared or not
@@ -260,6 +261,8 @@ def drive(cell, seed: int, seconds: float, trace: bool, device: dict,
             "idle_gaps": [[k, v] for k, v in run.trace.idle_gaps[:10]],
         }
         shutil.rmtree(run.trace_dir, ignore_errors=True)
+    if run.window_counts:  # tells two runs of unlike rates whether their work was alike
+        result["window"] = run.window_counts
     # each number compared beside its limit: last on stderr, last in the line
     result["compared"] = print_compared(run.checks)
     return result

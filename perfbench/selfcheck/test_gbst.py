@@ -1,6 +1,9 @@
 """The `gbmlr_higgs.train` cell's self-checks on the CPU, as `test_ffm.py`
 keeps them for the cell before it: the program as configured comes out
-`correct` at a tiny size against the committed limits; each planted fault
+`correct` at a tiny size against the committed limits (60 iterations a tree,
+as the configuration states); the window opens and closes on tree boundaries
+and the result line says what it held; a fit that ends in a failed line
+search is counted and named; each planted fault
 (the skipped fold among them) and the bfloat16 control put in the program's
 place come out not correct; the window's passes are the program's own count;
 the stop through the preemption guard leaves no thread or handler behind;
@@ -72,11 +75,11 @@ def test_the_program_as_configured_is_correct():
     assert set(res["compared"]) == {
         "loss_gap", "grad_gap", "dw_gap", "handback_gap", "fold_loss_gap",
         "fold_test_loss_gap", "next_tree_loss_gap"}
-    assert set(res["metrics"]) == {"examples_per_s", "peak_hbm_gib", "setup_s"}
+    assert set(res["metrics"]) == {"examples_per_s.gbst", "peak_hbm_gib", "setup_s"}
     # the window's passes are the program's own count between its boundaries
     assert got["window"].steps == got["counters"]["lbfgs.passes"] > 0
     # tree 0 ran its six iterations; the job ended at a later tree's boundary
-    assert got["facts"]["iterations"][0] == 6 and got["facts"]["trees_started"] >= 2
+    assert got["facts"]["iterations_a_tree"][0] == 60 and got["facts"]["trees_started"] >= 2
     assert got["gauges"]["gbst.stat.k"] == 16 and got["gauges"]["gbst.stat.stride"] == 31
     assert got["gauges"]["blocked.stat.chunks_per_pass"] >= 1
     if got["trees"]:  # a boundary fell into the window: the spans say how long
@@ -85,6 +88,67 @@ def test_the_program_as_configured_is_correct():
     assert threading.active_count() == threads
     handler = signal.getsignal(signal.SIGTERM)
     assert not isinstance(getattr(handler, "__self__", None), PreemptionGuard)
+
+
+COUNTS = {"passes", "iterations", "trees", "failed_searches", "trials_per_iteration"}
+
+
+def test_the_window_holds_whole_trees_and_the_result_says_what_it_held():
+    got = {}
+
+    def after(run, state):
+        got.update(window=run.window, facts=dict(run.facts), rec=state["rec"],
+                   counters=dict(run.counters_window), boundaries=list(run.boundaries))
+
+    res = drive(after=after, seconds=0.5)
+    rec, w, held = got["rec"], got["window"], res["window"]
+    assert set(held) == COUNTS and res["correct"]
+    # opened at tree 1's boundary (warm_trees), closed at a later tree's
+    first, last = got["facts"]["trees_held"]
+    assert first == 1 and last >= 2 and held["trees"] == last - first
+    assert held["trees"] == got["counters"]["gbst.trees"]  # the program's own count
+    # both boundaries are first evaluations: the passes between them are the
+    # whole fits of trees [first, last) and the next tree's first evaluation
+    fits = rec.fits[first:last]
+    trials = sum(abs(t) for f in fits for t in f["trials"])
+    assert w.steps == held["passes"] == trials + held["trees"]
+    assert held["iterations"] == sum(f["iters"] for f in fits)
+    assert held["trials_per_iteration"] == pytest.approx(trials / held["iterations"])
+    assert held["failed_searches"] == 0 == res["failed"]
+    assert res["attempted"] == sum(f["iters"] for f in rec.fits)
+    # the last boundary the family noted inside the window is the close itself
+    assert got["boundaries"][-1][1] == w.steps_close
+    assert not w.exhausted and w.length_s >= 0.5
+
+
+def test_a_fit_that_ends_in_a_failed_search_is_counted_and_named(monkeypatch):
+    """A planted `LBFGSResult`: tree 1's fit comes back as the program hands
+    back a search that halved its step down to `min_step`."""
+    import dataclasses
+
+    import ytklearn_tpu.boost as boost_mod
+
+    real, n = boost_mod.minimize_lbfgs, {"fits": 0}
+
+    def minimize(*a, **kw):
+        res, n["fits"] = real(*a, **kw), n["fits"] + 1
+        if n["fits"] == 2:
+            return dataclasses.replace(res, status="line_search_failed(-1)")
+        return res
+
+    monkeypatch.setattr(boost_mod, "minimize_lbfgs", minimize)
+    got = {}
+    res = drive(after=lambda run, state: got.update(run.facts), seconds=0.2)
+    assert res["failed"] == 1 and got["failed_trees"] == [1]
+    assert res["window"]["failed_searches"] == 1  # tree 1 lies in the window
+    assert res["attempted"] == res["failed"] + sum(got["iterations_a_tree"])
+    assert res["correct"]  # a failed search is counted, not judged
+
+
+def test_a_window_needs_tree_0_in_set_up():
+    cell = tiny.tiny_cell(NAME, SIZES, traffic={"warm_trees": 0})
+    with pytest.raises(SystemExit, match="warm_trees"):
+        harness.drive(cell, 2147483659, 0.2, False, tiny.CPU_DEVICE)
 
 
 @pytest.mark.parametrize(
@@ -110,6 +174,27 @@ def test_control_bfloat16_reference_is_not_correct():
     res = drive(after=after)
     assert res["correct"]
     assert got and not harness.verdict(got), got
+
+
+def test_every_seed_fits_the_same_train_rows_and_reorders_the_test_rows():
+    import numpy as np
+
+    family = manifest.load_module("families", "gbst")
+    cell = tiny.tiny_cell(NAME, SIZES)
+    data_seed = int(cell.traffic["data_seed"])
+    (tr_a, te_a), (tr_b, te_b), (tr_c, te_c) = (
+        family.make_rows(seed, data_seed, cell.sizes)
+        for seed in (2147483659, 2147483659, 2**31 + 12345))
+    for a, b, c in zip(tr_a, tr_b, tr_c):  # idx, val, y, weight: the order too
+        assert np.array_equal(a, b) and np.array_equal(a, c)
+    for a, b in zip(te_a, te_b):  # the same seed gives the same inputs
+        assert np.array_equal(a, b)
+    val_a, val_c = np.asarray(te_a[1]), np.asarray(te_c[1])
+    assert not np.array_equal(val_a, val_c)  # another seed: another order
+    key = lambda v: v[np.lexsort(v.T[::-1])]  # noqa: E731
+    assert np.array_equal(key(val_a), key(val_c))  # of the same rows
+    other = family.make_rows(2147483659, data_seed + 1, cell.sizes)[0]
+    assert not np.array_equal(np.asarray(other[1]), np.asarray(tr_a[1]))
 
 
 def sizes():

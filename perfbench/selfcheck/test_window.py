@@ -58,3 +58,43 @@ def test_a_window_cannot_close_early_or_twice():
         w.close(13.0, 10)
     with pytest.raises(RuntimeError):
         Window(1.0).rate()
+
+
+def tree_boundaries(trees, t0=50.0):
+    """(time, passes finished) at every tree boundary of a job of soft trees:
+    `trees` is a list of (seconds, passes) a tree, its fold and the next
+    tree's first evaluation included; boundary i is tree i's first
+    evaluation done."""
+    out, t, passes = [(t0, 1)], t0, 1
+    for seconds, n in trees:
+        t, passes = t + seconds, passes + n
+        out.append((t, passes))
+    return out
+
+
+@pytest.mark.parametrize("warm_trees,seconds,held", [
+    (1, 30.0, 9),   # 8 trees are 27.6 s: the ninth closes it
+    (1, 27.6, 8),   # a boundary at exactly --seconds closes
+    (2, 30.0, 9),   # opened one tree later, closed one tree later
+    (1, 3.0, 1),    # a window shorter than a tree holds one whole tree
+])
+def test_a_window_of_soft_trees_opens_and_closes_on_tree_boundaries(warm_trees, seconds, held):
+    # a tree: 60 iterations, 70 trials, the next tree's first evaluation
+    b = tree_boundaries([(3.45, 71)] * 20)
+    w = close_on_boundaries(b, open_index=warm_trees, seconds=seconds)
+    assert (w.t_open, w.steps_open) == b[warm_trees]
+    assert (w.t_close, w.steps_close) in b and not w.exhausted
+    assert b.index((w.t_close, w.steps_close)) - warm_trees == held
+    assert w.steps == 71 * held and w.length_s == pytest.approx(3.45 * held)
+    # a whole number of trees: as many fits as boundaries, whatever --seconds
+    assert w.rate() == pytest.approx(71 / 3.45)
+
+
+def test_trees_of_unlike_length_still_close_on_a_boundary():
+    # a tree with a failed search takes 1.43 s more and adds no pass
+    trees = [(3.4, 70), (3.6, 76), (4.83, 70), (3.3, 66), (3.5, 72)] * 4
+    b = tree_boundaries(trees)
+    w = close_on_boundaries(b, open_index=1, seconds=10.0)
+    assert (w.t_close, w.steps_close) == b[4]  # 3.6 + 4.83 is 8.43 s: tree 3 closes it
+    assert w.steps == 76 + 70 + 66 and w.length_s == pytest.approx(3.6 + 4.83 + 3.3)
+    assert w.overshoot_s == pytest.approx(1.73)

@@ -1,7 +1,9 @@
 """Spreads of the runs that tools/sets.sh recorded, by the contract's rule:
 the distance between the first and third quartile
 (`statistics.quantiles(values, n=4)`) as a share of the median, for each
-metric in each labelled set.
+metric in each labelled set. Before them one line a run: its rate beside
+what its window held (the result line's `window`, where a family counts it),
+so that two runs of unlike rates can be told apart by their work.
 
     python3 perfbench/tools/spread.py chiprun_out/sets/<cell>.jsonl
 """
@@ -21,6 +23,10 @@ def main() -> int:
         if not res or rec["trace"]:
             continue
         bad += 0 if res["correct"] else 1
+        rates = [v["value"] for k, v in res["metrics"].items() if "_per_s" in k]
+        print(f"{rec['label']:12s} seed {rec['seed']} rate {rates[0] if rates else None} "
+              f"correct {res['correct']} failed {res['failed']} of {res['attempted']} "
+              f"window {json.dumps(res.get('window'))}")
         for k, v in res["metrics"].items():
             sets[rec["label"]][k].append(v["value"])
         sets[rec["label"]]["_wall_s"].append(rec["wall_s"])
