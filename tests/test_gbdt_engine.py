@@ -391,6 +391,17 @@ def test_benchmark_contract(tmp_path):
     assert [r for r, _ in seen["preempt"]] == [2]
     assert seen["preempt"][0][1][0] == 4 and seen["preempt"][0][1][2] == 5
     assert tr.time_stats["preprocess"] > 0
+    # the gauges perfbench/metrics/{hist_pool_gib,hist_part_roofline}.py and
+    # docs/observability.md name, published on the stop path too: what the
+    # width chose and what the partitioned passes needed
+    for k in ("features", "hist_pool_bytes", "route_kernel", "packed_tiles",
+              "rungs_fused", "rungs_xla", "trees_logged", "hist_part_passes",
+              "hist_part_rows_scanned", "hist_part_rows_needed",
+              "leaf_lookup_kernel"):
+        assert k in tr.time_stats, k
+    assert tr.time_stats["features"] == 6
+    assert tr.time_stats["hist_pool_bytes"] == 15 * 6 * 32 * 3 * 4
+    assert tr.time_stats["trees_logged"] == 2
 
     # a run that ends by itself hands the carry back through _run_rounds
     p2 = _params(tmp_path / "b", "loss", round_num=2, max_leaf_cnt=8)

@@ -193,7 +193,7 @@ def test_training_with_the_kernel_is_the_same_run(
 
     calls = []
 
-    def through_the_kernel(leaf, pos, *, kernels, bm, mesh=None):
+    def through_the_kernel(leaf, pos, *, kernels, bm, mesh=None, interpret=False):
         calls.append(pos.shape)
         return route.leaf_values(
             leaf, pos, kernels="pallas", bm=bm, mesh=mesh, interpret=True
@@ -306,3 +306,53 @@ def test_sharded_kernel_compiles_for_four_chips(v5e):
     # a shard looks up its own rows: no row crosses a chip
     for op in ("all-gather", "all-reduce(", "collective-permute", "all-to-all"):
         assert op not in text, op
+
+
+# The kernels of the wide path (gbdt_epsilon: 409,600 rows x 2,000 columns)
+# at their real shapes, for the same described chip: the full scan on packed
+# words at a 64-node wave and at one node, and what makes the words.
+
+
+@pytest.mark.parametrize(
+    "precision, N", [("bf16", 64), ("int8", 64), ("bf16", 1)]
+)
+def test_wide_scan_kernel_compiles_for_the_chip(v5e, precision, N):
+    from jax.sharding import SingleDeviceSharding
+
+    from ytklearn_tpu.gbdt import hist
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    F, n, bm, B = 2000, 409_600, 16384, 256
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda b4, pos, g, h, ids: hist.hist_wave(
+                b4, pos, g, h, ids, B, precision=precision, kernels="pallas")
+        ).lower(
+            S((F, n // bm, 1, bm // 4), jnp.int32), S((n,), jnp.int32),
+            S((n,), jnp.float32), S((n,), jnp.float32), S((N,), jnp.int32),
+        ).compile()
+    assert "gbdt_hist_scan" in compiled.as_text()
+
+
+def test_wide_tiles_are_packed_within_the_byte_budget(v5e):
+    """2,000 x 409,600 one-byte bins into words, 154 features at a time:
+    the temporaries stay under a quarter of the 3.05 GiB the widened copy
+    would take whole."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ytklearn_tpu.gbdt import hist
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda b: hist.tile_bins(b, 16384, pack=True)
+        ).lower(
+            jax.ShapeDtypeStruct((2000, 409_600), jnp.uint8, sharding=one)
+        ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 2000 * 409_600
+    assert mem.temp_size_in_bytes < (3 << 30) // 4

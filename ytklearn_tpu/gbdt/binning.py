@@ -41,6 +41,59 @@ from ..config.params import ApproximateSpec, GBDTParams
 # column; the sketch is O(b log(n/chunk))). Override: YTK_SKETCH_ROWS.
 SKETCH_ROWS = knobs.get_int("YTK_SKETCH_ROWS")
 
+# The one byte budget of the device-side passes that grow with the column
+# count (quantiles, bin ids, EFB's column statistics here; hist.tile_bins'
+# widened copy): a 4-byte-a-cell copy of an (F, n) matrix is made whole up
+# to WHOLE_BYTES (Higgs: 28 x 10.5M x 4 B = 1.18 GB), and past it (Epsilon:
+# 2,000 x 400,000 x 4 B = 3.2 GB beside the raw rows) CHUNK_BYTES of
+# columns at a time. Columns are independent in every one of those passes,
+# so the chunked results are the whole matrix's bit for bit
+# (tests/test_gbdt_wide.py).
+WHOLE_BYTES = 2 << 30
+CHUNK_BYTES = 1 << 28
+
+
+def feature_chunk(F: int, n: int) -> int:
+    """Columns of an (F, n) matrix of 4-byte cells worked on at a time: all
+    F where the whole is at most WHOLE_BYTES, else equal parts of about
+    CHUNK_BYTES (12 parts of 167 columns at 2,000 x 400,000)."""
+    if F * n * 4 <= WHOLE_BYTES:
+        return F
+    parts = -(-(F * n * 4) // CHUNK_BYTES)
+    return -(-F // parts)
+
+
+class ColumnsT:
+    """The raw (n, F) rows as the device-side binning reads them: (F, n)
+    column-major, a range of columns at a time. Up to WHOLE_BYTES the
+    transposed copy is made once and kept, and `chunks()` yields it whole;
+    past that no transposed copy of the matrix exists and every pass
+    transposes `feature_chunk` columns at a time."""
+
+    def __init__(self, X):
+        import jax
+        import jax.numpy as jnp
+
+        self.X = jax.device_put(X)
+        self.n, self.F = self.X.shape
+        self.step = feature_chunk(self.F, self.n)
+        self.whole = jnp.transpose(self.X) if self.step >= self.F else None
+
+    @property
+    def n_chunks(self) -> int:
+        return -(-self.F // self.step)
+
+    def chunks(self):
+        """(lo, hi, (hi - lo, n) device array) over all columns in order."""
+        import jax.numpy as jnp
+
+        if self.whole is not None:
+            yield 0, self.F, self.whole
+            return
+        for lo in range(0, self.F, self.step):
+            hi = min(lo + self.step, self.F)
+            yield lo, hi, jnp.transpose(self.X[:, lo:hi])
+
 
 @dataclass
 class FeatureBins:
@@ -663,17 +716,32 @@ def build_bundle_plan(
     nnz: Optional[np.ndarray] = None,
     mins: Optional[np.ndarray] = None,
 ) -> Optional[BundlePlan]:
-    """Plan EFB bundles from a transposed (F, n) matrix (device jnp array
-    or host numpy — the nonzero-pattern reductions and the candidate
-    conflict matmul run wherever the matrix lives). Host callers can pass
+    """Plan EFB bundles from a transposed (F, n) matrix (device jnp array,
+    host numpy or a ColumnsT — the nonzero-pattern reductions and the
+    candidate conflict matmul run wherever the matrix lives). Host callers can pass
     precomputed (nnz, mins) from gbdt.data.column_stats to keep the
     full-matrix boolean pattern from materializing. Returns None when
     nothing bundles."""
     import jax.numpy as jnp
 
+    cols = X_t if isinstance(X_t, ColumnsT) else None
+    if cols is not None and cols.whole is not None:
+        X_t, cols = cols.whole, None
     is_dev = not isinstance(X_t, np.ndarray)
     xp = jnp if is_dev else np
-    F, n = X_t.shape
+    if cols is not None:
+        # no transposed copy of the matrix: the column statistics a range
+        # of columns at a time, the candidates' rows gathered below
+        F, n = cols.F, cols.n
+        stats = [
+            (np.asarray(jnp.sum(part != 0, axis=1)),
+             np.asarray(jnp.min(part, axis=1)))
+            for _, _, part in cols.chunks()
+        ]
+        nnz = np.concatenate([s[0] for s in stats]).astype(np.int64)
+        mins = np.concatenate([s[1] for s in stats])
+    else:
+        F, n = X_t.shape
     if nnz is None:
         nnz = np.asarray(xp.sum(X_t != 0, axis=1)).astype(np.int64)
     if mins is None:
@@ -688,7 +756,10 @@ def build_bundle_plan(
     # f32 nonzero pattern stays within a fixed memory budget on either
     # backend (budget 0 MUST see every conflict — a sampled estimate could
     # silently bundle conflicting features)
-    Xc = X_t[xp.asarray(cand)] if is_dev else X_t[np.asarray(cand)]
+    if cols is not None:
+        Xc = jnp.transpose(cols.X[:, jnp.asarray(cand)])
+    else:
+        Xc = X_t[xp.asarray(cand)] if is_dev else X_t[np.asarray(cand)]
     # chunk cap 2^22 keeps per-chunk counts exactly representable in f32
     chunk = min(1 << 22, max(8192, (1 << 26) // max(C, 1)))
     conflicts = np.zeros((C, C), np.float64)
@@ -734,14 +805,19 @@ def quantile_bins_device(
     fits max_cnt keep every distinct value (reference:
     SampleByQuantile.java:60-105 — sketch query at even ranks).
 
-    X_t: (F, n) device array. Returns (candidates (F, max_cnt) f32 with
-    possible duplicates, distinct_counts (F,) int) on host; the caller
-    dedupes/finalizes per feature.
+    X_t: (F, n) device array, or a ColumnsT (a range of columns a sort;
+    columns are sorted independently, so the parts are the whole's rows).
+    Returns (candidates (F, max_cnt) f32 with possible duplicates,
+    distinct_counts (F,) int) on host; the caller dedupes/finalizes per
+    feature.
     """
     import jax
     import jax.numpy as jnp
 
-    F, n = X_t.shape
+    if isinstance(X_t, ColumnsT):
+        n, parts = X_t.n, (part for _, _, part in X_t.chunks())
+    else:
+        n, parts = X_t.shape[1], (X_t,)
     mc = spec.max_cnt
     uniform = weight is None or (
         spec.alpha == 0.0
@@ -777,13 +853,19 @@ def quantile_bins_device(
         return cand, distinct
 
     if uniform:
-        cand, distinct = run_uniform(X_t)
+        run = run_uniform
     else:
         w_pow = jnp.asarray(
             np.power(np.maximum(weight, 0.0), spec.alpha).astype(np.float32)
         )
-        cand, distinct = run_weighted(X_t, w_pow)
-    return np.asarray(cand), np.asarray(distinct)
+
+        def run(part):
+            return run_weighted(part, w_pow)
+
+    # a part's candidates are on the host before the next part is sorted
+    out = [tuple(np.asarray(a) for a in run(part)) for part in parts]
+    return (np.concatenate([c for c, _ in out]),
+            np.concatenate([d for _, d in out]))
 
 
 def build_bins_maybe_device(
@@ -821,18 +903,16 @@ def build_bins_maybe_device(
     return _to_feature_bins(per_feature)
 
 
-def bin_matrix_device(X_t_dev, bins: FeatureBins):
-    """Device-side value->bin conversion into the transposed (F, n) layout
-    the growth engine wants (same rule as `bin_matrix`; the compare-count
-    searchsorted fuses on TPU instead of a 28-feature host loop)."""
-    import jax
-    import jax.numpy as jnp
+_BIN_IDS = None  # the jitted value->bin program, made at first use
 
-    values = jnp.asarray(bins.values)  # (F, B)
-    counts = jnp.asarray(bins.counts)  # (F,)
 
-    @jax.jit
-    def run(X_t):
+def _bin_ids():
+    """(X_t (F, n), values (F, B), counts (F,)) -> (F, n) int32 bin ids."""
+    global _BIN_IDS
+    if _BIN_IDS is None:
+        import jax
+        import jax.numpy as jnp
+
         def per_feature(col, v, cnt):
             last = v[cnt - 1]
             # first index with v[i] >= col == count of v[i] < col
@@ -846,9 +926,42 @@ def bin_matrix_device(X_t_dev, bins: FeatureBins):
             i = jnp.where((i >= 1) & (col < mids) & ~over, i - 1, i)
             return jnp.where(over, cnt - 1, i)
 
-        return jax.vmap(per_feature)(X_t, values, counts)
+        _BIN_IDS = jax.jit(jax.vmap(per_feature))
+    return _BIN_IDS
 
-    return run(X_t_dev)
+
+def bin_matrix_device(X_t_dev, bins: FeatureBins, n_pad: int = None,
+                      dtype=None):
+    """Device-side value->bin conversion into the transposed (F, n) layout
+    the growth engine wants (same rule as `bin_matrix`; the compare-count
+    searchsorted fuses on TPU instead of a 28-feature host loop).
+
+    X_t_dev: (F, n) device array -> (F, n) int32 bin ids; or a ColumnsT,
+    binned a range of columns at a time, each range padded with zero rows
+    to `n_pad` and narrowed to `dtype` before the next is read, so that no
+    int32 matrix of all columns exists -> (F, n_pad) `dtype`."""
+    import jax
+    import jax.numpy as jnp
+
+    run = _bin_ids()
+    if not isinstance(X_t_dev, ColumnsT):
+        return run(X_t_dev, jnp.asarray(bins.values), jnp.asarray(bins.counts))
+    cols = X_t_dev
+    parts = []
+    for lo, hi, part in cols.chunks():
+        ids = run(
+            jnp.pad(part, ((0, 0), (0, n_pad - cols.n))),
+            jnp.asarray(bins.values[lo:hi]), jnp.asarray(bins.counts[lo:hi]),
+        ).astype(dtype)
+        if cols.whole is None:
+            # the host runs ahead of the device: unsettled, every range's
+            # transposed, padded and int32 copies would be allocated before
+            # the first is freed (seen as a peak that differed by a range's
+            # bytes from run to run: my chip runs, PR 39)
+            jax.block_until_ready(ids)
+        parts.append(ids)
+        del part, ids
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
 def bin_matrix(X: np.ndarray, bins: FeatureBins) -> np.ndarray:

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.scopes import scope
+from ..obs.scopes import scope, subscope
 from .hist import (
     BMG_DEFAULT,
     compact_indices,
@@ -50,7 +50,7 @@ from .hist import (
     hist_wave_gather,
     tile_bins,
 )
-from .route import route_wave
+from .route import route_kernel_holds, route_wave
 
 BIG32 = np.int32(2**31 - 1)
 
@@ -260,8 +260,9 @@ class GrowSpec:
     # fused kernel's per-row DMA issue loop is O(R) scalar work, so huge
     # budgets would pay more in descriptors than they save in MACs); 0 =
     # every rung takes the XLA gather. `fused_interpret` runs the fused
-    # kernel through the Pallas interpreter in the dense family —
-    # equivalence tests of the REAL kernel logic on the CPU mesh.
+    # kernel through the Pallas interpreter in the dense family, and in the
+    # Pallas family every kernel of the growth program — equivalence tests
+    # of the REAL kernel logic on the CPU mesh.
     fused_max_rows: int = 1 << 18
     fused_interpret: bool = False
     bm_g: int = BMG_DEFAULT
@@ -292,6 +293,27 @@ class GrowSpec:
     def leaf_cap(self) -> int:
         # unlimited -> whatever fits the fixed arrays (nodes = 2*leaves-1)
         return self.max_leaves if self.max_leaves > 0 else (self.max_nodes + 1) // 2
+
+    # What the width decides, from F, B, bm and the family alone (the rungs
+    # below are the trainer's: GBDTTrainer._grow_spec sets `ladder` and
+    # `fused_max_rows` from hist.fused_holds).
+    @property
+    def route(self) -> str:
+        """The family route.route_wave takes: the one-pass kernel where its
+        block holds all F features' bins of bm rows, else (and wherever the
+        family is dense) a bins row a slot on the untiled matrix."""
+        if self.kernels == "pallas" and route_kernel_holds(self.F, self.bm):
+            return "pallas"
+        return "dense"
+
+    @property
+    def packed(self) -> bool:
+        """Whether the full-scan kernel's tiles are hist.tile_bins' packed
+        words: one-byte bins that the routing kernel does not read."""
+        return (
+            self.kernels == "pallas" and self.route == "dense"
+            and self.B <= 256
+        )
 
     def goss_sizes(self, n_full: int) -> Tuple[int, int, int]:
         """GOSS's static sizes over `n_full` (padded, per-shard) rows: (top
@@ -592,11 +614,18 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         # tile once per tree: the Pallas kernels want (F, nblk, 1, bm); done
         # inside the wave loop XLA re-materializes the tiled copy EVERY wave
         # (~10 ms x 20 waves per tree at 10M rows, seen in xprof)
+        # (the routing kernel reads the same tiles; where it does not hold
+        # the width a wave is routed on the untiled matrices, the test rows
+        # are not tiled at all, and one-byte tiles are packed)
         if spec.kernels == "pallas":
-            bins_k = tile_bins(bins_t, spec.bm)
-            aux_k = tuple(tile_bins(bt, spec.bm) for bt in aux)
+            bins_k = tile_bins(bins_t, spec.bm, pack=spec.packed)
         else:
             bins_k = bins_t
+        if spec.route == "pallas":
+            bins_r = bins_k
+            aux_k = tuple(tile_bins(bt, spec.bm) for bt in aux)
+        else:
+            bins_r = bins_t
             aux_k = aux
 
         if spec.precision == "int8":
@@ -635,6 +664,7 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             return hist_wave(
                 bins_in, pos_v, g_v, h_v, ids, B,
                 bm=spec.bm, precision=spec.precision, kernels=spec.kernels,
+                interpret=spec.fused_interpret,
             )
 
         def hist_call(pos_fit, ids):
@@ -655,26 +685,32 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             explicit gather + full-scan kernel (large budgets)."""
 
             def call(pos_fit, ids):
-                mask = jnp.zeros(pos_fit.shape, bool)
-                for k in range(int(ids.shape[0])):  # static width unroll
-                    mask = mask | (pos_fit == ids[k])
-                idx, cnt = compact_indices(mask, R)
-                valid = jnp.arange(R, dtype=jnp.int32) < cnt
-                pg = jnp.where(valid, jnp.take(pos_fit, idx), -1)
-                gg = jnp.take(G_, idx)
-                hg = jnp.take(H_, idx)
-                if impl == "fused":
-                    part = hist_wave_gather(
-                        rows_fused, idx, pg, gg, hg, ids, F, B,
-                        precision=spec.precision, kernels=spec.kernels,
-                        bm_g=spec.bm_g, interpret=spec.fused_interpret,
-                    )
-                    return hist_finish(part)
-                bg = jnp.take(rows_xla, idx, axis=0)  # (R, F) u8
-                bt = jnp.transpose(bg).astype(jnp.int32)
-                if spec.kernels == "pallas":
-                    bt = bt.reshape(F, R // spec.bm, 1, spec.bm)
-                return hist_finish(hist_partial(bt, pg, gg, hg, ids))
+                # a second naming beside the scopes: the pass's kernel stays
+                # under `gbdt.hist`, where hist_wave puts it
+                with subscope("gbdt.hist.part"):
+                    mask = jnp.zeros(pos_fit.shape, bool)
+                    for k in range(int(ids.shape[0])):  # static width unroll
+                        mask = mask | (pos_fit == ids[k])
+                    idx, cnt = compact_indices(mask, R)
+                    valid = jnp.arange(R, dtype=jnp.int32) < cnt
+                    pg = jnp.where(valid, jnp.take(pos_fit, idx), -1)
+                    gg = jnp.take(G_, idx)
+                    hg = jnp.take(H_, idx)
+                    if impl == "fused":
+                        part = hist_wave_gather(
+                            rows_fused, idx, pg, gg, hg, ids, F, B,
+                            precision=spec.precision, kernels=spec.kernels,
+                            bm_g=spec.bm_g, interpret=spec.fused_interpret,
+                        )
+                        return hist_finish(part)
+                    bg = jnp.take(rows_xla, idx, axis=0)  # (R, F) u8
+                    if spec.packed:
+                        bt = tile_bins(jnp.transpose(bg), spec.bm, pack=True)
+                    else:
+                        bt = jnp.transpose(bg).astype(jnp.int32)
+                        if spec.kernels == "pallas":
+                            bt = bt.reshape(F, R // spec.bm, 1, spec.bm)
+                    return hist_finish(hist_partial(bt, pg, gg, hg, ids))
 
             return call
 
@@ -844,14 +880,18 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
 
             # routing (train + any aux sets)
             with scope("gbdt.route"):
-                pos = route_wave(
-                    bins_k, pos, sel_ok, nid, f_best, slot_l, lch, rch,
-                    sel_lo, sel_hi, kernels=spec.kernels, bm=spec.bm,
+                route = partial(
+                    route_wave, kernels=spec.route, bm=spec.bm,
+                    interpret=spec.fused_interpret,
+                )
+                pos = route(
+                    bins_r, pos, sel_ok, nid, f_best, slot_l, lch, rch,
+                    sel_lo, sel_hi,
                 )
                 aux_pos = tuple(
-                    route_wave(
+                    route(
                         bt, ap, sel_ok, nid, f_best, slot_l, lch, rch,
-                        sel_lo, sel_hi, kernels=spec.kernels, bm=spec.bm,
+                        sel_lo, sel_hi,
                     )
                     for bt, ap in zip(aux_k, aux_pos)
                 )

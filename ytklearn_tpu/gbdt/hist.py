@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.scopes import scope
+from .binning import feature_chunk
 
 
 # sample-block width: the Pallas grid's lane-major tile. 16384 measured
@@ -52,29 +53,77 @@ def _pad_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def tile_bins(bins_t, bm: int):
+def tile_bins(bins_t, bm: int, pack: bool = False):
     """(F, n) bin matrix -> (F, n/bm, 1, bm), the layout the full-scan and
     routing kernels block over. 32-bit bins reshape for free. A uint8 matrix
     is widened for the reshape and narrowed after it, behind an optimization
     barrier so XLA cannot fold the pair away: its direct uint8 reshape into
     a shape with a size-1 sublane dim compiles in time proportional to n
     (139 s at 4.2M rows, most of the round program's ~9 min at 10.5M; with
-    the barrier 12 s at 4.2M — TPU v5 lite, libtpu 0.0.34)."""
+    the barrier 12 s at 4.2M — TPU v5 lite, libtpu 0.0.34).
+
+    pack (one-byte bins the routing kernel does not read: GrowSpec.packed):
+    (F, n/bm, 1, bm/4) int32, a word the bins of four rows of its block,
+    byte k of word j the block's row k * bm/4 + j, which `gbdt_hist_scan[_q]`
+    take apart with three shifts into the block's rows in order. A uint8
+    tile with a size-1 sublane dim takes 4 bytes a bin on the device (3.05
+    GiB at 2,000 x 409,600), the words one. The widened copy the words are
+    made from is binning's 4-byte-a-cell copy again, so it keeps to that
+    budget: past WHOLE_BYTES the words are made `feature_chunk` features at
+    a time, one part after the other (a loop, so no two parts' copies are
+    alive together)."""
     F, n = bins_t.shape
-    if bins_t.dtype.itemsize >= 4:
-        return bins_t.reshape(F, n // bm, 1, bm)
-    wide = bins_t.astype(jnp.int32).reshape(F, n // bm, 1, bm)
-    return jax.lax.optimization_barrier(wide).astype(bins_t.dtype)
+    if not pack:
+        if bins_t.dtype.itemsize >= 4:
+            return bins_t.reshape(F, n // bm, 1, bm)
+        wide = bins_t.astype(jnp.int32).reshape(F, n // bm, 1, bm)
+        return jax.lax.optimization_barrier(wide).astype(bins_t.dtype)
+    assert bins_t.dtype.itemsize == 1 and bm % 4 == 0, (bins_t.dtype, bm)
+    nblk, q = n // bm, bm // 4
+
+    def words(part):
+        f = part.shape[0]
+        w = jax.lax.optimization_barrier(part.astype(jnp.int32))
+        w = w.reshape(f, nblk, 4, q)
+        w = w[:, :, 0] | (w[:, :, 1] << 8) | (w[:, :, 2] << 16) | (w[:, :, 3] << 24)
+        return w.reshape(f, nblk, 1, q)
+
+    c = feature_chunk(F, n)
+    if c >= F:
+        return words(bins_t)
+
+    def body(i, out):
+        # the last part starts early rather than run short: same words twice
+        lo = jnp.minimum(i * c, F - c)
+        part = jax.lax.dynamic_slice(bins_t, (lo, 0), (c, n))
+        return jax.lax.dynamic_update_slice(out, words(part), (lo, 0, 0, 0))
+
+    return jax.lax.fori_loop(
+        0, -(-F // c), body, jnp.zeros((F, nblk, 1, q), jnp.int32)
+    )
 
 
-@partial(jax.jit, static_argnames=("B", "bm", "fg", "use_bf16"))
+def _block_bins(bins_ref, fi: int, packed: bool):
+    """One feature's bins of a row block as (1, bm) int32 lanes, from a
+    (1, bm) block of bins or a (1, bm/4) block of tile_bins' packed words."""
+    v = bins_ref[fi, 0, 0, :][None, :]
+    if not packed:
+        return v.astype(jnp.int32)
+    return jnp.concatenate(
+        [v & 255, (v >> 8) & 255, (v >> 16) & 255, (v >> 24) & 255], axis=1
+    )
+
+
+@partial(jax.jit, static_argnames=("B", "bm", "fg", "use_bf16", "interpret"))
 def _hist_pallas(
-    bins4, pos, g, h, node_ids, B: int, bm: int, fg: int, use_bf16: bool
+    bins4, pos, g, h, node_ids, B: int, bm: int, fg: int, use_bf16: bool,
+    interpret: bool = False,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    F, nblk = bins4.shape[0], bins4.shape[1]
+    F, nblk, bw = bins4.shape[0], bins4.shape[1], bins4.shape[3]
+    packed = bw != bm  # tile_bins' words: four rows' bins an int32
     n = nblk * bm
     N = node_ids.shape[0]
     assert F % fg == 0, (F, fg)
@@ -96,7 +145,7 @@ def _hist_pallas(
         PV = jnp.concatenate([P * gv, P * hv, P], axis=0)  # (3N, bm)
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
         for fi in range(fg):
-            b = bins_ref[fi, 0, 0, :][None, :].astype(jnp.int32)  # (1, bm)
+            b = _block_bins(bins_ref, fi, packed)  # (1, bm)
             OH = (iota_b == b).astype(cdt)  # (B, bm)
             acc = jax.lax.dot_general(
                 PV, OH, nt, precision=prec, preferred_element_type=jnp.float32
@@ -115,7 +164,7 @@ def _hist_pallas(
         name="gbdt_hist_scan",
         grid=(F // fg, nblk),
         in_specs=[
-            pl.BlockSpec((fg, 1, 1, bm), lambda fo, k: (fo, k, 0, 0)),
+            pl.BlockSpec((fg, 1, 1, bw), lambda fo, k: (fo, k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
@@ -126,12 +175,16 @@ def _hist_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        interpret=interpret,
     )(bins4, pos3, g3, h3, ids2)
     return out  # (F, 3N, B), rows [g*N | h*N | c*N]
 
 
-@partial(jax.jit, static_argnames=("B", "bm", "fg"))
-def _hist_pallas_q(bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int):
+@partial(jax.jit, static_argnames=("B", "bm", "fg", "interpret"))
+def _hist_pallas_q(
+    bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int,
+    interpret: bool = False,
+):
     """int8 variant: gq/hq are pre-quantized grads as f32 integers in
     [-127, 127] (caller owns the scales); one-hots are exact, dots run at
     2x MXU rate with i32 accumulation (|sum| <= bm*127 per tile, far from
@@ -139,7 +192,8 @@ def _hist_pallas_q(bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    F, nblk = bins4.shape[0], bins4.shape[1]
+    F, nblk, bw = bins4.shape[0], bins4.shape[1], bins4.shape[3]
+    packed = bw != bm
     N = node_ids.shape[0]
     assert F % fg == 0, (F, fg)
     nt = (((1,), (1,)), ((), ()))  # A @ B.T
@@ -162,7 +216,7 @@ def _hist_pallas_q(bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int):
         PV = jnp.concatenate([gv, hv, P], axis=0).astype(jnp.int8)  # (3N, bm)
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
         for fi in range(fg):
-            b = bins_ref[fi, 0, 0, :][None, :].astype(jnp.int32)
+            b = _block_bins(bins_ref, fi, packed)
             OH = (iota_b == b).astype(jnp.int8)  # (B, bm)
             acc = jax.lax.dot_general(
                 PV, OH, nt, preferred_element_type=jnp.int32
@@ -181,7 +235,7 @@ def _hist_pallas_q(bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int):
         name="gbdt_hist_scan_q",
         grid=(F // fg, nblk),
         in_specs=[
-            pl.BlockSpec((fg, 1, 1, bm), lambda fo, k: (fo, k, 0, 0)),
+            pl.BlockSpec((fg, 1, 1, bw), lambda fo, k: (fo, k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
@@ -192,6 +246,7 @@ def _hist_pallas_q(bins4, pos, gq, hq, node_ids, B: int, bm: int, fg: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        interpret=interpret,
     )(bins4, pos3, g3, h3, ids2)
 
 
@@ -234,8 +289,14 @@ def _hist_dense_at(precision: str, bins_t, pos, g, h, node_ids, B: int):
 
 
 def _pick_fg(F: int) -> int:
-    # wider groups amortize the per-step P/PV build further: fg=14 measured
-    # ~12% faster than fg=7 at the Higgs shape (r5, device-loop timing)
+    """Features a grid step of the full-scan kernel: the widest of the
+    listed group widths that divides F. A wider group amortizes the
+    per-step P/PV build and the pos/g/h DMAs over more features; the output
+    block it keeps in VMEM is fg x 3N x B floats (2.75 MB at 14 x 192 x
+    256). The order of the list dates from a retired set-up and has not
+    been re-measured on the v5e; what each width runs today is in PERF.md
+    section 5 (28 columns: 14, a grid of 2 x 641 steps; 2,000 columns: 8,
+    250 x 25 steps; a prime count such as 137: 1). Retuning is ROADMAP A7."""
     for fg in (14, 7, 8, 4, 5, 6, 3, 2):
         if F % fg == 0:
             return fg
@@ -253,17 +314,20 @@ def hist_wave(
     precision: str,
     kernels: str,
     bm: int = BM_DEFAULT,
+    interpret: bool = False,
 ):
     """(N, F, B, 3) histograms for the nodes listed in `node_ids`.
 
     bins_t   (F, n) int32 — transposed bin matrix (n padded to bm), or
-                            pre-tiled (F, n/bm, 1, bm)
+                            pre-tiled (F, n/bm, 1, bm), or tile_bins'
+                            packed words (F, n/bm, 1, bm/4)
     pos      (n,) int32   — tree-node id per sample (-1 or absent = skip)
     g, h     (n,) f32     — weighted grad / hess per sample; at "int8"
                             f32 integers in [-127, 127] (caller's scales)
     node_ids (N,) int32   — node ids to histogram (-2 pads: match nothing)
     precision "bf16" | "f32" (f32 sums) | "int8" (exact int32 sums)
     kernels   "pallas" (Mosaic, the chip) | "dense" (einsum, elsewhere)
+    interpret  the Pallas family through the Pallas interpreter (CPU tests)
     """
     F = bins_t.shape[0]
     N = node_ids.shape[0]
@@ -272,12 +336,13 @@ def hist_wave(
             bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
             if precision == "int8":
                 out = _hist_pallas_q(
-                    bins4, pos, g, h, node_ids, B, bm, _pick_fg(F)
+                    bins4, pos, g, h, node_ids, B, bm, _pick_fg(F),
+                    interpret,
                 )
             else:
                 out = _hist_pallas(
                     bins4, pos, g, h, node_ids, B, bm, _pick_fg(F),
-                    precision == "bf16",
+                    precision == "bf16", interpret,
                 )
         else:
             bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)
@@ -318,6 +383,19 @@ BMG_DEFAULT = 1024  # gathered-tile rows (sublane dim of the NN dot)
 # price is 512 B of HBM per row per 128 features (5.4 GB at 10.5M x 28,
 # where the uint8 matrix is 0.29 GB); each DMA moves one 512 B tile row.
 GATHER_LANES = 128
+
+# What the fused kernel keeps in VMEM whatever the row count: its whole
+# (F, 3N, B) output and the gathered (bm_g, W) tile; and it unrolls a step a
+# feature. 5.5 MB + 0.5 MB at 28 features, 64 nodes, 256 bins; on the v5e
+# (16 MiB of scoped VMEM) Mosaic refuses it from 80 features on at that
+# wave. Past FUSED_VMEM_BYTES no rung is fused (GBDTTrainer._grow_spec).
+FUSED_VMEM_BYTES = 12 << 20
+
+
+def fused_holds(F: int, N: int, B: int, bm_g: int = BMG_DEFAULT) -> bool:
+    """Whether `gbdt_hist_gather` holds F features at N nodes a wave."""
+    held = F * 3 * N * B * 4 + bm_g * _pad_to(F, GATHER_LANES) * 4
+    return held <= FUSED_VMEM_BYTES
 
 
 def gather_table(bins_t):

@@ -28,8 +28,25 @@ import jax.numpy as jnp
 from .hist import tile_bins
 
 
-@partial(jax.jit, static_argnames=("bm",))
-def _route_pallas(bins4, pos, valid, nid, feat, slot, lo, hi, lch, rch, bm: int):
+# The routing kernel is handed every feature's bins of a row block, F x bm
+# cells of 4 bytes in VMEM (a one-byte tile with a size-1 sublane dim takes
+# a word a bin), double-buffered: 3.7 MB at 28 features and bm 16,384; on
+# the v5e (16 MiB of scoped VMEM) Mosaic refuses it from 128 features on.
+# Past ROUTE_VMEM_BYTES a wave is routed by bins row on the untiled matrix
+# (`_route_dense`: 64 row reads of n bytes, whatever F), GrowSpec.route.
+ROUTE_VMEM_BYTES = 12 << 20
+
+
+def route_kernel_holds(F: int, bm: int) -> bool:
+    """Whether `gbdt_route`'s block holds F features."""
+    return 2 * F * bm * 4 <= ROUTE_VMEM_BYTES
+
+
+@partial(jax.jit, static_argnames=("bm", "interpret"))
+def _route_pallas(
+    bins4, pos, valid, nid, feat, slot, lo, hi, lch, rch, bm: int,
+    interpret: bool = False,
+):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -83,6 +100,7 @@ def _route_pallas(bins4, pos, valid, nid, feat, slot, lo, hi, lch, rch, bm: int)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        interpret=interpret,
     )(tab, bins4, pos3).reshape(n)
 
 
@@ -187,10 +205,10 @@ def leaf_values(
 
 def route_wave(
     bins_t, pos, valid, nid, feat, slot, lch, rch, lo, hi, *, kernels: str,
-    bm: int,
+    bm: int, interpret: bool = False,
 ):
     """One wave's routing: the one-pass kernel (kernels="pallas") or its
-    XLA twin ("dense"), as the caller says (hist.hist_wave's field).
+    XLA twin ("dense"), as the caller says (GrowSpec.route).
 
     bins_t: (F, n) or pre-tiled (F, nblk, 1, bm). lo/hi: per-slot EFB
     member-range bounds (see _route_dense)."""
@@ -198,7 +216,7 @@ def route_wave(
         bins4 = bins_t if bins_t.ndim == 4 else tile_bins(bins_t, bm)
         return _route_pallas(
             bins4, pos, valid, nid,
-            jnp.maximum(feat, 0), slot, lo, hi, lch, rch, bm,
+            jnp.maximum(feat, 0), slot, lo, hi, lch, rch, bm, interpret,
         )
     bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(bins_t.shape[0], -1)
     return _route_dense(bins2, pos, valid, nid, feat, slot, lo, hi, lch, rch)

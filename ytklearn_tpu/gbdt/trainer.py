@@ -62,6 +62,7 @@ from ..obs import (
 )
 from ..resilience import chaos_point, trainer_guard
 from .binning import (
+    ColumnsT,
     FeatureBins,
     bin_matrix,
     bin_matrix_device,
@@ -69,6 +70,7 @@ from .binning import (
     build_bins_maybe_device,
     build_bundle_plan,
     bundle_bin_matrix_t,
+    feature_chunk,
 )
 from .data import GBDTData, GBDTIngest, column_stats
 from .engine import (
@@ -77,7 +79,7 @@ from .engine import (
     make_grow_tree,
     wave_log_rows,
 )
-from .hist import BM_DEFAULT, pad_inputs
+from .hist import BM_DEFAULT, fused_holds, pad_inputs
 from .host_engine import train_host
 from .route import leaf_values
 from .tree import (
@@ -109,6 +111,14 @@ log = logging.getLogger("ytklearn_tpu.gbdt")
 # TPU rung ever runs (ledger `breakdown`). ROADMAP S2 re-measures them.
 LADDER = {"pallas": (64, 256), "dense": (8, 32)}
 FUSED_MAX_ROWS = 1 << 18
+# The TPU ladder where the fused kernel does not hold the width
+# (hist.fused_holds: its whole (F, 3N, B) output lives in VMEM): XLA row
+# gathers feeding the full-scan kernel, no fused rung. A gathered pass
+# costs its rows x F whatever the budget's share of n, so the rungs sit
+# where a pass is dearest: n/4 and n/16 (114,688 and 32,768 rows at 409,600
+# x 2,000, where over 20 trees they ran 78 and 2 of 223 passes: PERF.md
+# section 5, my chip runs, PR 39). Not tuned beyond that: ROADMAP A7.
+WIDE_LADDER = (4, 16)
 # The largest tree (GrowSpec.max_nodes) whose end-of-tree leaf lookup the
 # one-pass kernel takes (GrowSpec.leaf_lookup; route.leaf_values): it costs
 # a lane gather and a select per 128 nodes a row, XLA's gather 8.2 ns an
@@ -381,6 +391,11 @@ class GBDTTrainer:
         # where Mosaic can't compile (CPU tests / virtual mesh); mesh>1
         # runs the SAME Pallas kernels per shard under shard_map
         kernels = "pallas" if jax.default_backend() == "tpu" else "dense"
+        # what the width decides, here and in GrowSpec.route / .packed and
+        # nowhere else: whether any rung is fused
+        ladder, fused_max_rows = LADDER[kernels], FUSED_MAX_ROWS
+        if kernels == "pallas" and not fused_holds(F, NW, B):
+            ladder, fused_max_rows = WIDE_LADDER, 0
         return GrowSpec(
             F=F,
             B=B,
@@ -398,8 +413,8 @@ class GBDTTrainer:
             min_split_samples=float(p.min_split_samples),
             precision=self.hist_precision,
             kernels=kernels,
-            ladder=LADDER[kernels],
-            fused_max_rows=FUSED_MAX_ROWS,
+            ladder=ladder,
+            fused_max_rows=fused_max_rows,
             goss_a=self.goss[0],
             goss_b=self.goss[1],
             goss_scale=goss_scale,
@@ -423,12 +438,14 @@ class GBDTTrainer:
             self.mesh is None or self.mesh.devices.size == 1
         ) and jax.process_count() == 1
         if use_dev_bin:
-            X_t_dev = jnp.transpose(jax.device_put(train.X))  # (F, n) real rows
+            # (F, n) real rows: one transposed copy, or past binning's byte
+            # budget a range of columns at a time and no whole copy
+            cols = ColumnsT(train.X)
             bins = build_bins_maybe_device(
-                train.X, X_t_dev, train.weight, p, train.feature_names
+                train.X, cols, train.weight, p, train.feature_names
             )
         else:
-            X_t_dev = None
+            cols = None
             bins = build_bins_global(train.X, train.weight, p, train.feature_names)
         B_real = bins.max_bins
         B = max(8, 1 << (B_real - 1).bit_length())  # pad to pow2 for tiling
@@ -455,7 +472,7 @@ class GBDTTrainer:
             budget = knobs.get_int("YTK_EFB_CONFLICT")
             with obs_span("gbdt.efb.plan", F=F):
                 if use_dev_bin:
-                    plan = build_bundle_plan(X_t_dev, bins, budget, B)
+                    plan = build_bundle_plan(cols, bins, budget, B)
                 else:
                     nnz, mins = column_stats(train.X)
                     plan = build_bundle_plan(
@@ -485,21 +502,20 @@ class GBDTTrainer:
         # right after the replay
         keep_replay = plan is not None and p.model.continue_train
         self._replay_bins = None
+        # one-byte bins quarter the routing and DMA traffic
+        small = jnp.uint8 if B <= 256 else jnp.int32
         if use_dev_bin:
             n_rows = train.X.shape[0]
             n_pad = -(-n_rows // BM_DEFAULT) * BM_DEFAULT
-            Xp = jnp.pad(X_t_dev, ((0, 0), (0, n_pad - n_rows)))
-            bins_t_raw = bin_matrix_device(Xp, bins)
+            bins_t_raw = bin_matrix_device(cols, bins, n_pad=n_pad, dtype=small)
             bins_t = (
                 bundle_bin_matrix_t(bins_t_raw, plan)
                 if plan is not None
                 else bins_t_raw
             )
-            if B <= 256:
-                bins_t = bins_t.astype(jnp.uint8)  # quarter the routing/DMA
             if keep_replay:
                 self._replay_bins = [jnp.transpose(bins_t_raw)]
-            del X_t_dev, Xp, bins_t_raw
+            del cols, bins_t_raw
         else:
             bins_np_raw = bin_matrix(train.X, bins)
             if plan is not None:
@@ -533,21 +549,18 @@ class GBDTTrainer:
             if use_dev_bin:
                 nt = test.X.shape[0]
                 nt_pad = -(-nt // BM_DEFAULT) * BM_DEFAULT
-                Xt_t = jnp.pad(
-                    jnp.transpose(jax.device_put(test.X)), ((0, 0), (0, nt_pad - nt))
+                bt_raw = bin_matrix_device(
+                    ColumnsT(test.X), bins, n_pad=nt_pad, dtype=small
                 )
-                bt_raw = bin_matrix_device(Xt_t, bins)
                 bt_dev = (
                     bundle_bin_matrix_t(bt_raw, plan)
                     if plan is not None
                     else bt_raw
                 )
-                if B <= 256:
-                    bt_dev = bt_dev.astype(jnp.uint8)
                 aux_bins = (bt_dev,)
                 if keep_replay:
                     self._replay_bins.append(jnp.transpose(bt_raw))
-                del Xt_t, bt_dev, bt_raw
+                del bt_dev, bt_raw
             else:
                 bins_test_raw = bin_matrix(test.X, bins)
                 if plan is not None:
@@ -678,6 +691,7 @@ class GBDTTrainer:
             kernels=spec.leaf_lookup(LEAF_KERNEL_MAX_NODES),
             bm=spec.bm,
             mesh=self.mesh if dd.D > 1 else None,
+            interpret=spec.fused_interpret,
         )
 
         def round_step(carry, rnd, key, data):
@@ -827,6 +841,23 @@ class GBDTTrainer:
         ts["leaf_lookup_kernel"] = (
             spec.leaf_lookup(LEAF_KERNEL_MAX_NODES) == "pallas"
         )
+        # what the width chose (_grow_spec, GrowSpec.route / .packed), and
+        # what it costs in memory, from shapes
+        ts["features"] = int(F)
+        ts["hist_pool_bytes"] = int(
+            spec.max_nodes * (F // max(dd.D, 1)) * B * 3 * 4
+        )
+        ts["route_kernel"] = spec.route == "pallas"
+        ts["packed_tiles"] = bool(spec.packed)
+        ts["rungs_fused"] = sum(impl == "fused" for _, impl in rungs)
+        ts["rungs_xla"] = sum(impl == "xla" for _, impl in rungs)
+        # the partitioned passes: those that scanned a budget, not the fit
+        # rows their tree's root pass scanned
+        part = used & (wl[..., 0] < wl[:, :1, 0])
+        ts["trees_logged"] = n_trees
+        ts["hist_part_passes"] = float(part.sum())
+        ts["hist_part_rows_scanned"] = float((wl[..., 0] * part).sum())
+        ts["hist_part_rows_needed"] = float((wl[..., 1] * part).sum())
         ts["goss"] = goss_on
         if goss_on:
             ts["goss_a"] = float(spec.goss_a)
@@ -1001,6 +1032,9 @@ class GBDTTrainer:
         with profiler.phase(
             "gbdt.preprocess", settle=lambda: dd.device_arrays(),
             F=train.n_features,
+            # ranges of columns the device-side binning goes through
+            chunks=-(-train.n_features
+                     // feature_chunk(train.n_features, train.X.shape[0])),
         ):
             dd = self._prep_device_inputs(train, test)
         health.record_memory("gbdt.preprocess")
@@ -1398,8 +1432,8 @@ class GBDTTrainer:
         n_real = getattr(train, "n_real", None) or train.X.shape[0]
         with obs_span("gbdt.quality_sketch", features=len(names)):
             return build_training_sketch(
-                np.asarray(train.X[:n_real]), names,
-                weight=np.asarray(train.weight[:n_real]),
+                train.X, names, weight=np.asarray(train.weight[:n_real]),
+                rows=n_real,
             )
 
     def _stash_quality_scores(self, scores, weight) -> None:

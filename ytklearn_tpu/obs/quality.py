@@ -271,25 +271,41 @@ def _stride_sample(n: int, cap: int = TRAIN_SKETCH_ROWS) -> np.ndarray:
     return np.arange(0, n, max(1, n // cap))[:cap]
 
 
+# host threads of the per-column summaries (numpy's sort and searchsorted
+# release the GIL); one thread gives the same payload (tests/test_quality.py)
+SKETCH_THREADS = 8
+
+
 def build_training_sketch(
     X: np.ndarray,
     feature_names: Sequence[str],
     weight: Optional[np.ndarray] = None,
     preds: Optional[np.ndarray] = None,
     b: Optional[int] = None,
+    rows: Optional[int] = None,
+    threads: int = SKETCH_THREADS,
 ) -> dict:
     """The `<model>.sketch.json` payload: per-feature pruned GK summaries
     + presence rates over a deterministic row subsample of the training
     matrix, plus the (held-out, when the trainer has one) score
-    distribution. numpy-only — runs once per dump on the host."""
+    distribution. numpy-only — runs once per dump on the host.
+
+    X may live on the device: only the sampled rows are pulled, once, and
+    transposed once so that a column is contiguous. `rows` keeps the sample
+    to the first `rows` rows (a trainer's real rows) without a copy of the
+    matrix."""
     if b is None:
         b = knobs.get_int("YTK_QUALITY_B")
     n, F = X.shape
+    if rows is not None:
+        n = min(n, int(rows))
     idx = _stride_sample(n)
     w = None if weight is None else np.asarray(weight, np.float64)[idx]
-    features: Dict[str, dict] = {}
-    for f in range(min(F, len(feature_names))):
-        col = np.asarray(X[idx, f], np.float64)
+    F = min(F, len(feature_names))
+    cols = np.ascontiguousarray(np.asarray(X[idx])[:, :F].T)  # (F, sampled)
+
+    def summarize(f: int) -> dict:
+        col = cols[f].astype(np.float64)
         finite = np.isfinite(col)
         present = float(np.mean(finite)) if len(col) else 0.0
         vals = col[finite]
@@ -297,10 +313,21 @@ def build_training_sketch(
         sk = WeightedQuantileSketch(b=b)
         if len(vals):
             sk.push(vals, wv)
-        features[str(feature_names[f])] = {
+        return {
             "present": round(present, 6),
             "summary": summary_to_json(prune_summary(sk.summary(), b)),
         }
+
+    if threads > 1 and F > 1:
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(max_workers=min(threads, F)) as pool:
+            blocks = list(pool.map(summarize, range(F)))
+    else:
+        blocks = [summarize(f) for f in range(F)]
+    features: Dict[str, dict] = {
+        str(feature_names[f]): blocks[f] for f in range(F)
+    }
     payload = {
         "schema": QUALITY_SCHEMA,
         "version": 1,

@@ -26,6 +26,14 @@ The installed profiler does not carry `op_name` to the op-line events (an
 event is named by its HLO text and has three timing stats), which is why
 the map is taken at compile time.
 
+  subscope(name)     a second naming beside the scopes, for a part of a
+                     scope that a reader wants apart WITHOUT taking it out
+                     of the scope (`gbdt.hist.part`: the partitioned
+                     histogram passes, which stay under `gbdt.hist`). It has
+                     a map of its own (`subscope_map()`, one `subscope_map`
+                     event a module that has any); the scope map does not
+                     know it.
+
 JAX's persistent compile cache keys a program by its operations with the
 debug information stripped, `op_name` included: a program that differs from
 a cached one only in its scopes is served the cached executable, whose
@@ -34,7 +42,8 @@ process that renamed a scope read the first one's name back). So
 `compile_lowered` makes the scopes part of the program: which scope every
 operation lies under, in program order, is hashed into a module attribute
 (`mhlo.frontend_attributes {ytk_scopes}`), which the cache key does cover.
-A program under no scope is left as it was.
+A program under no scope is left as it was; an operation's subscope is
+hashed with its scope, and a program with no subscope hashes as before.
 """
 
 from __future__ import annotations
@@ -50,7 +59,9 @@ from . import core
 
 _lock = threading.Lock()
 _SCOPES: set = set()
+_SUBSCOPES: set = set()
 _MAPS: Dict[str, Dict[str, str]] = {}
+_SUBMAPS: Dict[str, Dict[str, str]] = {}
 
 _MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)")
 _INSTR_RE = re.compile(
@@ -64,21 +75,36 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
-def innermost_scope(op_name: str) -> Optional[str]:
-    """The registered scope that lies deepest in an `op_name` path
+def subscope(name: str):
+    """`with subscope("gbdt.hist.part"): ...`: see the module docstring."""
+    _SUBSCOPES.add(name)
+    return jax.named_scope(name)
+
+
+def innermost_scope(op_name: str, names=None) -> Optional[str]:
+    """The registered scope (or the name of `names`) that lies deepest in
+    an `op_name` path
     (`jit(iteration)/while/body/transpose(jvp(fm.gather_v))/scatter-add`
     -> `fm.gather_v`), or None."""
     best, at = None, -1
-    for name in _SCOPES:
+    for name in _SCOPES if names is None else names:
         for m in re.finditer(r"(?<![\w.])" + re.escape(name) + r"(?![\w.])", op_name):
             if m.start() > at:
                 best, at = name, m.start()
     return best
 
 
-def parse_hlo(text: str):
-    """(module name, {instruction name: scope}) of one compiled HLO text."""
-    module, ops = None, {}
+def parse_hlo(text: str, names=None):
+    """(module name, {instruction name: scope}) of one compiled HLO text;
+    with `names`, by those names instead of the registered scopes."""
+    module, (ops,) = _parse_hlo(text, (_SCOPES if names is None else names,))
+    return module, ops
+
+
+def _parse_hlo(text: str, name_sets):
+    """One pass over a compiled HLO text (the round program's is megabytes):
+    (module name, one {instruction name: name} map a set of names)."""
+    module, maps = None, [{} for _ in name_sets]
     for line in text.splitlines():
         if module is None:
             m = _MODULE_RE.match(line)
@@ -87,10 +113,11 @@ def parse_hlo(text: str):
                 continue
         m = _INSTR_RE.match(line)
         if m:
-            sc = innermost_scope(m.group(2))
-            if sc is not None:
-                ops[m.group(1)] = sc
-    return module, ops
+            for names, ops in zip(name_sets, maps):
+                sc = innermost_scope(m.group(2), names) if names else None
+                if sc is not None:
+                    ops[m.group(1)] = sc
+    return module, maps
 
 
 _LOC_NAME = re.compile(r'^loc\("([^"]*)"')
@@ -107,7 +134,9 @@ def _scope_digest(module) -> Optional[str]:
         m = _LOC_NAME.match(str(op.location))
         name = m.group(1) if m else ""
         if name not in seen:
-            seen[name] = innermost_scope(name) or "-"
+            sub = innermost_scope(name, _SUBSCOPES) if _SUBSCOPES else None
+            seen[name] = (innermost_scope(name) or "-") + (
+                "" if sub is None else "|" + sub)
         marks.append(seen[name])
         for region in op.regions:
             for block in region.blocks:
@@ -139,11 +168,16 @@ def compile_lowered(lowered):
                 attrs["mhlo.frontend_attributes"] = ir.DictAttr.get(front)
     compiled = lowered.compile()
     if core.enabled() and _SCOPES:
-        name, ops = parse_hlo(compiled.as_text())
+        name, (ops, sub) = _parse_hlo(
+            compiled.as_text(), (_SCOPES, _SUBSCOPES))
         if name is not None:
             with _lock:
                 _MAPS[name] = ops
             core.event("scope_map", module=name, ops=ops)
+            if sub:
+                with _lock:
+                    _SUBMAPS[name] = sub
+                core.event("subscope_map", module=name, ops=sub)
     return compiled
 
 
@@ -152,6 +186,13 @@ def scope_map() -> Dict[str, Dict[str, str]]:
     so far with obs on."""
     with _lock:
         return {m: dict(ops) for m, ops in _MAPS.items()}
+
+
+def subscope_map() -> Dict[str, Dict[str, str]]:
+    """module name -> instruction name -> subscope, of the programs compiled
+    so far with obs on that have an operation under one."""
+    with _lock:
+        return {m: dict(ops) for m, ops in _SUBMAPS.items()}
 
 
 def _signature(args) -> tuple:
