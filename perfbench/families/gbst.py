@@ -10,16 +10,21 @@ a tree's first evaluation and, for each iteration, the trials its
 their seconds stay in the window. The recorder wraps `boost.minimize_lbfgs`,
 lives across trees and changes nothing the fits do.
 
-The window holds whole trees. A tree boundary is the moment the callback of a
-tree's first evaluation has returned: every earlier tree is fitted, folded
-and dumped, this tree's masks are drawn and its weights re-drawn, and the
-device is drained (the trainer has read the first evaluation's norms). Set-up
-ends at the boundary of tree `warm_trees` (at least 1: tree 0, its fold and
-tree 1's first evaluation, which `correct` compares, are set-up); the window
-closes at the first tree boundary at or after `--seconds`. So every window
-holds as many fits as folds, dumps and mask draws, and two runs differ by
-whole trees only. The iteration boundaries in between go to `run.boundary`
-for placing a stall and open or close nothing.
+The window holds a fixed set of whole trees, whatever the clock. A tree
+boundary is the moment the callback of a tree's first evaluation has
+returned: every earlier tree is fitted, folded and dumped, this tree's masks
+are drawn and its weights re-drawn, and the device is drained (the trainer
+has read the first evaluation's norms). Set-up ends at the boundary of tree
+`warm_trees` (at least 1: tree 0, its fold and tree 1's first evaluation,
+which `correct` compares, are set-up); the window closes at the boundary of
+tree `warm_trees + window_trees` (`warm_trees + trace_trees` in a traced run,
+whose profiler keeps only so many device events), not at a boundary set by
+`--seconds`. So every run holds the same fits, folds, dumps and mask draws,
+and fits the same trees in all: the job's failed searches, which grow with
+the trees a job holds (from about the sixth tree on every fit ends in one),
+are the program's to change, not the clock's. A faster program has a
+shorter window over the same work. The iteration boundaries in between go to
+`run.boundary` for placing a stall and open or close nothing.
 
 What a window held is counted here and printed in the result line
 (`window`): `passes`, `iterations` (line searches that ended well), `trees`,
@@ -35,10 +40,11 @@ the window's seconds and in none of its steps.
 The stop. Answering the callback with "stop" ends one tree's fit, not the
 job: `boost.py` folds that tree and starts the next. So the job is ended the
 way a user's is: once the window has closed and the clock is read, at that
-same tree boundary (tree 2's or a later one's: tree 1's first evaluation is
-compared), the recorder raises SIGTERM, which the trainer's preemption guard
-defers to the next tree boundary, and answers "stop"; the trainer folds and
-dumps the tree it was on and leaves through `Preempted`.
+same boundary of tree `warm_trees + window_trees`, the recorder raises
+SIGTERM, which the trainer's preemption guard defers to the next tree
+boundary, and answers "stop"; the trainer folds and dumps the tree it was
+on, that tree stopped after its first evaluation, and leaves through
+`Preempted` before any later tree is begun.
 """
 
 from __future__ import annotations
@@ -159,6 +165,9 @@ def train(run, overrides: dict) -> dict:
     if warm < 1:
         raise SystemExit("perfbench: warm_trees under 1: tree 0, its fold and tree 1's "
                          "first evaluation are compared and belong to set-up")
+    held = int(run.cell.traffic["trace_trees" if run.trace_on else "window_trees"])
+    if held < 1:
+        raise SystemExit("perfbench: a window of under one tree (window_trees, trace_trees)")
     follow = int(run.cell.config["compare"]["follow_iterations"])
     obs.configure(enabled=True)
     obs.health.install_trace_counters()
@@ -210,12 +219,13 @@ def train(run, overrides: dict) -> dict:
             run.boundary(rec.passes)
             if it != 0:
                 return stop
-            # a tree boundary: the only place the window opens or closes
+            # a tree boundary, trees [0, tree) done: the only place the
+            # window opens or closes
             if tree == warm:
-                run.open_window(rec.passes)
+                run.open_window(rec.passes, work=held, unit="trees", units_done=tree)
                 rec.at_open = rec.counts(tree)
-            elif run.window is not None and run.window.due(time.perf_counter()):
-                run.close_window(rec.passes)
+            elif run.window is not None and run.window.due(time.perf_counter(), tree):
+                run.close_window(rec.passes, units_done=tree)
                 rec.at_close = rec.counts(tree)
             if rec.at_close is not None and not rec.stopped:
                 end_job()

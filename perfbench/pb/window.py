@@ -7,6 +7,11 @@ boundary after set-up, lasts at least `seconds`, and closes at the first
 boundary at or after that. The rate is every step between the two boundaries
 over all the time between them: stalls, host callbacks and syncs included. No
 step is skipped and no reading is a median of parts.
+
+A window of fixed work (`work`, counted in the family's `unit`, such as trees)
+closes at the first boundary at which that much more work is done, whatever
+the clock: it holds the same work on every run, and a faster program has a
+shorter window over it. `seconds` is then only what the run was asked for.
 """
 
 from __future__ import annotations
@@ -18,29 +23,42 @@ from typing import Optional, Sequence, Tuple
 @dataclasses.dataclass
 class Window:
     seconds: float
+    work: Optional[float] = None  # a window of fixed work: this many `unit`s
+    unit: str = "seconds"  # what closes the window: the clock, or the work's unit
     t_open: Optional[float] = None
     steps_open: float = 0.0
+    units_open: float = 0.0
     t_close: Optional[float] = None
     steps_close: float = 0.0
-    exhausted: bool = False  # training ended by itself before `seconds`
+    exhausted: bool = False  # training ended by itself before the window was due
 
-    def open(self, t: float, steps_done: float) -> None:
+    def open(self, t: float, steps_done: float, units_done: float = 0.0) -> None:
         if self.t_open is not None:
             raise RuntimeError("window opened twice")
         self.t_open, self.steps_open = float(t), float(steps_done)
+        self.units_open = float(units_done)
 
     @property
     def is_open(self) -> bool:
         return self.t_open is not None and self.t_close is None
 
-    def due(self, t: float) -> bool:
-        """True once a boundary at time `t` may close the window."""
-        return self.is_open and t - self.t_open >= self.seconds
+    def due(self, t: float, units_done: float = 0.0) -> bool:
+        """True once a boundary at time `t`, with `units_done` units of work
+        done since the job began, may close the window."""
+        if not self.is_open:
+            return False
+        if self.work is not None:
+            return units_done - self.units_open >= self.work
+        return t - self.t_open >= self.seconds
 
-    def close(self, t: float, steps_done: float, exhausted: bool = False) -> None:
+    def close(self, t: float, steps_done: float, exhausted: bool = False,
+              units_done: float = 0.0) -> None:
         if not self.is_open:
             raise RuntimeError("window is not open")
-        if not exhausted and t - self.t_open < self.seconds:
+        if not exhausted and not self.due(t, units_done):
+            if self.work is not None:
+                raise RuntimeError(f"window closed after {units_done - self.units_open:g} "
+                                   f"{self.unit}, before {self.work:g}")
             raise RuntimeError(
                 f"window closed after {t - self.t_open:.3f}s, before {self.seconds}s"
             )
@@ -48,6 +66,11 @@ class Window:
             raise RuntimeError("steps ran backwards")
         self.t_close, self.steps_close = float(t), float(steps_done)
         self.exhausted = exhausted
+
+    @property
+    def closed_by(self) -> str:
+        """What closed the window: its unit, or the job's end."""
+        return "job_end" if self.exhausted else self.unit
 
     @property
     def length_s(self) -> float:
@@ -58,8 +81,9 @@ class Window:
         return self.steps_close - self.steps_open
 
     @property
-    def overshoot_s(self) -> float:
-        return self.length_s - self.seconds
+    def overshoot_s(self) -> Optional[float]:
+        """Seconds past `seconds`; none for a window of fixed work."""
+        return None if self.work is not None else self.length_s - self.seconds
 
     def rate(self, work_per_step: float = 1.0) -> float:
         if self.steps <= 0 or self.length_s <= 0:

@@ -123,9 +123,12 @@ class Run:
         self.attempted = self.failed = 0
 
     # -- called by the family adapter ---------------------------------------
-    def open_window(self, steps_done: float) -> None:
+    def open_window(self, steps_done: float, work: float = None, unit: str = "seconds",
+                    units_done: float = 0.0) -> None:
         """Set-up is over: snapshot the counters, start the profiler in a
-        traced run, and open the window last of all."""
+        traced run, and open the window last of all. With `work` the window
+        holds that many more `unit`s (`units_done` are done now), whatever
+        the clock (pb/window.py)."""
         import jax
 
         from pb.window import Window
@@ -135,10 +138,10 @@ class Run:
         if self.trace_on:
             shutil.rmtree(self.trace_dir, ignore_errors=True)
             jax.profiler.start_trace(self.trace_dir)
-        self.window = Window(self.seconds)
+        self.window = Window(self.seconds, work, unit)
         now = time.perf_counter()
         self.setup_s = now - T_PROCESS
-        self.window.open(now, steps_done)
+        self.window.open(now, steps_done, units_done)
         self.probe = HostProbe()
 
     def boundary(self, steps: float) -> None:
@@ -148,13 +151,14 @@ class Run:
             self.boundaries.append(
                 [round(time.perf_counter() - self.window.t_open, 4), steps])
 
-    def close_window(self, steps_done: float, exhausted: bool = False) -> None:
+    def close_window(self, steps_done: float, exhausted: bool = False,
+                     units_done: float = 0.0) -> None:
         """At a boundary with the device drained."""
         import jax
 
         from ytklearn_tpu import obs
 
-        self.window.close(time.perf_counter(), steps_done, exhausted)
+        self.window.close(time.perf_counter(), steps_done, exhausted, units_done)
         self.facts.update(self.probe.close(), boundaries=self.boundaries)
         if self.trace_on:
             jax.profiler.stop_trace()
@@ -235,7 +239,9 @@ def drive(cell, seed: int, seconds: float, trace: bool, device: dict,
     if run.window is None or run.window.t_close is None:
         raise SystemExit("perfbench: the family handed back no closed window")
     if trace:
-        run.trace = xplane.summarize(xplane.find_xplane(run.trace_dir), cell.chips)
+        path = xplane.find_xplane(run.trace_dir)
+        run.trace = xplane.summarize(path, cell.chips)
+        run.facts["xplane_mb"] = os.path.getsize(path) / 1e6  # the profiler keeps ~310
     run.checks = family.compare(run, state)  # reference: after the window
     if after is not None:
         after(run, state)
@@ -248,7 +254,8 @@ def drive(cell, seed: int, seconds: float, trace: bool, device: dict,
     }
     print("perfbench window: " + json.dumps({
         "seconds": run.window.length_s, "steps": run.window.steps,
-        "asked_s": seconds, "overshoot_s": run.window.overshoot_s,
+        "asked_s": seconds, "closed_by": run.window.closed_by,
+        "overshoot_s": run.window.overshoot_s,
         "exhausted": run.window.exhausted, "setup_s": run.setup_s,
         "compiles_in_window": run.counters_window.get("compile.traces.backend_compile", 0.0),
         **run.facts,

@@ -1,9 +1,10 @@
 """The `gbmlr_higgs.train` cell's self-checks on the CPU, as `test_ffm.py`
 keeps them for the cell before it: the program as configured comes out
 `correct` at a tiny size against the committed limits (60 iterations a tree,
-as the configuration states); the window opens and closes on tree boundaries
-and the result line says what it held; a fit that ends in a failed line
-search is counted and named; each planted fault
+as the configuration states); the window opens and closes on tree boundaries,
+holds `window_trees` trees whatever `--seconds` says, and the result line
+says what it held; a fit that ends in a failed line search is counted and
+named, in the window's last tree too; each planted fault
 (the skipped fold among them) and the bfloat16 control put in the program's
 place come out not correct; the window's passes are the program's own count;
 the stop through the preemption guard leaves no thread or handler behind;
@@ -45,8 +46,8 @@ def files_of_its_own(tmp_path, monkeypatch):
     monkeypatch.setattr(harness, "WORK_DIR", str(tmp_path / "work"))
 
 
-def drive(fault=None, after=None, seed=2147483659, seconds=0.2):
-    cell = tiny.tiny_cell(NAME, SIZES)
+def drive(fault=None, after=None, seed=2147483659, seconds=0.2, window_trees=1):
+    cell = tiny.tiny_cell(NAME, SIZES, traffic={"window_trees": window_trees})
     cell.config["compare"]["reference_block_rows"] = 2048
     family = manifest.load_module("families", cell.config["family"])
     mend = family.plant(fault) if fault else None
@@ -100,12 +101,12 @@ def test_the_window_holds_whole_trees_and_the_result_says_what_it_held():
         got.update(window=run.window, facts=dict(run.facts), rec=state["rec"],
                    counters=dict(run.counters_window), boundaries=list(run.boundaries))
 
-    res = drive(after=after, seconds=0.5)
+    res = drive(after=after, seconds=0.5, window_trees=2)
     rec, w, held = got["rec"], got["window"], res["window"]
     assert set(held) == COUNTS and res["correct"]
-    # opened at tree 1's boundary (warm_trees), closed at a later tree's
+    # opened at tree 1's boundary (warm_trees), closed at tree 3's
     first, last = got["facts"]["trees_held"]
-    assert first == 1 and last >= 2 and held["trees"] == last - first
+    assert (first, last) == (1, 3) and held["trees"] == last - first
     assert held["trees"] == got["counters"]["gbst.trees"]  # the program's own count
     # both boundaries are first evaluations: the passes between them are the
     # whole fits of trees [first, last) and the next tree's first evaluation
@@ -118,7 +119,39 @@ def test_the_window_holds_whole_trees_and_the_result_says_what_it_held():
     assert res["attempted"] == sum(f["iters"] for f in rec.fits)
     # the last boundary the family noted inside the window is the close itself
     assert got["boundaries"][-1][1] == w.steps_close
-    assert not w.exhausted and w.length_s >= 0.5
+    # closed by the trees it holds, not by the 0.5 s it was asked for
+    assert not w.exhausted and w.closed_by == "trees" and w.length_s > 0
+    assert w.overshoot_s is None
+
+
+@pytest.fixture(scope="module")
+def runs_by_seconds():
+    """seconds -> (result line, what the run saw), each run made once."""
+    return {}
+
+
+def held_at(runs: dict, seconds: float):
+    if seconds not in runs:
+        got = {}
+
+        def after(run, state):
+            got.update(facts=dict(run.facts), closed_by=run.window.closed_by)
+
+        runs[seconds] = (drive(after=after, seconds=seconds, window_trees=2), got)
+    return runs[seconds]
+
+
+@pytest.mark.parametrize("seconds,other", [(0.0, 1e9), (1e9, 0.0)])
+def test_the_window_holds_its_trees_whatever_the_clock(runs_by_seconds, seconds, other):
+    """A window of no seconds and one of 1e9 seconds hold the same trees 1-2
+    and the job the same fits, searches and failures."""
+    res, got = held_at(runs_by_seconds, seconds)
+    res_other, got_other = held_at(runs_by_seconds, other)
+    assert res["correct"] and got["closed_by"] == "trees"
+    assert got["facts"]["trees_held"] == [1, 3] == got_other["facts"]["trees_held"]
+    assert res["window"]["trees"] == 2
+    for key in ("window", "failed", "attempted"):
+        assert res[key] == res_other[key], key
 
 
 def test_a_fit_that_ends_in_a_failed_search_is_counted_and_named(monkeypatch):
@@ -145,10 +178,43 @@ def test_a_fit_that_ends_in_a_failed_search_is_counted_and_named(monkeypatch):
     assert res["correct"]  # a failed search is counted, not judged
 
 
+def test_a_failed_search_in_the_last_tree_of_the_window_counts_once(monkeypatch):
+    """Trees 1 and 2 are the window; tree 2's fit comes back failed. Tree 3
+    is stopped after its first evaluation and no later tree is begun."""
+    import dataclasses
+
+    import ytklearn_tpu.boost as boost_mod
+
+    real, n = boost_mod.minimize_lbfgs, {"fits": 0}
+
+    def minimize(*a, **kw):
+        res, n["fits"] = real(*a, **kw), n["fits"] + 1
+        if n["fits"] == 3:  # tree 2
+            return dataclasses.replace(res, status="line_search_failed(-1)")
+        return res
+
+    monkeypatch.setattr(boost_mod, "minimize_lbfgs", minimize)
+    got = {}
+    res = drive(after=lambda run, state: got.update(run.facts), seconds=0.0, window_trees=2)
+    assert res["failed"] == 1 and got["failed_trees"] == [2]
+    assert res["window"]["failed_searches"] == 1 and res["window"]["trees"] == 2
+    assert got["trees_held"] == [1, 3]
+    assert got["trees_started"] == 4 and got["iterations_a_tree"][3] == 0
+    assert res["attempted"] == res["failed"] + sum(got["iterations_a_tree"])
+    assert res["correct"]
+
+
 def test_a_window_needs_tree_0_in_set_up():
     cell = tiny.tiny_cell(NAME, SIZES, traffic={"warm_trees": 0})
     with pytest.raises(SystemExit, match="warm_trees"):
         harness.drive(cell, 2147483659, 0.2, False, tiny.CPU_DEVICE)
+
+
+@pytest.mark.parametrize("trace,key", [(False, "window_trees"), (True, "trace_trees")])
+def test_a_window_holds_a_tree_at_least(trace, key):
+    cell = tiny.tiny_cell(NAME, SIZES, traffic={key: 0})
+    with pytest.raises(SystemExit, match="under one tree"):
+        harness.drive(cell, 2147483659, 0.2, trace, tiny.CPU_DEVICE)
 
 
 @pytest.mark.parametrize(

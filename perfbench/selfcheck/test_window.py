@@ -47,6 +47,25 @@ def test_training_that_ends_first_is_the_whole_phase():
     assert w.exhausted and w.steps == 4 and w.length_s == pytest.approx(4.0)
 
 
+def test_a_window_of_work_closes_on_work_whatever_its_length():
+    w = Window(30.0, work=12, unit="trees")
+    w.open(5.0, 1, units_done=1)
+    assert not w.due(1e9, units_done=12) and w.due(5.001, units_done=13)
+    with pytest.raises(RuntimeError, match="11 trees, before 12"):
+        w.close(1e9, 380, units_done=12)  # a long window, a tree short
+    w.close(5.5, 432, units_done=13)  # far short of 30 s
+    assert w.closed_by == "trees" and w.steps == 431 and w.length_s == pytest.approx(0.5)
+    assert w.overshoot_s is None and not w.exhausted
+    # a window of the clock still refuses to close early, whatever work is done
+    t = Window(30.0)
+    t.open(0.0, 0)
+    assert not t.due(29.9, units_done=100)
+    with pytest.raises(RuntimeError, match="before 30.0s"):
+        t.close(29.9, 10, units_done=100)
+    t.close(30.0, 10)
+    assert t.closed_by == "seconds" and t.overshoot_s == 0.0
+
+
 def test_a_window_cannot_close_early_or_twice():
     w = Window(10.0)
     w.open(0.0, 3)

@@ -3,7 +3,8 @@ the distance between the first and third quartile
 (`statistics.quantiles(values, n=4)`) as a share of the median, for each
 metric in each labelled set. Before them one line a run: its rate beside
 what its window held (the result line's `window`, where a family counts it),
-so that two runs of unlike rates can be told apart by their work.
+so that two runs of unlike rates can be told apart by their work, its length
+and what closed it.
 
     python3 perfbench/tools/spread.py chiprun_out/sets/<cell>.jsonl
 """
@@ -24,9 +25,11 @@ def main() -> int:
             continue
         bad += 0 if res["correct"] else 1
         rates = [v["value"] for k, v in res["metrics"].items() if "_per_s" in k]
+        win = rec.get("window") or {}
         print(f"{rec['label']:12s} seed {rec['seed']} rate {rates[0] if rates else None} "
               f"correct {res['correct']} failed {res['failed']} of {res['attempted']} "
-              f"window {json.dumps(res.get('window'))}")
+              f"window {json.dumps(res.get('window'))} {win.get('seconds')} s "
+              f"closed by {win.get('closed_by')}")
         for k, v in res["metrics"].items():
             sets[rec["label"]][k].append(v["value"])
         sets[rec["label"]]["_wall_s"].append(rec["wall_s"])
