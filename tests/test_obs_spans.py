@@ -186,6 +186,9 @@ def test_lbfgs_passes_counts_every_line_search_trial(obs_on):
     its = [e for e in _spans() if e["name"] == "lbfgs.iteration"]
     assert [e["step"] for e in its] == list(range(1, len(statuses) + 1))
     assert [e["args"]["passes"] for e in its] == [abs(s) for s in statuses]
+    # a search that succeeds reports its trials as its status
+    assert [e["args"]["trials"] for e in its] == statuses
+    assert [e["args"]["status"] for e in its] == statuses
     first = [e for e in _spans() if e["name"] == "lbfgs.first_eval"]
     assert len(first) == 1 and "step" not in first[0]
 

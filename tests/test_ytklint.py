@@ -1503,6 +1503,31 @@ def test_metric_doc_sync_both_ways(tmp_path, monkeypatch):
     assert any("markers" in p for p in flow.check_doc_sync(bare, census))
 
 
+def test_the_census_reads_the_benchmarks_readers(monkeypatch):
+    """The benchmark reads the program's names from files that are not
+    linted: the census takes their references, and every one of them names
+    something the program makes (a config key or a file name in a path
+    call is no metric)."""
+    import pathlib
+
+    from tools.ytklint import flow
+
+    monkeypatch.chdir(pathlib.Path(__file__).resolve().parent.parent)
+    census = flow.census_for_repo()
+    bench = {p: refs for p, refs in census.consumer_refs.items()
+             if p.startswith("perfbench/")}
+    assert {"perfbench/pb/trials.py", "perfbench/metrics/hist_scope_share.py",
+            "perfbench/families/gbst.py"} <= set(bench)
+    assert [(p, line, lit) for p, refs in bench.items() for line, lit in refs
+            if not census._satisfied(lit)] == []
+    # scopes are produced names too: the scope shares' readers consume them
+    assert "scope" in census.exact["gbdt.route"]["kinds"]
+    assert census._consumers_of("gbdt.route", False) == [
+        "perfbench/metrics/route_scope_share.py"]
+    assert flow.is_consumer("perfbench/metrics/x.py")
+    assert not flow.is_consumer("perfbench/tools/x.py")
+
+
 # -- --changed-only ----------------------------------------------------------
 
 

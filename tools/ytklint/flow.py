@@ -45,6 +45,7 @@ chains with ``core.lint_sources({path: source, ...})``.
 from __future__ import annotations
 
 import ast
+import fnmatch
 import pathlib
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
@@ -70,13 +71,23 @@ BLESSED_IO_FILES = frozenset({
 })
 
 #: files whose metric-name references are the consumer side of the
-#: census (sentinels, gates, reports)
+#: census (sentinels, gates, reports, the benchmark's readers), as
+#: repo-relative paths or globs; the benchmark's files are read for the
+#: census and the name map, not linted
 CONSUMER_FILES = (
     "ytklearn_tpu/obs/health.py",
     "scripts/obs_report.py",
     "scripts/check_bench_regress.py",
     "bench.py",
+    "perfbench/metrics/*.py",
+    "perfbench/families/*.py",
+    "perfbench/pb/*.py",
 )
+
+
+def is_consumer(path: str) -> bool:
+    return any(fnmatch.fnmatchcase(path, pat) for pat in CONSUMER_FILES)
+
 
 DOC_BEGIN = "<!-- metric-name-map:begin -->"
 DOC_END = "<!-- metric-name-map:end -->"
@@ -624,6 +635,8 @@ _PRODUCER_KINDS = {
     "step_span": "span", "obs_step_span": "span",
     "root_span": "span", "obs_root_span": "span",
     "hop": "span", "hop_at": "span", "batch_hop": "span",
+    # `obs.scopes`: names on the device, read back through the scope maps
+    "scope": "scope", "subscope": "scope",
 }
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+\.?$")
@@ -634,7 +647,8 @@ _NON_METRIC_PREFIXES = ("ytklearn_tpu.", "scripts.", "tools.", "tests.",
                         "jax.", "numpy.", "np.", "os.", "sys.", "time.",
                         "threading.", "subprocess.")
 _PATHISH_CALL_TAILS = {"join", "exists", "open", "dirname", "abspath",
-                       "isfile", "isdir", "Path", "remove", "unlink"}
+                       "isfile", "isdir", "Path", "remove", "unlink",
+                       "set_path"}  # a config key (`model.data_path`)
 
 
 def _producer_name(arg: ast.expr) -> Tuple[Optional[str], bool]:
@@ -658,7 +672,7 @@ class MetricCensus:
     consumer references are lint findings; the producer inventory is
     pinned by the generated docs/observability.md name-map section."""
 
-    def __init__(self, ctxs: Sequence) -> None:
+    def __init__(self, ctxs: Sequence, consumers_only: Sequence = ()) -> None:
         # name -> {"kinds": set, "files": set}
         self.exact: Dict[str, dict] = {}
         self.prefixes: Dict[str, dict] = {}
@@ -666,8 +680,10 @@ class MetricCensus:
         self.consumer_refs: Dict[str, List[Tuple[int, str]]] = {}
         for ctx in ctxs:
             self._scan_producers(ctx)
-            if ctx.path in CONSUMER_FILES:
+            if is_consumer(ctx.path):
                 self._scan_consumer(ctx)
+        for ctx in consumers_only:
+            self._scan_consumer(ctx)
 
     def _scan_producers(self, ctx) -> None:
         for n in ast.walk(ctx.tree):
@@ -689,6 +705,7 @@ class MetricCensus:
         # and filename components fed to path calls (os.path.join(d,
         # "higgs.train") is a dataset file, not a counter)
         skip_ids: Set[int] = set()
+        skip_names: Set[str] = set()
         for n in ast.walk(ctx.tree):
             if not isinstance(n, ast.Call):
                 continue
@@ -696,6 +713,20 @@ class MetricCensus:
             if tail == "getLogger" or tail in _PATHISH_CALL_TAILS:
                 for a in n.args:
                     skip_ids.add(id(a))
+                    if isinstance(a, ast.Name):
+                        skip_names.add(a.id)
+        # and the literals a loop binds to such an argument:
+        # `for key, name in (("model.data_path", "gbdt.model"), ...)`
+        for n in ast.walk(ctx.tree):
+            if not (isinstance(n, ast.For) and isinstance(n.target, ast.Tuple)
+                    and isinstance(n.iter, (ast.Tuple, ast.List))):
+                continue
+            bound = [t.id if isinstance(t, ast.Name) else None for t in n.target.elts]
+            for row in n.iter.elts:
+                if isinstance(row, (ast.Tuple, ast.List)):
+                    for name, v in zip(bound, row.elts):
+                        if name in skip_names:
+                            skip_ids.add(id(v))
         refs: List[Tuple[int, str]] = []
         for n in ast.walk(ctx.tree):
             if not (isinstance(n, ast.Constant) and isinstance(n.value, str)):
@@ -786,9 +817,15 @@ class MetricCensus:
 
 
 def census_for_repo() -> MetricCensus:
+    """Producers and consumers over the linted tree, and the consumers of
+    the consumer files outside it (the benchmark's readers)."""
     from .core import contexts_for_paths
 
-    return MetricCensus(contexts_for_paths(DEFAULT_PATHS))
+    ctxs = contexts_for_paths(DEFAULT_PATHS)
+    linted = {c.path for c in ctxs}
+    outside = sorted({str(f) for pat in CONSUMER_FILES for f in _REPO_ROOT.glob(pat)
+                      if f.relative_to(_REPO_ROOT).as_posix() not in linted})
+    return MetricCensus(ctxs, consumers_only=contexts_for_paths(outside))
 
 
 def check_doc_sync(doc_path: pathlib.Path,

@@ -966,6 +966,7 @@ class GBDTTrainer:
                 sig_fn=lambda: profiler.abstract_signature(carry, data),
             ):
                 rnd_dev = jnp.asarray(rnd)
+                obs_inc("launches.jit_round_step")
                 carry = jit_round(
                     carry, rnd_dev, jax.random.fold_in(root_key, rnd), data
                 )
@@ -976,6 +977,7 @@ class GBDTTrainer:
                     # time, their compile) and the host's wait for the
                     # loss enqueued one window earlier
                     with _sync_span(rnd, rounds=rnd - synced, lagged=True) as sp:
+                        obs_inc("launches.jit_sync_slice", 2 if has_test else 1)
                         nxt = (
                             rnd,
                             sync_slice(carry[3], rnd_dev),

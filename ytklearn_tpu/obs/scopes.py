@@ -12,7 +12,10 @@ without reading XLA's text:
   Program(fn)        a jitted step compiled ahead of time once per
                      argument signature, under `fn`'s own fixed name (the
                      trace's module line reads `jit_<name>`), so that the
-                     compiled HLO is at hand when it is made.
+                     compiled HLO is at hand when it is made. Every call
+                     adds 1 to the counter `launches.jit_<name>`: what the
+                     program asked of the device, against which a device
+                     trace's module line says how much of it was kept.
   compile_lowered()  compiles a `jax.stages.Lowered` and reads, once per
                      compile, the compiled HLO's `metadata={op_name=...}`
                      into a map module name -> instruction name -> scope,
@@ -208,10 +211,12 @@ class Program:
     """`Program(fn)(*args)` runs `jax.jit(fn)` compiled ahead of time: one
     compile per argument signature (shapes, dtypes, shardings), the compiled
     object kept, its scope map recorded when it is made. The function's
-    `__name__` is the program's name on the device."""
+    `__name__` is the program's name on the device, and a call counts one
+    launch of it (`launches.jit_<name>`)."""
 
     def __init__(self, fn, **jit_kwargs):
         self.jit = jax.jit(fn, **jit_kwargs)
+        self.launches = f"launches.jit_{fn.__name__}"
         self._compiled: Dict[tuple, object] = {}
 
     def compile(self, *args):
@@ -225,4 +230,5 @@ class Program:
         return compiled
 
     def __call__(self, *args):
+        core.inc(self.launches)
         return self.compile(*args)(*args)

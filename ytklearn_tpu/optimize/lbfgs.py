@@ -95,6 +95,9 @@ class LBFGSState(NamedTuple):
     ys: jnp.ndarray  # (m,), newest first
     hist_len: jnp.ndarray  # how many of the m pairs are real
     ls_status: jnp.ndarray  # >0 ok (trial count), <0 failed
+    # trials the last line search made, failed or not: each one loss+gradient
+    # pass. An output of the iteration and no input of the next one
+    ls_trials: Optional[jnp.ndarray] = None
 
 
 @dataclass
@@ -240,7 +243,8 @@ def _build_programs(
 
     def line_search(wprev, gprev, p, step0, loss0, pure0, reg, batch):
         """reference: HoagOptimizer.lineSearch:1068-1201. Returns
-        (w, g, loss, pure, status) — status<0: failed (reverted)."""
+        (w, g, loss, pure, status, trials) — status<0: failed (reverted);
+        `trials` counts every trial made, those of a failed search too."""
         dginit = jnp.vdot(gprev, p)
 
         def body(carry):
@@ -292,7 +296,7 @@ def _build_programs(
             loss0,
             pure0,
         )
-        _, _, status, w, g, loss, pure = lax.while_loop(
+        _, trials, status, w, g, loss, pure = lax.while_loop(
             lambda c: c[2] == 0, body, init
         )
         failed = status < 0
@@ -301,7 +305,7 @@ def _build_programs(
         g = jnp.where(failed, gprev, g)
         loss = jnp.where(failed, loss0, loss)
         pure = jnp.where(failed, pure0, pure)
-        return w, g, loss, pure, status
+        return w, g, loss, pure, status, trials
 
     def first_eval(w, reg, batch):
         pure, loss, g = lg(w, reg, batch)
@@ -321,7 +325,7 @@ def _build_programs(
             # constrain search direction (reference :697-705)
             p = jnp.where((reg.l1_vec > 0.0) & (p * gprev >= 0.0), 0.0, p)
 
-        w, g, loss, pure, status = line_search(
+        w, g, loss, pure, status, trials = line_search(
             wprev, gprev, p, state.step, state.loss, state.pure_loss, reg, batch
         )
 
@@ -333,7 +337,7 @@ def _build_programs(
         ys_arr = jnp.concatenate([ys[None], state.ys[:-1]])
         new_len = jnp.minimum(state.hist_len + 1, m).astype(jnp.int32)
         return (
-            (w, g, loss, pure, status),
+            (w, g, loss, pure, status, trials),
             (s, y, ys_arr, new_len),
             jnp.linalg.norm(w),
             jnp.linalg.norm(g),
@@ -355,14 +359,16 @@ class _Iteration(Program):
     host, it goes to the head of the history and the oldest pair falls off,
     so that a step allocates one pair and no second copy of S and Y. After a
     failed line search (status < 0) the history stays as it was: the read of
-    the status is the sync the caller's own read of `ls_status` makes next."""
+    the status and the trial count, in one fetch, is the sync the caller's
+    own reads of `ls_status` and `ls_trials` make next."""
 
     def __call__(self, state: LBFGSState, reg: Reg, batch):
-        (w, g, loss, pure, status), (s, y, ys_arr, new_len), wnorm, gnorm = (
-            super().__call__(state, reg, batch)
+        # the last search's trials are no input: every call has one signature
+        (w, g, loss, pure, status, trials), (s, y, ys_arr, new_len), wnorm, gnorm = (
+            super().__call__(state._replace(ls_trials=None), reg, batch)
         )
         S, Y, ys, hist_len = state.S, state.Y, state.ys, state.hist_len
-        if int(jax.device_get(status)) > 0:
+        if int(jax.device_get((status, trials))[0]) > 0:
             S, Y = (s,) + tuple(S[:-1]), (y,) + tuple(Y[:-1])
             ys, hist_len = ys_arr, new_len
         new_state = LBFGSState(
@@ -376,6 +382,7 @@ class _Iteration(Program):
             ys=ys,
             hist_len=hist_len,
             ls_status=status,
+            ls_trials=trials,
         )
         return new_state, wnorm, gnorm
 
@@ -470,6 +477,7 @@ def minimize_lbfgs(
         ys=jnp.ones((config.m,), dtype),
         hist_len=jnp.asarray(0, jnp.int32),
         ls_status=jnp.asarray(1, jnp.int32),
+        ls_trials=jnp.asarray(0, jnp.int32),
     )
     if callback is not None and callback(0, state):
         return _result(state, 0, "callback_stop")
@@ -497,17 +505,17 @@ def minimize_lbfgs(
                 sig_fn=lambda: profiler.abstract_signature(state, reg, batch),
             ):
                 state, wnorm, gnorm = iteration(state, reg, batch)
-                ls = int(state.ls_status)
+                ls, trials = int(state.ls_status), int(state.ls_trials)
                 # every line-search trial is one loss+gradient pass over
-                # the data (a failed search reports its status, -1..-3,
-                # and counts as that many: it ends the run anyway)
-                sp.add(passes=abs(ls))
+                # the data, those of a search that failed (status -1..-3,
+                # the reason) as well
+                sp.add(passes=trials, trials=trials, status=ls)
             # the host's part of the step (counters, sentinels, the caller's
             # callback, the convergence test) under a span of that step:
             # while it runs the device has nothing to do
             with obs_span("lbfgs.host", step=it):
                 obs_inc("lbfgs.iterations")
-                obs_inc("lbfgs.passes", abs(ls))
+                obs_inc("lbfgs.passes", trials)
                 if health_on:
                     # after the iteration's span, so a strict escalation's
                     # flight dump carries it completed in its ring
@@ -516,9 +524,6 @@ def minimize_lbfgs(
                         status = "nan_loss"
                         break
                     guard.update(loss_val, it=it)
-                if ls > 1:
-                    # trials beyond the first = line-search retries (step rescales)
-                    obs_inc("lbfgs.ls_retries", ls - 1)
                 if ls < 0:
                     obs_inc("lbfgs.ls_failures")
                     status = f"line_search_failed({ls})"
