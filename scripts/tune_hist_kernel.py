@@ -1,145 +1,100 @@
-"""Micro-bench of the histogram kernel variants at Higgs shape on the
-real chip. Times hist_wave-level calls directly so each variant compiles
-in seconds (the whole-tree program costs ~5 min/compile).
+"""Per-N, per-H times of the full-scan histogram kernel `gbdt_hist_scan`
+(`gbdt/hist.py::_hist_pallas`) on the chip, at the two GBDT cells' shapes:
+Higgs (28 columns, 10,502,144 rows, one-byte tiles) and Epsilon (2,000
+columns, 409,600 rows, packed words), B = 256, bf16. H = 1 is the whole bin
+one-hot; H > 1 factors it (`hist.onehot_split` picks H from N and B). Each
+factored pass is checked against H = 1: counts equal, sums close.
 
-Variants: feature-group width fg, block width bm, int8 vs bf16, u8 vs
-i32 one-hot compares.
+    python scripts/tune_hist_kernel.py [out.json]
+
+Prints one line a (shape, N, H), and writes the table as JSON to out.json
+when it is given. Exits non-zero off the chip: a CPU timing is not a
+device number.
 """
 
 from __future__ import annotations
 
+import json
+import os
 import sys
 import time
 from functools import partial
 
-import numpy as np
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+SHAPES = {  # name: (columns, row blocks of 16,384, packed words)
+    "higgs": (28, 641, False),
+    "epsilon": (2000, 25, True),
+}
+# the H tried at each N: the rule's neighbours, and 1
+SPLITS = {1: (1, 2, 4, 8, 16), 2: (1, 2, 4, 8, 16), 4: (1, 2, 4, 8),
+          8: (1, 2, 4, 8), 16: (1, 2, 4), 32: (1, 2), 64: (1, 2)}
+B, REPS = 256, 5
 
 
-def main() -> None:
+def main() -> int:
     import jax
     import jax.numpy as jnp
-
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    import numpy as np
 
     from ytklearn_tpu.compile_cache import configure_compile_cache
-    from ytklearn_tpu.gbdt.hist import _hist_pallas, _hist_pallas_q
+    from ytklearn_tpu.gbdt import hist
 
+    if jax.default_backend() != "tpu":
+        print(f"no TPU (backend {jax.default_backend()!r})", file=sys.stderr)
+        return 2
     configure_compile_cache()
-
-    n = 1280 * 8192  # 10.48M
-    F, B, N = 28, 256, 32
-    rng = np.random.RandomState(0)
-    bins_host = rng.randint(0, 255, size=(F, n), dtype=np.uint8)
-    bins_dev = jax.device_put(bins_host)
-    pos = jax.device_put(rng.randint(0, 64, size=n).astype(np.int32))
-    g = jax.device_put(rng.randn(n).astype(np.float32))
-    h = jax.device_put(np.abs(rng.randn(n)).astype(np.float32))
-    gq = jnp.clip(jnp.round(g * 50), -127, 127)
-    hq = jnp.clip(jnp.round(h * 50), -127, 127)
-    ids = jax.device_put(np.arange(N, dtype=np.int32))
-
-    def timeit(name, fn, *args, reps=8):
-        out = fn(*args)
-        jax.block_until_ready(out)
-        t0 = time.time()
-        for _ in range(reps):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        dt = (time.time() - t0) / reps * 1000
-        print(f"{name:42s} {dt:8.2f} ms", flush=True)
-        return dt
-
-    # --- baselines at various fg / bm ------------------------------------
-    for bm in (8192, 16384, 32768):
-        bins4 = bins_dev.reshape(F, n // bm, 1, bm)
-        for fg in (7, 14, 28):
-            timeit(
-                f"int8 bm={bm} fg={fg}",
-                partial(_hist_pallas_q, B=B, bm=bm, fg=fg),
-                bins4, pos, gq, hq, ids,
-            )
-    bins4 = bins_dev.reshape(F, n // 8192, 1, 8192)
-    timeit(
-        "bf16 bm=8192 fg=7",
-        partial(_hist_pallas, B=B, bm=8192, fg=7, use_bf16=True),
-        bins4, pos, g, h, ids,
-    )
-
-    # --- u8 one-hot compare variant (int8 dot) ---------------------------
-    def hist_q_u8(bins4, pos, gq, hq, node_ids, B, bm, fg):
-        F, nblk = bins4.shape[0], bins4.shape[1]
-        N = node_ids.shape[0]
-        nt = (((1,), (1,)), ((), ()))
-        pos3 = pos.reshape(nblk, 1, bm)
-        g3 = gq.reshape(nblk, 1, bm)
-        h3 = hq.reshape(nblk, 1, bm)
-        ids2 = node_ids.reshape(N, 1)
-
-        def kernel(bins_ref, pos_ref, g_ref, h_ref, ids_ref, out_ref):
-            blk = pl.program_id(1)
-            p = pos_ref[0, 0, :][None, :]
-            Pb = ids_ref[:, 0:1] == p
-            P = Pb.astype(jnp.float32)
-            gv = P * g_ref[0, 0, :][None, :]
-            hv = P * h_ref[0, 0, :][None, :]
-            PV = jnp.concatenate([gv, hv, P], axis=0).astype(jnp.int8)
-            iota_b = jax.lax.broadcasted_iota(
-                jnp.int32, (B, 1), 0
-            ).astype(jnp.uint8)
-            for fi in range(fg):
-                b = bins_ref[fi, 0, 0, :][None, :]  # stays u8
-                OH = (iota_b == b).astype(jnp.int8)
-                acc = jax.lax.dot_general(
-                    PV, OH, nt, preferred_element_type=jnp.int32
-                )
-
-                @pl.when(blk == 0)
-                def _():
-                    out_ref[fi, :, :] = acc
-
-                @pl.when(blk > 0)
-                def _():
-                    out_ref[fi, :, :] = out_ref[fi, :, :] + acc
-
-        return pl.pallas_call(
-            kernel,
-            grid=(F // fg, nblk),
-            in_specs=[
-                pl.BlockSpec((fg, 1, 1, bm), lambda fo, k: (fo, k, 0, 0)),
-                pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
-                pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
-                pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
-                pl.BlockSpec((N, 1), lambda fo, k: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((fg, 3 * N, B), lambda fo, k: (fo, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((F, 3 * N, B), jnp.int32),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
-            ),
-        )(bins4, pos3, g3, h3, ids2)
-
-    for bm in (8192, 32768):
-        bins4 = bins_dev.reshape(F, n // bm, 1, bm)
-        for fg in (7, 28):
-            try:
-                timeit(
-                    f"int8 u8-OH bm={bm} fg={fg}",
-                    partial(jax.jit, static_argnames=())(
-                        partial(hist_q_u8, B=B, bm=bm, fg=fg)
-                    ),
-                    bins4, pos, gq, hq, ids,
-                )
-            except Exception as e:  # noqa: BLE001
-                print(f"int8 u8-OH bm={bm} fg={fg} FAILED: {type(e).__name__}",
-                      flush=True)
-
-    # --- correctness spot check (u8 variant vs reference kernel) ---------
-    bins4 = bins_dev.reshape(F, n // 8192, 1, 8192)
-    a = _hist_pallas_q(bins4, pos, gq, hq, ids, B, 8192, 7)
-    b = hist_q_u8(bins4, pos, gq, hq, ids, B=B, bm=8192, fg=7)
-    print("u8 variant exact:", bool(jnp.all(a == b)), flush=True)
+    out_path = sys.argv[1] if len(sys.argv) > 1 else None
+    bm = hist.BM_DEFAULT
+    rows = []
+    for shape, (F, nblk, packed) in SHAPES.items():
+        n = nblk * bm
+        k = jax.random.split(jax.random.PRNGKey(0), 5)
+        bins_t = jax.random.randint(k[0], (F, n), 0, B).astype(jnp.uint8)
+        tiles = jax.block_until_ready(hist.tile_bins(bins_t, bm, pack=packed))
+        del bins_t
+        g = jax.random.normal(k[1], (n,), jnp.float32)
+        h = jax.random.uniform(k[2], (n,), jnp.float32)
+        fg = hist._pick_fg(F)
+        for N, splits in SPLITS.items():
+            pos = jax.random.randint(k[3], (n,), 0, 2 * N)
+            ids = jnp.arange(N, dtype=jnp.int32)
+            base = None
+            for H in splits:
+                run = partial(hist._hist_pallas, tiles, pos, g, h, ids, B, bm,
+                              fg, True, H)
+                t0 = time.time()
+                try:
+                    got = jax.block_until_ready(run())
+                except Exception as e:  # a refused H is a row, not the end
+                    print(json.dumps(dict(shape=shape, N=N, H=H,
+                                          error=str(e).splitlines()[0])))
+                    continue
+                compile_s = time.time() - t0
+                t0 = time.time()
+                for _ in range(REPS):
+                    out = run()
+                jax.block_until_ready(out)
+                ms = (time.time() - t0) / REPS * 1e3
+                got = np.asarray(got).reshape(F, 3, N, B)
+                if base is None:
+                    base, cnt_ok, gap = got, True, 0.0
+                else:
+                    cnt_ok = bool(np.array_equal(got[:, 2], base[:, 2]))
+                    scale = np.abs(base[:, :2]).max() + 1e-30
+                    gap = float(np.abs(got[:, :2] - base[:, :2]).max() / scale)
+                row = dict(shape=shape, F=F, n=n, N=N, H=H, ms=round(ms, 3),
+                           rule=hist.onehot_split(N, B), counts_equal=cnt_ok,
+                           sum_gap=gap, compile_s=round(compile_s, 1))
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump(dict(device=jax.devices()[0].device_kind, rows=rows), f,
+                      indent=1)
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

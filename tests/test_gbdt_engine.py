@@ -397,7 +397,7 @@ def test_benchmark_contract(tmp_path):
     for k in ("features", "hist_pool_bytes", "route_kernel", "packed_tiles",
               "rungs_fused", "rungs_xla", "trees_logged", "hist_part_passes",
               "hist_part_rows_scanned", "hist_part_rows_needed",
-              "leaf_lookup_kernel"):
+              "leaf_lookup_kernel", "hist_factored_passes"):
         assert k in tr.time_stats, k
     assert tr.time_stats["features"] == 6
     assert tr.time_stats["hist_pool_bytes"] == 15 * 6 * 32 * 3 * 4

@@ -190,6 +190,49 @@ def test_scan_kernel_interpreted_equals_dense_twin(F, packed, precision):
     np.testing.assert_array_equal(np.asarray(got[..., 2]), np.asarray(want[..., 2]))
 
 
+@pytest.mark.parametrize("precision", ["bf16", "f32"])
+@pytest.mark.parametrize("packed", [False, True], ids=["one-byte", "packed"])
+@pytest.mark.parametrize("B", [256, 24])
+@pytest.mark.parametrize("N", [1, 2, 4, 8, 16, 32, 64])
+def test_factored_scan_interpreted_equals_dense_twin(N, B, packed, precision):
+    """At every wave width of a tree, on one-byte tiles and packed words:
+    where the kernel factors the bin one-hot (B = 256, N < 32) and where it
+    builds the whole one-hot (N >= 32; B = 24, no power of two), its counts
+    are the dense twin's bit for bit and its sums within the unfactored
+    kernel's tolerance."""
+    H = hist.onehot_split(N, B)
+    assert (H > 1) == (B == 256 and N < 32)
+    F, bm, nblk = 16, 512, 2
+    n = bm * nblk
+    rng = np.random.RandomState(N * 1000 + B)
+    bins_t = rng.randint(0, B, (F, n)).astype(np.uint8)
+    pos = rng.randint(-1, N + 3, n).astype(np.int32)
+    g, h = rng.randn(n).astype(np.float32), rng.rand(n).astype(np.float32)
+    ids = rng.permutation(N + 2)[:N].astype(np.int32)
+    ids[N // 2] = -2  # a padded slot matches no row
+    tiles = hist.tile_bins(jnp.asarray(bins_t), bm, pack=packed)
+    args = (jnp.asarray(pos), jnp.asarray(g), jnp.asarray(h), jnp.asarray(ids), B)
+    got = hist.hist_wave(tiles, *args, precision=precision, kernels="pallas",
+                         bm=bm, interpret=True)
+    want = hist.hist_wave(jnp.asarray(bins_t), *args, precision=precision,
+                          kernels="dense", bm=bm)
+    assert got.shape == (N, F, B, 3) and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got[..., 2]), np.asarray(want[..., 2]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-4)
+
+
+def test_onehot_split_table():
+    """H from (N, B) alone: the least of max(compares, 6N*H), the compares
+    B for the whole one-hot and H + B/H factored."""
+    assert [hist.onehot_split(N, 256) for N in (1, 2, 4, 8, 16, 32, 64, 128)] == [
+        8, 4, 4, 2, 2, 1, 1, 1]
+    assert hist.onehot_split(1, 24) == 1 and hist.onehot_split(1, 255) == 1
+    assert hist.onehot_split(1, 8) == 1  # max(2 + 4, 12) is not under 8
+    assert hist.onehot_split(1, 32) == 2
+    assert [hist.onehot_split(N, 512) for N in (1, 16, 32, 64)] == [8, 2, 2, 1]
+
+
 # -- (4) what the width decides: one table ----------------------------------
 
 _SHAPES = {  # rows padded to bm 16384: Higgs, MS LTR, Epsilon
@@ -240,14 +283,17 @@ def test_width_predicates_borders():
     assert _rung_spec(F=2000, B=256, kernels="pallas").packed
 
 
-# -- (5) Higgs' round program is the parent's -------------------------------
+# -- (5) Higgs' round program is the one it was taken from -----------------
 
 # sha256 of the traced round program (its jaxpr's text, addresses struck out)
 # at gbdt_higgs.train's true shape, 10,502,144 + 507,904 padded rows x 28, in
-# the Pallas family, as the PARENT of PR 39 (e3fac9c) traces it: taken there
-# with this function, equal here. A change to the GBDT round program at
-# Higgs' width changes it: take it again and say in PERF.md what moved.
-HIGGS_ROUND_SHA = "52a0351c8fe4c03cba039ee8cea02c730aea51ab7365e462685f105769611e01"
+# the Pallas family. First taken at e3fac9c; taken again on purpose when the
+# narrow waves' kernel came to factor the bin one-hot (on top of 9b12f90: the
+# six scans under 32 nodes and the `gbdt.hist.start` subscope changed; the
+# 32- and 64-node scans' kernel is the one before, jaxpr for jaxpr). A change
+# to the GBDT round program at Higgs' width changes it: take it again and say
+# in PERF.md what moved.
+HIGGS_ROUND_SHA = "21c03c29bbe8a3e6f38086586cb76f522377af7365b03c22775ca588c2b6c756"
 
 
 def _round_program_text(monkeypatch, tmp_path, n_rows, nt_rows, F):
@@ -298,6 +344,13 @@ def test_wide_round_program_traces_with_every_wide_branch(tmp_path, monkeypatch)
         assert shape in text, shape
     # and no one-byte tile, of train or test rows
     assert "u8[2000,25,1,16384]" not in text and "u8[2000,7,1,16384]" not in text
+    # the narrow waves' kernel writes the factored form (F, 3N*H, B/H): N =
+    # 1, 2, 4, 8, 16 at H = 8, 4, 4, 2, 2; the 32- and 64-node scans the
+    # whole one-hot's (F, 3N, B)
+    for shape in ("f32[2000,24,32]", "f32[2000,24,64]", "f32[2000,48,64]",
+                  "f32[2000,48,128]", "f32[2000,96,128]",
+                  "f32[8,96,256]", "f32[8,192,256]"):
+        assert shape in text, shape
 
 
 # -- (6) a wide run against the plain reference -----------------------------
@@ -389,8 +442,17 @@ def test_wide_run_agrees_with_the_plain_reference(tmp_path, monkeypatch, family)
         assert g["gbdt.stat.hist_part_passes"] > 0
         assert 0 < g["gbdt.stat.hist_part_rows_needed"] <= (
             g["gbdt.stat.hist_part_rows_scanned"])
+        # the root's and the five slow-start waves under 32 nodes factor
+        # the bin one-hot
+        assert g["gbdt.stat.hist_factored_passes"] == 6
     else:
         assert g["gbdt.stat.packed_tiles"] == 0 and g["gbdt.stat.rungs_fused"] == 0
+        assert g["gbdt.stat.hist_factored_passes"] == 0
+    # the narrow waves' histogram passes, a subscope of the round program
+    from ytklearn_tpu.obs import scopes
+
+    assert any("gbdt.hist.start" in ops.values()
+               for ops in scopes.subscope_map().values())
 
 
 # -- (7) the sketch's threads -----------------------------------------------

@@ -48,6 +48,7 @@ from .hist import (
     gather_table,
     hist_wave,
     hist_wave_gather,
+    onehot_split,
     tile_bins,
 )
 from .route import route_kernel_holds, route_wave
@@ -314,6 +315,17 @@ class GrowSpec:
             self.kernels == "pallas" and self.route == "dense"
             and self.B <= 256
         )
+
+    def factored_passes(self) -> int:
+        """The full-scan passes a tree whose kernel factors the bin one-hot
+        (hist.onehot_split > 1), of the root's and the slow start's, whose
+        widths are fixed (1, then 1, 2, 4, ... below the wave): the Pallas
+        kernel at bf16 or f32 (the int8 kernel and the dense twin never)."""
+        if self.kernels != "pallas" or self.precision == "int8":
+            return 0
+        w = self.wave
+        widths = [1] + [1 << k for k in range(w.bit_length()) if 1 << k < w]
+        return sum(onehot_split(n, self.B) > 1 for n in widths)
 
     def goss_sizes(self, n_full: int) -> Tuple[int, int, int]:
         """GOSS's static sizes over `n_full` (padded, per-shard) rows: (top
@@ -671,6 +683,13 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
             """Full-scan histogram (root + slow start + big-wave phases)."""
             return hist_finish(hist_partial(bins_k, pos_fit, G_, H_, ids))
 
+        def hist_start(pos_fit, ids):
+            """The root's and the slow start's full scans: the narrow waves,
+            where hist.onehot_split factors the kernel's bin one-hot. A
+            second naming beside the scopes, as `gbdt.hist.part` is."""
+            with subscope("gbdt.hist.start"):
+                return hist_call(pos_fit, ids)
+
         def hist_budget(R: int, impl: str = "xla"):
             """Leaf-partitioned histogram at static budget R: compact the
             rows belonging to the wave's nodes and histogram only those —
@@ -739,7 +758,7 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         # used per-device ones).
         ids0 = jnp.asarray([0], jnp.int32)  # root wave: one real slot
         pos_fit = jnp.where(include, pos, -1)
-        hist0 = hist_call(pos_fit, ids0)  # (1, F_loc, B, 3)
+        hist0 = hist_start(pos_fit, ids0)  # (1, F_loc, B, 3)
         root_ghc = jnp.sum(hist0[0, 0], axis=0)  # feature 0 bin-sum = totals
         if n_shards > 1:
             from ..parallel.collectives import psum
@@ -949,14 +968,13 @@ def _build_grow(spec: GrowSpec, n_shards: int = 1, axis: str = "data", ranges=No
         # slow start: after k waves at most 2^k nodes are expandable, so the
         # first waves run right-sized (N = 1, 2, 4, ...) — identical split
         # decisions to full-width waves at a fraction of the one-hot matmul
-        # rows. What that buys is measured, not proportional: on the v5e a
-        # full scan costs the same from 3 to 48 slots (0.713-0.721 s each
-        # over 14 trees, 51 ms a pass: the VPU's one-hot build, rows x F x B
-        # compares whatever the width) and grows only above that (96 slots
-        # 87.5 ms, 192 slots 508 ms a tree): PERF.md section 5
+        # rows. What that buys is measured, not proportional: below 32 nodes
+        # the kernel factors the bin one-hot (hist.onehot_split), whose whole
+        # build, rows x F x B compares, would cost a pass the same at every
+        # width; each width's time on the v5e is in PERF.md section 5
         nw_ss = 1
         while nw_ss < NW:
-            state = wave_body(state, nw_ss)
+            state = wave_body(state, nw_ss, hist_start)
             nw_ss *= 2
 
         if rungs:

@@ -12,6 +12,20 @@ the histogram as a blocked one-hot matmul on the MXU:
             OH (B, bm)   = bin one-hot              # VPU compare vs iota
             out[f] (3N,B) += PV @ OH.T              # MXU NT-dot, f32 accum
 
+A narrow wave factors the bin one-hot (`onehot_split`: H > 1). A bin is
+b = hi*L + lo with H*L = B, and for each feature the kernel builds
+
+            OH_hi (H, bm), OH_lo (L, bm)            # VPU, H + L compares
+            Q (3N*H, bm) = PV[:, None] * OH_hi[None] # VPU, 3N*H multiplies
+            out[f] (3N*H, L) += Q @ OH_lo.T         # MXU NT-dot, f32 accum
+
+so a row costs H + L compares and 3N*H multiplies by 0 or 1 where the
+whole one-hot costs B compares whatever N is. The MACs, the operands'
+values and the f32 sums are the same. Element (x*H + hi, lo) of the
+output lies at (x*H + hi)*L + lo = x*B + hi*L + lo in row-major order,
+where element (x, hi*L + lo) of (3N, B) lies, so (F, 3N*H, L) reshapes
+to (F, 3N, B) without moving data.
+
 Layouts are lane-major throughout (P (N, bm), OH (B, bm), samples always
 on lanes) so no in-kernel transposes occur and no (x, 1) blocks blow up
 VMEM with lane padding. Grouping features inside one grid step amortizes
@@ -114,11 +128,35 @@ def _block_bins(bins_ref, fi: int, packed: bool):
     )
 
 
-@partial(jax.jit, static_argnames=("B", "bm", "fg", "use_bf16", "interpret"))
+def onehot_split(N: int, B: int) -> int:
+    """H, the factor of the full-scan kernel's bin one-hot at N nodes a
+    wave and B bins (module docstring): the power of two of least cost a
+    row and feature, which is the larger of the one-hot build's compares
+    (B at H = 1, else H + B/H) and the MXU pass's 3N*H rows at two
+    compares' time a row, the weight that per-N kernel times on the v5e
+    gave at both GBDT cells' shapes (PERF.md section 6); 1 where B is no
+    power of two. At B = 256: N 1 -> 8; 2, 4 -> 4; 8, 16 -> 2; from 32 -> 1."""
+    if B & (B - 1):
+        return 1
+
+    def cost(H: int) -> int:
+        return max(B if H == 1 else H + B // H, 2 * 3 * N * H)
+
+    best, H = 1, 2
+    while H < B:
+        if cost(H) < cost(best):
+            best = H
+        H *= 2
+    return best
+
+
+@partial(jax.jit, static_argnames=("B", "bm", "fg", "use_bf16", "H", "interpret"))
 def _hist_pallas(
     bins4, pos, g, h, node_ids, B: int, bm: int, fg: int, use_bf16: bool,
-    interpret: bool = False,
+    H: int = 1, interpret: bool = False,
 ):
+    """(F, 3N, B) f32 histograms, rows [g*N | h*N | c*N]; H > 1 factors
+    the bin one-hot (hist_wave passes onehot_split(N, B))."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -127,6 +165,9 @@ def _hist_pallas(
     n = nblk * bm
     N = node_ids.shape[0]
     assert F % fg == 0, (F, fg)
+    assert B % H == 0, (B, H)
+    L = B // H
+    shift = L.bit_length() - 1  # H > 1: B and L are powers of two
     cdt = jnp.bfloat16 if use_bf16 else jnp.float32
     prec = None if use_bf16 else jax.lax.Precision.HIGHEST
     nt = (((1,), (1,)), ((), ()))  # A @ B.T
@@ -134,22 +175,39 @@ def _hist_pallas(
     pos3 = pos.reshape(nblk, 1, bm)
     g3 = g.reshape(nblk, 1, bm)
     h3 = h.reshape(nblk, 1, bm)
-    ids2 = node_ids.reshape(N, 1)
+    # H > 1: each node's id H times, so that row x*H + hi of PV is row x of
+    # the unfactored PV and Q is PV times OH_hi tiled 3N times, with no 3-D
+    # (3N, H, bm) product, whose size-H sublane dim Mosaic pads to a tile
+    ids2 = (node_ids if H == 1 else jnp.repeat(node_ids, H)).reshape(N * H, 1)
 
     def kernel(bins_ref, pos_ref, g_ref, h_ref, ids_ref, out_ref):
         blk = pl.program_id(1)
         p = pos_ref[0, 0, :][None, :]  # (1, bm) lanes
-        P = (ids_ref[:, 0:1] == p).astype(cdt)  # (N, bm)
+        P = (ids_ref[:, 0:1] == p).astype(cdt)  # (N*H, bm)
         gv = g_ref[0, 0, :][None, :].astype(cdt)
         hv = h_ref[0, 0, :][None, :].astype(cdt)
-        PV = jnp.concatenate([P * gv, P * hv, P], axis=0)  # (3N, bm)
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+        PV = jnp.concatenate([P * gv, P * hv, P], axis=0)  # (3N*H, bm)
+        if H == 1:
+            iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+        else:
+            iota_h = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0)
+            iota_l = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
         for fi in range(fg):
             b = _block_bins(bins_ref, fi, packed)  # (1, bm)
-            OH = (iota_b == b).astype(cdt)  # (B, bm)
-            acc = jax.lax.dot_general(
-                PV, OH, nt, precision=prec, preferred_element_type=jnp.float32
-            )  # (3N, B)
+            if H == 1:
+                OH = (iota_b == b).astype(cdt)  # (B, bm)
+                acc = jax.lax.dot_general(
+                    PV, OH, nt, precision=prec,
+                    preferred_element_type=jnp.float32,
+                )  # (3N, B)
+            else:
+                OH_hi = (iota_h == (b >> shift)).astype(cdt)  # (H, bm)
+                OH_lo = (iota_l == (b & (L - 1))).astype(cdt)  # (L, bm)
+                Q = PV * pltpu.repeat(OH_hi, 3 * N, axis=0)  # (3N*H, bm)
+                acc = jax.lax.dot_general(
+                    Q, OH_lo, nt, precision=prec,
+                    preferred_element_type=jnp.float32,
+                )  # (3N*H, L)
 
             @pl.when(blk == 0)
             def _():
@@ -168,16 +226,16 @@ def _hist_pallas(
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
             pl.BlockSpec((1, 1, bm), lambda fo, k: (k, 0, 0)),
-            pl.BlockSpec((N, 1), lambda fo, k: (0, 0)),
+            pl.BlockSpec((N * H, 1), lambda fo, k: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((fg, 3 * N, B), lambda fo, k: (fo, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, 3 * N, B), jnp.float32),
+        out_specs=pl.BlockSpec((fg, 3 * N * H, L), lambda fo, k: (fo, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((F, 3 * N * H, L), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(bins4, pos3, g3, h3, ids2)
-    return out  # (F, 3N, B), rows [g*N | h*N | c*N]
+    return out.reshape(F, 3 * N, B)  # rows [g*N | h*N | c*N]
 
 
 @partial(jax.jit, static_argnames=("B", "bm", "fg", "interpret"))
@@ -342,7 +400,7 @@ def hist_wave(
             else:
                 out = _hist_pallas(
                     bins4, pos, g, h, node_ids, B, bm, _pick_fg(F),
-                    precision == "bf16", interpret,
+                    precision == "bf16", onehot_split(N, B), interpret,
                 )
         else:
             bins2 = bins_t if bins_t.ndim == 2 else bins_t.reshape(F, -1)

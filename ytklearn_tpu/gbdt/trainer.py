@@ -379,11 +379,12 @@ class GBDTTrainer:
             NW = self.wave
         else:
             # 64 beat 32 and 128 at Higgs scale with quality inside the
-            # band (r5, a retired set-up; not re-measured): the hist
-            # kernel is VPU-bound on the one-hot builds at narrow waves —
-            # (4N+B)*bm VPU ops vs 3N*B*bm MACs per block — so wider waves
-            # raise MXU utilization; 128 over-relaxes best-first and pays
-            # for unused frontier slots
+            # band (r5, a retired set-up; not re-measured): a wave's MACs
+            # are 3N*B a row and feature, its bin one-hot B compares
+            # whatever N (from 32 nodes on at B = 256; a narrower wave's is
+            # factored: hist.onehot_split), so wider waves raise MXU
+            # utilization; 128 over-relaxes best-first and pays for unused
+            # frontier slots
             NW = 64
         NW = max(1, min(NW, (M + 1) // 2))
         # the implementation family, resolved here and nowhere below: the
@@ -851,6 +852,7 @@ class GBDTTrainer:
         ts["packed_tiles"] = bool(spec.packed)
         ts["rungs_fused"] = sum(impl == "fused" for _, impl in rungs)
         ts["rungs_xla"] = sum(impl == "xla" for _, impl in rungs)
+        ts["hist_factored_passes"] = spec.factored_passes()
         # the partitioned passes: those that scanned a budget, not the fit
         # rows their tree's root pass scanned
         part = used & (wl[..., 0] < wl[:, :1, 0])
